@@ -83,5 +83,7 @@ def sweep(steps: int = 20):
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()
     sweep()

@@ -1,7 +1,8 @@
 """End-to-end driver: federated HOTA-FedGradNorm training of a ~100M-param
 dense LM for a few hundred rounds on the distributed (shard_map) path.
 
-Topology: 2 clusters x 2 clients x 2-way tensor parallel = 8 host devices.
+Topology: 2 clusters x 2 clients x 2 replicas on the "model" axis = 8
+host devices (the step is not tensor-parallel; see repro.core.hota).
 Each client owns a differently-skewed synthetic token stream (statistical
 heterogeneity), personalized output heads, dynamic FedGradNorm weighting,
 and the fading-MAC OTA aggregation between cluster ISs and the PS.

@@ -13,9 +13,9 @@ as a ``jax.custom_vjp``:
 
 so autodiff of any scan-stacked backbone routes every parameter gradient
 through the paper's aggregation, one layer at a time (no full per-client
-gradient is ever materialized). The shard_map is *manual* over the FL axes
-(pod/cluster/client) and *auto* over "model": tensor-parallel sharding
-inside each client remains GSPMD's job.
+gradient is ever materialized). The shard_map is *manual* over every mesh
+axis — Mosaic kernels cannot be auto-partitioned — and no spec names a
+non-FL axis ("model"), so devices along one hold replicas.
 
 Channel keys: fold(step_key, class_salt, *layer_tags, leaf_idx) then, in
 the backward, fold(cluster) — one i.i.d. gain per parameter entry per
@@ -72,14 +72,6 @@ def _zero_cot(x):
     return np.zeros(x.shape, jax.dtypes.float0)
 
 
-def _axis_size(name):
-    """jax.lax.axis_size is newer jax; psum of a literal 1 constant-folds
-    to the axis size on older versions."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 class OTACtx(NamedTuple):
     """Traced context for the OTA backward. Passed as explicit custom_vjp
     arguments (closures over tracers break under scan)."""
@@ -105,7 +97,7 @@ def fold_tags(key: jax.Array, klass: str, tags, leaf_idx: int) -> jax.Array:
 def cluster_index(cluster_axes: Tuple[str, ...]) -> jax.Array:
     cidx = jax.lax.axis_index(cluster_axes[0])
     for a in cluster_axes[1:]:
-        cidx = cidx * _axis_size(a) + jax.lax.axis_index(a)
+        cidx = cidx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return cidx
 
 
@@ -215,7 +207,7 @@ def make_ota_gather(data_axes: Tuple[str, ...],
             # my FSDP piece = my cluster's sub-slice of my region
             cidx = jax.lax.axis_index(data_axes[1])
             for a in data_axes[2:]:
-                cidx = cidx * _axis_size(a) + jax.lax.axis_index(a)
+                cidx = cidx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
             n_sub = n_shards // n_clients   # CLIENT_AXIS size by construction
             sz = ghat_reg.shape[axis] // n_sub
             my = jax.lax.dynamic_slice_in_dim(ghat_reg, cidx * sz, sz, axis)
@@ -234,7 +226,7 @@ def make_ota_gather(data_axes: Tuple[str, ...],
         if axis >= 0:
             me = jax.lax.axis_index(data_axes[0])
             for a in data_axes[1:]:
-                me = me * _axis_size(a) + jax.lax.axis_index(a)
+                me = me * jax.lax.axis_size(a) + jax.lax.axis_index(a)
             sz = g.shape[axis] // n_shards
             ghat = jax.lax.dynamic_slice_in_dim(ghat, me * sz, sz, axis)
         return (ghat, jax.tree.map(_zero_cot, ctx))
@@ -354,7 +346,7 @@ def make_packed_final_gather(data_axes: Tuple[str, ...],
         gh_tree = packer.unpack(ghat)
         me = jax.lax.axis_index(data_axes[0])
         for a in data_axes[1:]:
-            me = me * _axis_size(a) + jax.lax.axis_index(a)
+            me = me * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         leaves = jax.tree.leaves(gh_tree)
         out = []
         for leaf, axes in zip(leaves, axes_list):

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from repro.common.flatpack import TreePacker, check_tree_matches_packer, \
     packer_for
 from repro.core.channel import ChannelParams
-from repro.core.hota import OTACtx, _axis_size, _zero_cot, cluster_index
+from repro.core.hota import OTACtx, _zero_cot, cluster_index
 from repro.core.ota import (
     _chunked_stream, packed_section_folds, section_gain_key,
     section_noise_key,
@@ -215,7 +215,7 @@ def make_packed_omega_gather(data_axes: Tuple[str, ...],
         my_reg = jax.lax.axis_index(CLIENT_AXIS)
         sub_idx = jax.lax.axis_index(data_axes[1])
         for a in data_axes[2:]:
-            sub_idx = sub_idx * _axis_size(a) + jax.lax.axis_index(a)
+            sub_idx = sub_idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
 
         def _region(a, i):
             sz_r = a.shape[fsdp_axes[i]] // n_clients

@@ -82,7 +82,6 @@ from repro.optim.adam import (
     AdamState, SlabAdamState, adam_init, adam_update, slab_adam_init,
     slab_adam_update,
 )
-from repro.sharding.mesh_utils import shard_map_compat
 
 LOSS_CHUNK = 512
 
@@ -92,11 +91,6 @@ LOSS_CHUNK = 512
 # only genuinely static knobs (ota_mode, use_pallas_ota, topology) may
 # (DESIGN.md §3.11).
 TRACE_LOG: List[Tuple[str, str]] = []
-
-
-# no spec here references the "model" axis, so the compat fallback's
-# full-manual mode is spec-equivalent for this step
-_shard_map = shard_map_compat
 
 
 def chunked_lm_loss(head, head_apply, feats, labels, chunk=LOSS_CHUNK):
@@ -694,21 +688,27 @@ def make_hota_train_step(
     (consumed only when the static ``fl.faults`` gate is on)."""
     parts = make_hota_step_parts(model, mesh, fl, tcfg, loss_kind=loss_kind,
                                  n_out=n_out)
-    manual_axes = set(_mesh_client_axes(mesh))
+    # manual over EVERY mesh axis: a Mosaic kernel cannot be partitioned
+    # automatically, so no axis may be left to the SPMD partitioner. No
+    # spec names a non-FL axis ("model"), so devices along it hold and
+    # compute replicas.
+    manual_axes = set(mesh.axis_names)
     state_specs, metric_spec = parts.state_specs, parts.metric_spec
     in_specs = (state_specs, parts.batch_spec[0], parts.batch_spec[1], P(),
                 parts.chan_spec, parts.faults_spec)
-    sharded_inner = _shard_map(
+    sharded_inner = jax.shard_map(
         parts.step, mesh=mesh, in_specs=in_specs,
-        out_specs=(state_specs, metric_spec), axis_names=manual_axes)
+        out_specs=(state_specs, metric_spec), axis_names=manual_axes,
+        check_vma=False)
     # statically-specialized naive baseline: with equal weighting and no
     # head phase baked into the config, the FGN inputs can never be
     # consumed, so default-chan calls dispatch to a trace with phases
     # 0/A/B removed (the pre-traced-knobs fast path). A supplied chan
     # always takes the scenario-polymorphic trace.
-    fast_inner = (_shard_map(
+    fast_inner = (jax.shard_map(
         partial(parts.step, fast=True), mesh=mesh, in_specs=in_specs,
-        out_specs=(state_specs, metric_spec), axis_names=manual_axes)
+        out_specs=(state_specs, metric_spec), axis_names=manual_axes,
+        check_vma=False)
         if parts.has_fast else None)
     n_total_clusters = parts.n_total_clusters
     chan_all = parts.chan_all

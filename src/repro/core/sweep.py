@@ -65,7 +65,7 @@ from repro.core.channel import ChannelParams, FaultParams, channel_params, \
 from repro.core.sim import HotaSim, SimState
 from repro.sharding.mesh_utils import SCENARIO_AXIS, bank_sharding, \
     replicated_sharding, scenario_axis_size, scenario_banked_spec, \
-    scenario_banked_tree, shard_map_compat
+    scenario_banked_tree
 
 # the ONLY FLConfig fields a scenario may vary — everything else is baked
 # into the trace (topology, local steps, FGN hyper-params, ota_mode, ...).
@@ -336,12 +336,12 @@ class ShardedScenarioBank(ScenarioBank):
     def _step(self, states, xb, yb, key, chan_bank, fault_bank):
         from jax.sharding import PartitionSpec as P
         banked, shared = P(SCENARIO_AXIS), P()
-        f = shard_map_compat(
+        f = jax.shard_map(
             self._vmapped_step,
             mesh=self.mesh,
             in_specs=(banked, shared, shared, shared, banked, banked),
             out_specs=(banked, banked),
-            axis_names={SCENARIO_AXIS})
+            axis_names={SCENARIO_AXIS}, check_vma=False)
         return f(states, xb, yb, key, chan_bank, fault_bank)
 
     # ------------------------------------------------------------------
@@ -418,12 +418,13 @@ class DistScenarioBank(_BankCheckpoint):
                             in_axes=(0, None, None, None, 0, 0))(
                 states, tokens, labels, key, chan_bank, fault_bank)
 
-        self._inner = shard_map_compat(
+        self._inner = jax.shard_map(
             body, mesh=mesh,
             in_specs=(self._state_banked, parts.batch_spec[0],
                       parts.batch_spec[1], P(), chan_banked, faults_banked),
             out_specs=(self._state_banked, self._metric_banked),
-            axis_names=set(_mesh_client_axes(mesh)) | {SCENARIO_AXIS})
+            axis_names=set(_mesh_client_axes(mesh)) | {SCENARIO_AXIS},
+            check_vma=False)
         self._jstep = jax.jit(self._inner)
         self.chan_bank = jax.tree.map(
             lambda a: jax.device_put(

@@ -19,17 +19,21 @@
   Masks are drawn by inverse-CDF thresholding (``u < erfc(√(H_th/2σ²))``
   — exactly the law of 1{|H|² ≥ H_th}; the estimator never consumes H
   because channel inversion cancels it on passing entries), so the
-  per-entry cost is one compare, not a transcendental chain. Per-cluster
+  per-entry cost is one compare, not a transcendental chain. The pass
+  probability is a per-cluster scalar computed by the wrappers (Mosaic
+  has no erfc lowering) and the uniform is the word's top 24 bits, so
+  every cast the kernel makes is exact. Per-cluster
   masks and the noise tree never touch HBM — one output slab per round
   instead of ~4·C·L small leaf kernels. The ``_fused`` variant generates
   its bits in-kernel from per-section threefry keys on a chunk-quantized
   stream (no (C, P) bits slab in HBM, and blocking can never shift the
   draw); the bits-supplied variant is the oracle bridge for tests.
 
-Channel knobs (σ_l², H_th, noise std, the ota_on gate) arrive as one
-traced (1, C+3) params block, so scenario sweeps (``ScenarioBank``) vmap
-over them without re-tracing; ``ota_on < 0.5`` forces every mask all-pass
-and zeroes the AWGN (the error-free baseline) inside the same kernel.
+Channel knobs (the per-cluster pass probabilities, noise std, the ota_on
+gate) arrive as one traced (1, C+2) params block, so scenario sweeps
+(``ScenarioBank``) vmap over them without re-tracing; ``ota_on < 0.5``
+forces every mask all-pass and zeroes the AWGN (the error-free baseline)
+inside the same kernel.
 
 Tiling: slabs are (rows, 128) — lane-aligned for the VPU — processed in
 (CHUNK_ROWS, 128) chunks (sublane-aligned for f32 packing) with the
@@ -59,29 +63,31 @@ VMEM_BUDGET_BYTES = 6 * 1024 * 1024
 TPU_WG_BLOCK_BUDGET = 8 * 1024 * 1024
 
 
+def _u32_to_f32(v):
+    """Exact float of a uint32 below 2²⁴, cast through int32 (Mosaic has
+    no uint32 -> float32 conversion)."""
+    return v.astype(jnp.int32).astype(jnp.float32)
+
+
 def _box_muller(bits, sigma2):
     """One N(0, σ²) draw per uint32 word (two u16 halves -> Box-Muller)."""
-    hi = (bits >> 16).astype(jnp.float32)
-    lo = (bits & jnp.uint32(0xFFFF)).astype(jnp.float32)
+    hi = _u32_to_f32(bits >> 16)
+    lo = _u32_to_f32(bits & jnp.uint32(0xFFFF))
     u1 = (hi + 1.0) * (1.0 / 65536.0)     # (0, 1]: log-safe
     u2 = lo * (1.0 / 65536.0)
     r = jnp.sqrt(-2.0 * jnp.log(u1))
     return r * jnp.cos(TWO_PI * u2) * jnp.sqrt(sigma2)
 
 
-def _pass_probability(sigma2, h_th):
-    """P(|H|² ≥ H_th), H ~ N(0, σ²): erfc(√(H_th/2σ²)) — a per-cluster
-    SCALAR, so the per-entry mask is one uniform-vs-threshold compare."""
-    sig2 = jnp.maximum(sigma2, 1e-30)
-    return jax.lax.erfc(jnp.sqrt(h_th / (2.0 * sig2)))
-
-
 def _bits_mask(bits, p_pass, off):
     """Inverse-CDF mask draw (eq. 7): the estimator never consumes H
     itself (channel inversion cancels it on passing entries), and
     1{|H|² ≥ H_th} is exactly Bernoulli(p_pass) — sampled here as
-    u < p_pass on the raw uniform word. Matches ref.bits_to_mask."""
-    u = bits.astype(jnp.float32) * jnp.float32(2.0 ** -32)
+    u < p_pass on the word's top 24 bits (an exact f32 uniform in
+    [0, 1)). ``p_pass`` is the cluster's scalar pass probability
+    (``ref.pass_probability``, computed by the wrappers). Matches
+    ref.bits_to_mask."""
+    u = _u32_to_f32(bits >> 8) * jnp.float32(2.0 ** -24)
     return jnp.logical_or(u < p_pass, off)
 
 
@@ -128,12 +134,10 @@ def _ota_mask_weight_kernel(x_ref, bits_ref, params_ref, out_ref, mask_ref):
     materialization). Masks use the same inverse-CDF law as the fused
     aggregate kernel (one compare per entry, matches ref.bits_to_mask on
     the identical bit stream)."""
-    sigma2 = params_ref[0, 0]
-    h_th = params_ref[0, 1]
-    ota_on = params_ref[0, 2]
-    w = params_ref[0, 3]
-    mask = _bits_mask(bits_ref[...], _pass_probability(sigma2, h_th),
-                      ota_on < 0.5)
+    p_pass = params_ref[0, 0]
+    ota_on = params_ref[0, 1]
+    w = params_ref[0, 2]
+    mask = _bits_mask(bits_ref[...], p_pass, ota_on < 0.5)
     x = x_ref[...].astype(jnp.float32)
     out_ref[...] = jnp.where(mask, w * x, 0.0)
     mask_ref[...] = mask.astype(mask_ref.dtype)
@@ -142,7 +146,7 @@ def _ota_mask_weight_kernel(x_ref, bits_ref, params_ref, out_ref, mask_ref):
 def ota_mask_weight_pallas(
     x: jax.Array,            # (rows, 128) slab
     bits: jax.Array,         # (rows, 128) uint32
-    params: jax.Array,       # (1, 4) f32: [sigma2, h_th, ota_on, w] (traced)
+    params: jax.Array,       # (1, 3) f32: [p_pass, ota_on, w] (traced)
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     interpret: bool = False,
@@ -159,7 +163,7 @@ def ota_mask_weight_pallas(
         in_specs=[
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((1, 4), lambda i: (0, 0)),
+            pl.BlockSpec((1, 3), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
@@ -184,19 +188,17 @@ def _ota_mask_count_kernel(x_ref, bits_ref, params_ref, out_ref, cnt_ref,
     per-cluster ``live`` flags (DESIGN.md §3.14) AND into the masks
     after the ``ota_on`` all-pass gate; all-ones = bit-exact legacy."""
     c = n_clusters
-    h_th = params_ref[0, c]
-    ota_on = params_ref[0, c + 1]
-    w = params_ref[0, c + 2]
-    me = params_ref[0, c + 3]
+    ota_on = params_ref[0, c]
+    w = params_ref[0, c + 1]
+    me = params_ref[0, c + 2]
     off = ota_on < 0.5
     x = x_ref[...].astype(jnp.float32)
     out = jnp.zeros_like(x)
     cnt = jnp.zeros_like(x)
     for l in range(n_clusters):              # static unrolled cluster loop
-        live_l = params_ref[0, c + 4 + l]
+        live_l = params_ref[0, c + 3 + l]
         mask = jnp.logical_and(
-            _bits_mask(bits_ref[l],
-                       _pass_probability(params_ref[0, l], h_th), off),
+            _bits_mask(bits_ref[l], params_ref[0, l], off),
             live_l >= 0.5)
         cnt = cnt + mask.astype(jnp.float32)
         mine = jnp.logical_and(mask, me == jnp.float32(l))
@@ -208,7 +210,7 @@ def _ota_mask_count_kernel(x_ref, bits_ref, params_ref, out_ref, cnt_ref,
 def ota_mask_count_pallas(
     x: jax.Array,            # (rows, 128) slab
     bits: jax.Array,         # (C, rows, 128) uint32 — per-cluster streams
-    params: jax.Array,       # (1, 2C+4): [σ²_·, H_th, ota_on, w, me, live_·]
+    params: jax.Array,       # (1, 2C+3): [p_pass_·, ota_on, w, me, live_·]
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     interpret: bool = False,
@@ -216,7 +218,7 @@ def ota_mask_count_pallas(
     """Fused M_me∘(w·x) + Σ_l M_l. Returns (out, cnt) as f32 slabs."""
     n_clusters, rows, lane = bits.shape
     assert lane == LANE and x.shape == (rows, LANE), (bits.shape, x.shape)
-    assert params.shape == (1, 2 * n_clusters + 4), params.shape
+    assert params.shape == (1, 2 * n_clusters + 3), params.shape
     br = _pick_block_rows(rows, n_clusters + 3, block_rows, interpret)
     grid = (rows // br,)
 
@@ -228,7 +230,7 @@ def ota_mask_count_pallas(
         in_specs=[
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
             pl.BlockSpec((n_clusters, br, LANE), lambda i: (0, i, 0)),
-            pl.BlockSpec((1, 2 * n_clusters + 4), lambda i: (0, 0)),
+            pl.BlockSpec((1, 2 * n_clusters + 3), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
@@ -243,44 +245,53 @@ def ota_mask_count_pallas(
     return out, cnt
 
 
+def _client_fold_block(x_ref, bits_ref, params_ref, n_clusters, n_clients,
+                       acc, cnt):
+    """Fold ``n_clusters`` clusters' masked, client-weighted gradients
+    into (acc, cnt), in cluster order. The params row is [p_pass_·,
+    w_··, z_std, ota_on, live_·, N_eff] over these clusters."""
+    c, n = n_clusters, n_clients
+    off = params_ref[0, c + c * n + 1] < 0.5     # traced error-free gate
+    for l in range(c):                       # static unrolled cluster loop
+        wg = jnp.zeros_like(acc)
+        for i in range(n):                   # eq. 3: Σ_n p[l,n]·g[l,n]
+            wg = wg + params_ref[0, c + l * n + i] * (
+                x_ref[l, i].astype(jnp.float32))
+        live_l = params_ref[0, c + c * n + 2 + l]
+        mask = jnp.logical_and(
+            _bits_mask(bits_ref[l], params_ref[0, l], off), live_l >= 0.5)
+        acc = acc + jnp.where(mask, wg, 0.0)
+        cnt = cnt + mask.astype(jnp.float32)
+    return acc, cnt
+
+
+def _client_finish(acc, cnt, nbits, params_ref, n_clusters, n_clients):
+    """AWGN + the guarded |M|·N_eff estimate (eqs. 8-10)."""
+    base = n_clusters + n_clusters * n_clients
+    noise_std = params_ref[0, base]
+    ota_on = params_ref[0, base + 1]
+    n_eff = params_ref[0, base + 2 + n_clusters]
+    y = acc + _box_muller(nbits, 1.0) * noise_std * ota_on
+    return jnp.where(
+        cnt > 0, y / (jnp.maximum(cnt, 1.0) * jnp.maximum(n_eff, 1.0)), 0.0)
+
+
 def _ota_aggregate_client_kernel(x_ref, bits_ref, nbits_ref, params_ref,
                                  out_ref, *, n_clusters, n_clients):
     """Client-folded PS estimator (DESIGN.md §3.12): the MAC loop computes
     Σ_l M_l ∘ (Σ_n p[l,n]·x[l,n]) IN BLOCK from the raw (C, N, ·) gradient
     slab and the (C, N) loss-weight matrix — eqs. 3 + 8-10 in one pass;
     neither the client-weighted tree nor a (C, P) pack copy exists. The
-    weight matrix rides the params block after the per-cluster σ²; the
-    per-cluster ``live`` flags and the traced N_eff denominator
-    (DESIGN.md §3.14) ride after the scalars — live ANDs into the masks
-    AFTER the ``ota_on`` all-pass gate, and live=ones/n_eff=N is the
-    bit-exact full-participation identity."""
-    c, n = n_clusters, n_clients
-    base = c + c * n
-    h_th = params_ref[0, base]
-    noise_std = params_ref[0, base + 1]
-    ota_on = params_ref[0, base + 2]
-    n_eff = params_ref[0, base + 3 + c]
-    off = ota_on < 0.5                       # traced error-free gate
-
-    acc = jnp.zeros_like(out_ref[...], jnp.float32)
-    cnt = jnp.zeros_like(acc)
-    for l in range(n_clusters):              # static unrolled cluster loop
-        wg = jnp.zeros_like(acc)
-        for i in range(n_clients):           # eq. 3: Σ_n p[l,n]·g[l,n]
-            wg = wg + params_ref[0, c + l * n + i] * (
-                x_ref[l, i].astype(jnp.float32))
-        live_l = params_ref[0, base + 3 + l]
-        mask = jnp.logical_and(
-            _bits_mask(bits_ref[l],
-                       _pass_probability(params_ref[0, l], h_th), off),
-            live_l >= 0.5)
-        acc = acc + jnp.where(mask, wg, 0.0)
-        cnt = cnt + mask.astype(jnp.float32)
-
-    z = _box_muller(nbits_ref[...], 1.0) * noise_std * ota_on
-    y = acc + z
-    out_ref[...] = jnp.where(
-        cnt > 0, y / (jnp.maximum(cnt, 1.0) * jnp.maximum(n_eff, 1.0)), 0.0)
+    weight matrix rides the params block after the per-cluster pass
+    probabilities; the per-cluster ``live`` flags and the traced N_eff
+    denominator (DESIGN.md §3.14) ride after the scalars — live ANDs into
+    the masks AFTER the ``ota_on`` all-pass gate, and live=ones/n_eff=N
+    is the bit-exact full-participation identity."""
+    zeros = jnp.zeros(out_ref.shape, jnp.float32)
+    acc, cnt = _client_fold_block(x_ref, bits_ref, params_ref, n_clusters,
+                                  n_clients, zeros, zeros)
+    out_ref[...] = _client_finish(acc, cnt, nbits_ref[...], params_ref,
+                                  n_clusters, n_clients)
 
 
 def _ota_aggregate_client_cblk_kernel(x_ref, bits_ref, nbits_ref, params_ref,
@@ -294,16 +305,8 @@ def _ota_aggregate_client_cblk_kernel(x_ref, bits_ref, nbits_ref, params_ref,
     mul+add into FMA differently around the scratch round-trip; ~1 ulp,
     pinned in tests/test_sectioned.py). The last cluster block adds AWGN
     and finishes the guarded estimate. The per-block params row carries
-    that block's σ²/p/live slices (padded tail clusters arrive live=0,
-    so they contribute nothing)."""
-    c, n = cb, n_clients
-    base = c + c * n
-    h_th = params_ref[0, base]
-    noise_std = params_ref[0, base + 1]
-    ota_on = params_ref[0, base + 2]
-    n_eff = params_ref[0, base + 3 + c]
-    off = ota_on < 0.5
-
+    that block's p_pass/w/live slices (padded tail clusters arrive
+    live=0, so they contribute nothing)."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -311,31 +314,15 @@ def _ota_aggregate_client_cblk_kernel(x_ref, bits_ref, nbits_ref, params_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    acc = acc_ref[...]
-    cnt = cnt_ref[...]
-    for l in range(cb):                      # static unrolled cluster loop
-        wg = jnp.zeros_like(acc)
-        for i in range(n_clients):           # eq. 3: Σ_n p[l,n]·g[l,n]
-            wg = wg + params_ref[0, c + l * n + i] * (
-                x_ref[l, i].astype(jnp.float32))
-        live_l = params_ref[0, base + 3 + l]
-        mask = jnp.logical_and(
-            _bits_mask(bits_ref[l],
-                       _pass_probability(params_ref[0, l], h_th), off),
-            live_l >= 0.5)
-        acc = acc + jnp.where(mask, wg, 0.0)
-        cnt = cnt + mask.astype(jnp.float32)
-    acc_ref[...] = acc
-    cnt_ref[...] = cnt
+    acc_ref[...], cnt_ref[...] = _client_fold_block(
+        x_ref, bits_ref, params_ref, cb, n_clients, acc_ref[...],
+        cnt_ref[...])
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _done():
-        z = _box_muller(nbits_ref[...], 1.0) * noise_std * ota_on
-        y = acc_ref[...] + z
-        out_ref[...] = jnp.where(
-            cnt_ref[...] > 0,
-            y / (jnp.maximum(cnt_ref[...], 1.0) * jnp.maximum(n_eff, 1.0)),
-            0.0)
+        out_ref[...] = _client_finish(acc_ref[...], cnt_ref[...],
+                                      nbits_ref[...], params_ref, cb,
+                                      n_clients)
 
 
 def _client_cluster_block(n_clusters: int, n_clients: int,
@@ -351,19 +338,19 @@ def _client_cluster_block(n_clusters: int, n_clients: int,
 
 
 def _client_params_blocked(params, n_clusters, n_clients, cb, n_cb):
-    """Re-tile the (1, C(N+2)+4) client params row into (n_cb, cb(N+2)+4)
-    per-cluster-block rows of the SAME layout (σ², p, scalars, live,
+    """Re-tile the (1, C(N+2)+3) client params row into (n_cb, cb(N+2)+3)
+    per-cluster-block rows of the SAME layout (p_pass, w, scalars, live,
     N_eff), padding the tail block's clusters with live=0."""
     c, n = n_clusters, n_clients
     pad = n_cb * cb - c
-    sig = jnp.pad(params[0, :c], (0, pad))
-    p = jnp.pad(params[0, c:c + c * n].reshape(c, n), ((0, pad), (0, 0)))
-    live = jnp.pad(params[0, c + c * n + 3:c + c * n + 3 + c], (0, pad))
-    scal = jnp.broadcast_to(params[0, c + c * n:c + c * n + 3].reshape(1, 3),
-                            (n_cb, 3))
+    p_pass = jnp.pad(params[0, :c], (0, pad))
+    w = jnp.pad(params[0, c:c + c * n].reshape(c, n), ((0, pad), (0, 0)))
+    live = jnp.pad(params[0, c + c * n + 2:c + c * n + 2 + c], (0, pad))
+    scal = jnp.broadcast_to(params[0, c + c * n:c + c * n + 2].reshape(1, 2),
+                            (n_cb, 2))
     n_eff = jnp.broadcast_to(params[0, -1].reshape(1, 1), (n_cb, 1))
     return jnp.concatenate([
-        sig.reshape(n_cb, cb), p.reshape(n_cb, cb * n), scal,
+        p_pass.reshape(n_cb, cb), w.reshape(n_cb, cb * n), scal,
         live.reshape(n_cb, cb), n_eff], axis=1)
 
 
@@ -371,8 +358,8 @@ def ota_aggregate_client_pallas(
     x: jax.Array,            # (C, N, rows, 128) f32 — RAW per-client grads
     bits: jax.Array,         # (C, rows, 128) uint32 — gain bits per cluster
     nbits: jax.Array,        # (rows, 128) uint32 — AWGN bits
-    params: jax.Array,       # (1, C·(N+2)+4):
-                             #   [σ²_·, p_··, H_th, z_std, ota_on, live_·, N_eff]
+    params: jax.Array,       # (1, C·(N+2)+3):
+                             #   [p_pass_·, w_··, z_std, ota_on, live_·, N_eff]
     *,
     n_clients: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
@@ -393,7 +380,7 @@ def ota_aggregate_client_pallas(
     assert lane == LANE and n_cl == n_clients, (x.shape, n_clients)
     assert bits.shape == (n_clusters, rows, LANE), (bits.shape, x.shape)
     assert nbits.shape == (rows, LANE), nbits.shape
-    assert params.shape == (1, n_clusters * (n_clients + 2) + 4), params.shape
+    assert params.shape == (1, n_clusters * (n_clients + 2) + 3), params.shape
     cb = (cluster_block if cluster_block
           else _client_cluster_block(n_clusters, n_clients, interpret))
     if cb < n_clusters:
@@ -412,8 +399,10 @@ def ota_aggregate_client_pallas(
                              lambda i, j: (j, 0, i, 0)),
                 pl.BlockSpec((cb, br, LANE), lambda i, j: (j, i, 0)),
                 pl.BlockSpec((br, LANE), lambda i, j: (i, 0)),
-                pl.BlockSpec((1, cb * (n_clients + 2) + 4),
-                             lambda i, j: (j, 0)),
+                # one (1, K) row per cluster block: the leading block dim
+                # is squeezed so the last two dims span the whole array
+                pl.BlockSpec((None, 1, cb * (n_clients + 2) + 3),
+                             lambda i, j: (j, 0, 0)),
             ],
             out_specs=pl.BlockSpec((br, LANE), lambda i, j: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
@@ -422,7 +411,7 @@ def ota_aggregate_client_pallas(
             interpret=interpret,
         )(x, bits, nbits,
           _client_params_blocked(params.astype(jnp.float32), n_clusters,
-                                 n_clients, cb, n_cb))
+                                 n_clients, cb, n_cb)[:, None, :])
 
     # C·N grad blocks + C bits blocks + noise + out resident at once
     br = _pick_block_rows(rows, n_clusters * (n_clients + 1) + 2,
@@ -439,7 +428,7 @@ def ota_aggregate_client_pallas(
                          lambda i: (0, 0, i, 0)),
             pl.BlockSpec((n_clusters, br, LANE), lambda i: (0, i, 0)),
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_clusters * (n_clients + 2) + 4),
+            pl.BlockSpec((1, n_clusters * (n_clients + 2) + 3),
                          lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, LANE), lambda i: (i, 0)),
@@ -489,16 +478,14 @@ def ota_channel_pallas(
 def _ota_aggregate_kernel(wg_ref, bits_ref, nbits_ref, params_ref, out_ref,
                           *, n_clusters, n_clients):
     c = n_clusters
-    h_th = params_ref[0, c]
-    noise_std = params_ref[0, c + 1]
-    ota_on = params_ref[0, c + 2]
+    noise_std = params_ref[0, c]
+    ota_on = params_ref[0, c + 1]
     off = ota_on < 0.5                       # traced error-free gate
 
     acc = jnp.zeros_like(out_ref[...], jnp.float32)
     cnt = jnp.zeros_like(acc)
     for l in range(n_clusters):              # static unrolled cluster loop
-        mask = _bits_mask(bits_ref[l],
-                          _pass_probability(params_ref[0, l], h_th), off)
+        mask = _bits_mask(bits_ref[l], params_ref[0, l], off)
         acc = acc + jnp.where(mask, wg_ref[l].astype(jnp.float32), 0.0)
         cnt = cnt + mask.astype(jnp.float32)
 
@@ -538,17 +525,15 @@ def _fused_body(wg, bits_fn, nbits_fn, params_ref, n_clusters, n_clients,
     """Accumulate one row-chunk [r0, r0+br) over the cluster axis and
     finish it with AWGN + the guarded |M|·N estimate (eqs. 8-10)."""
     c = n_clusters
-    h_th = params_ref[0, c]
-    noise_std = params_ref[0, c + 1]
-    ota_on = params_ref[0, c + 2]
+    noise_std = params_ref[0, c]
+    ota_on = params_ref[0, c + 1]
     off = ota_on < 0.5
 
     acc = jnp.zeros((br, LANE), jnp.float32)
     cnt = jnp.zeros_like(acc)
     for l in range(n_clusters):              # static unrolled cluster loop
         bits = bits_fn(l)[:br]
-        mask = _bits_mask(bits, _pass_probability(params_ref[0, l], h_th),
-                          off)
+        mask = _bits_mask(bits, params_ref[0, l], off)
         acc = acc + jnp.where(mask, wg(l, r0, br).astype(jnp.float32), 0.0)
         cnt = cnt + mask.astype(jnp.float32)
     z = _box_muller(nbits_fn()[:br], 1.0) * noise_std * ota_on
@@ -653,9 +638,8 @@ def _ota_aggregate_tpu_kernel(wg_ref, keys_ref, params_ref, out_ref,
     however large C grows; the last cluster block adds AWGN and writes
     the guarded estimate."""
     c = n_clusters
-    h_th = params_ref[0, c]
-    noise_std = params_ref[0, c + 1]
-    ota_on = params_ref[0, c + 2]
+    noise_std = params_ref[0, c]
+    ota_on = params_ref[0, c + 1]
     off = ota_on < 0.5
     i = pl.program_id(0)
     j = pl.program_id(1)
@@ -671,12 +655,11 @@ def _ota_aggregate_tpu_kernel(wg_ref, keys_ref, params_ref, out_ref,
         l = j * cb + l_loc                   # traced GLOBAL cluster index
         bits = _hw_chunk_bits(keys_ref[0], l, i)
         valid = l < n_clusters               # padded tail cluster block
-        sig_l = jnp.sum(jnp.where(
+        p_l = jnp.sum(jnp.where(
             jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
             == jnp.minimum(l, c - 1),
             params_ref[0, :c].reshape(c, 1), 0.0))
-        mask = jnp.logical_and(
-            _bits_mask(bits, _pass_probability(sig_l, h_th), off), valid)
+        mask = jnp.logical_and(_bits_mask(bits, p_l, off), valid)
         acc = acc + jnp.where(mask, wg_ref[l_loc].astype(jnp.float32), 0.0)
         cnt = cnt + mask.astype(jnp.float32)
     acc_ref[...] = acc
@@ -695,7 +678,7 @@ def _ota_aggregate_tpu_kernel(wg_ref, keys_ref, params_ref, out_ref,
 def ota_aggregate_fused_pallas(
     wg: jax.Array,           # (C, rows, 128) f32 — ONE section's slab
     keys: jax.Array,         # (2, 2) uint32 threefry keys [gains, AWGN]
-    params: jax.Array,       # (1, C+3) f32: [σ²_0..σ²_{C-1}, H_th, z_std, ota_on]
+    params: jax.Array,       # (1, C+2) f32: [p_pass_0..p_pass_{C-1}, z_std, ota_on]
     *,
     n_clients: int,
     interpret: bool = False,
@@ -722,7 +705,7 @@ def ota_aggregate_fused_pallas(
                 pl.BlockSpec((n_clusters, rows, LANE), lambda i: (0, 0, 0)),
                 pl.BlockSpec((n_clusters, rows, LANE), lambda i: (0, 0, 0)),
                 pl.BlockSpec((rows, LANE), lambda i: (0, 0)),
-                pl.BlockSpec((1, n_clusters + 3), lambda i: (0, 0)),
+                pl.BlockSpec((1, n_clusters + 2), lambda i: (0, 0)),
             ],
             out_specs=pl.BlockSpec((rows, LANE), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
@@ -743,7 +726,7 @@ def ota_aggregate_fused_pallas(
             in_specs=[
                 pl.BlockSpec((n_clusters, rows, LANE), lambda i: (0, 0, 0)),
                 pl.BlockSpec((2, 2), lambda i: (0, 0)),
-                pl.BlockSpec((1, n_clusters + 3), lambda i: (0, 0)),
+                pl.BlockSpec((1, n_clusters + 2), lambda i: (0, 0)),
             ],
             out_specs=pl.BlockSpec((rows, LANE), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
@@ -769,7 +752,7 @@ def ota_aggregate_fused_pallas(
             pl.BlockSpec((cb, CHUNK_ROWS, LANE),
                          lambda i, j: (j, i, 0)),
             pl.BlockSpec((2, 2), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, n_clusters + 3), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, n_clusters + 2), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((CHUNK_ROWS, LANE), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
@@ -783,7 +766,7 @@ def ota_aggregate_pallas(
     wg: jax.Array,           # (C, rows, 128) f32 — Σ_i p_i g_i per cluster
     bits: jax.Array,         # (C, rows, 128) uint32 — gain bits per cluster
     nbits: jax.Array,        # (rows, 128) uint32 — AWGN bits
-    params: jax.Array,       # (1, C+3) f32: [σ²_0..σ²_{C-1}, H_th, z_std, ota_on]
+    params: jax.Array,       # (1, C+2) f32: [p_pass_0..p_pass_{C-1}, z_std, ota_on]
     *,
     n_clients: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
@@ -806,7 +789,7 @@ def ota_aggregate_pallas(
             pl.BlockSpec((n_clusters, br, LANE), lambda i: (0, i, 0)),
             pl.BlockSpec((n_clusters, br, LANE), lambda i: (0, i, 0)),
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_clusters + 3), lambda i: (0, 0)),
+            pl.BlockSpec((1, n_clusters + 2), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),

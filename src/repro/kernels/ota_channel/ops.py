@@ -27,7 +27,7 @@ from repro.kernels.ota_channel.kernel import (
 )
 from repro.kernels.ota_channel.ref import (
     bits_to_mask, ota_aggregate_client_ref, ota_aggregate_slab_ref,
-    ota_channel_ref, ota_stream_fold_ref,
+    ota_channel_ref, ota_stream_fold_ref, pass_probability,
 )
 from repro.kernels.slab import (
     LANE, ROW_QUANTUM, flat_to_slab, on_tpu, pad_to_lanes,
@@ -106,10 +106,9 @@ def ota_mask_weight_apply(x: jax.Array, bits: jax.Array, sigma2, h_th,
     outs, masks = [], []
     if main:
         params = jnp.stack([
-            jnp.asarray(sigma2, jnp.float32).reshape(()),
-            jnp.asarray(h_th, jnp.float32).reshape(()),
+            pass_probability(sigma2, h_th).reshape(()),
             jnp.asarray(ota_on, jnp.float32).reshape(()),
-            w.reshape(())]).reshape(1, 4)
+            w.reshape(())]).reshape(1, 3)
         o, m = ota_mask_weight_pallas(
             jax.lax.slice(flat, (0,), (main,)).reshape(main // LANE, LANE),
             jax.lax.slice(bits, (0,), (main,)).reshape(main // LANE, LANE),
@@ -183,14 +182,13 @@ def ota_client_fold_apply(g: jax.Array, p: jax.Array, bits: jax.Array,
                else jnp.maximum(jnp.asarray(n_eff, jnp.float32), 1.0)
                .reshape(()))
     params = jnp.concatenate([
-        sig,
+        pass_probability(sig, h_th),
         p32.reshape(n_clusters * n_clients),
-        jnp.stack([jnp.asarray(h_th, jnp.float32).reshape(()),
-                   jnp.asarray(noise_std, jnp.float32).reshape(()),
+        jnp.stack([jnp.asarray(noise_std, jnp.float32).reshape(()),
                    jnp.asarray(ota_on, jnp.float32).reshape(())]),
         live_v,
         n_eff_v.reshape(1),
-    ]).reshape(1, n_clusters * (n_clients + 2) + 4)
+    ]).reshape(1, n_clusters * (n_clients + 2) + 3)
     main = n - n % ROW_QUANTUM
     outs = []
     if main:
@@ -306,13 +304,12 @@ def ota_mask_count_apply(x: jax.Array, bits_all: jax.Array, me, sigma2_all,
               else jnp.asarray(live_all, jnp.float32).reshape(n_clusters))
     main = n - n % ROW_QUANTUM
     params = jnp.concatenate([
-        sig.reshape(n_clusters),
-        jnp.stack([jnp.asarray(h_th, jnp.float32).reshape(()),
-                   jnp.asarray(ota_on, jnp.float32).reshape(()),
+        pass_probability(sig.reshape(n_clusters), h_th),
+        jnp.stack([jnp.asarray(ota_on, jnp.float32).reshape(()),
                    w.reshape(()),
                    jnp.asarray(me, jnp.float32).reshape(())]),
         live_v,
-    ]).reshape(1, 2 * n_clusters + 4)
+    ]).reshape(1, 2 * n_clusters + 3)
     outs, cnts = [], []
     if main:
         o, c = ota_mask_count_pallas(
@@ -349,12 +346,12 @@ def ota_channel_reference(x: jax.Array, key: jax.Array, sigma2, h_th,
 
 
 def _channel_params_block(sigma2, h_th, noise_std, ota_on, c: int):
+    """The aggregate kernels' (1, C+2) row: [p_pass_·, z_std, ota_on]."""
     return jnp.concatenate([
-        jnp.asarray(sigma2, jnp.float32).reshape(c),
-        jnp.asarray(h_th, jnp.float32).reshape(1),
+        pass_probability(jnp.asarray(sigma2, jnp.float32).reshape(c), h_th),
         jnp.asarray(noise_std, jnp.float32).reshape(1),
         jnp.asarray(ota_on, jnp.float32).reshape(1),
-    ]).reshape(1, c + 3)
+    ]).reshape(1, c + 2)
 
 
 def _ota_aggregate_fused_impl(wg, section_keys, section_lens, sigma2, h_th,

@@ -46,9 +46,13 @@ def bits_to_mask(bits: jax.Array, sigma2, h_th, ota_on=1.0) -> jax.Array:
     passing entries), and 1{|H|² ≥ H_th} for H ~ N(0, σ²) is exactly
     Bernoulli(erfc(√(H_th/2σ²))) — so ``u < p_pass`` on the raw uniform
     draw is the identical distribution at one compare per entry instead
-    of a Box-Muller log/sqrt/cos chain. ``ota_on < 0.5`` forces all-pass.
+    of a Box-Muller log/sqrt/cos chain. The uniform is the word's top 24
+    bits over 2²⁴ — exact in f32, and the form the compiled kernels use,
+    so kernel and oracle masks agree bit for bit on the same stream.
+    ``ota_on < 0.5`` forces all-pass.
     """
-    u = bits.astype(jnp.float32) * jnp.float32(2.0 ** -32)
+    u = (bits >> 8).astype(jnp.int32).astype(jnp.float32) * jnp.float32(
+        2.0 ** -24)
     p = pass_probability(sigma2, h_th)
     return jnp.logical_or(u < p, jnp.asarray(ota_on, jnp.float32) < 0.5)
 

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (assignment §MULTI-POD DRY-RUN).
 
 For every (architecture x input shape x mesh) combination, lower + compile
@@ -15,11 +12,14 @@ the real step function on the production mesh with ShapeDtypeStruct inputs
 Usage:
     python -m repro.launch.dryrun --arch starcoder2-3b --shape train_4k
     python -m repro.launch.dryrun --arch all --shape all [--multi-pod both]
-Results land in results/dryrun/<arch>__<shape>__<mesh>.json.
+Results land in results/dryrun/<arch>__<shape>__<mesh>.json. Run as a
+script, it forces 512 host devices (``XLA_FLAGS``) before JAX starts;
+importing the module changes nothing.
 """
 
 import argparse
 import json
+import os
 import time
 import traceback
 from typing import Optional
@@ -315,4 +315,5 @@ def main():
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

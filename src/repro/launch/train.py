@@ -1,15 +1,19 @@
-"""Real training driver (CPU-scale meshes; the production mesh path is
-exercised by dryrun.py on this container).
+"""Training CLI: the distributed HOTA-FedGradNorm round on a device mesh.
 
-Runs HOTA-FedGradNorm training of any --arch's reduced (smoke) config on a
-debug mesh using host devices, with checkpointing and metric logging:
+Runs any --arch's reduced (smoke) config on a (clusters, clients, model)
+mesh of the visible devices, with checkpointing and metric logging. On
+one TPU chip:
+
+    PYTHONPATH=src python -m repro.launch.train --mesh 1,1,1 --steps 5
+
+On CPU, force host devices for a larger mesh:
 
     XLA_FLAGS="--xla_force_host_platform_device_count=8" \\
     PYTHONPATH=src python -m repro.launch.train --arch starcoder2-3b \\
         --steps 50 --mesh 2,2,2
 
-(mesh = clusters,clients,model). For the paper's own experiment use
-examples/paper_reproduction.py, which runs the faithful C=10/N=3 simulator.
+For the paper's own experiment use the faithful C=10/N=3 simulator
+(``repro.core.paper_setup``, ``benchmarks/fig*``).
 """
 from __future__ import annotations
 
@@ -25,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import save_checkpoint
 from repro.checkpoint.store import latest_step, restore_checkpoint
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import FLConfig, TrainConfig
 from repro.configs import ALIASES, get_smoke_config
 from repro.core.hota_step import make_hota_train_step
@@ -76,7 +81,9 @@ class RoundGuard:
                                   shardings=self.shardings), True
 
 
-def main():
+def main(argv=None):
+    """Train for ``--steps`` rounds; returns the last round's metrics as
+    floats. ``argv`` defaults to the command line."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-3b")
     ap.add_argument("--steps", type=int, default=50)
@@ -116,6 +123,8 @@ def main():
                     help="save the FULL train state every K rounds "
                          "(0 = only the final omega snapshot)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--log-every", type=int, default=10,
+                    help="print metrics every K rounds (and the last)")
     # fault injection (DESIGN.md §3.14) — traced knobs, one static gate
     ap.add_argument("--faults", action="store_true",
                     help="enable the fault-injection round path")
@@ -142,14 +151,16 @@ def main():
                          "(default ~/.cache/repro/layout_tune.json or "
                          "$REPRO_LAYOUT_CACHE; pass '' to disable "
                          "persistence)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     shape = tuple(int(x) for x in args.mesh.split(","))
     n_dev = int(np.prod(shape))
     devs = np.array(jax.devices())
-    assert devs.size >= n_dev, (
-        f"need {n_dev} devices; set "
-        f'XLA_FLAGS="--xla_force_host_platform_device_count={n_dev}"')
+    if devs.size < n_dev:
+        raise SystemExit(
+            f"--mesh {args.mesh} needs {n_dev} devices; found {devs.size}: "
+            f"{[f'{d.platform}:{d.device_kind}' for d in devs]}")
     mesh = Mesh(devs[:n_dev].reshape(shape), ("cluster", "client", "model"))
 
     cfg = get_smoke_config(ALIASES.get(args.arch, args.arch))
@@ -208,13 +219,16 @@ def main():
         cfg.vocab_size, n_clients_total * args.batch_per_client,
         args.seq_len, seed=args.seed)
     jstep = jax.jit(step_fn)
+    run_key = jax.random.PRNGKey(args.seed + 1)
 
+    m = {}
     t0 = time.time()
     for step in range(args.steps):
         toks, labs = next(batches)
         toks = jax.device_put(jnp.asarray(toks), NamedSharding(mesh, batch_spec[0]))
         labs = jax.device_put(jnp.asarray(labs), NamedSharding(mesh, batch_spec[1]))
-        state, m = jstep(state, toks, labs, jax.random.PRNGKey(args.seed + 1))
+        state, m = jstep(state, toks, labs,
+                         jax.random.fold_in(run_key, step))
         if guard is not None:
             state, restored = guard.observe(m["skipped"], state)
             if restored:
@@ -227,7 +241,7 @@ def main():
             save_checkpoint(args.ckpt_dir, int(state.step),
                             jax.tree.map(np.asarray, state),
                             {"arch": args.arch, "kind": "full_state"})
-        if step % 10 == 0 or step == args.steps - 1:
+        if step % args.log_every == 0 or step == args.steps - 1:
             faulty = (f" part {float(m['n_participants']):.0f}"
                       f" skip {float(m['skipped']):.0f}"
                       if args.faults else "")
@@ -240,6 +254,7 @@ def main():
                                jax.tree.map(np.asarray, state.omega),
                                {"arch": args.arch})
         print("checkpoint:", path)
+    return {k: float(v) for k, v in m.items()}
 
 
 if __name__ == "__main__":

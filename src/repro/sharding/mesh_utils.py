@@ -73,21 +73,6 @@ def total_clients(mesh: Mesh) -> int:
 # scenario axis (sharded sweep banks — DESIGN.md §3.8)
 # --------------------------------------------------------------------------
 
-def shard_map_compat(f, mesh, in_specs, out_specs, axis_names):
-    """jax.shard_map appeared in newer jax; fall back to the experimental
-    API. The fallback goes fully manual (no ``auto`` axes): on old
-    jax/jaxlib, axis_index inside a partially-manual region lowers to a
-    PartitionId op the SPMD partitioner rejects."""
-    import jax
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def scenario_axis_size(mesh: Mesh) -> int:
     """Device count along the scenario axis of a sweep mesh."""
     assert SCENARIO_AXIS in mesh.axis_names, mesh

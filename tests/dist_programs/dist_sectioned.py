@@ -37,7 +37,6 @@ from repro.core.hota_slab import (
 from repro.core.hota_step import make_hota_train_step
 from repro.models.model import build_model
 from repro.models.params import abstract_params, init_params, logical_axes
-from repro.sharding.mesh_utils import shard_map_compat
 
 C, N, B, D = 2, 2, 4, 256
 MAXC = 8
@@ -103,11 +102,11 @@ def build_bwd(count_mode, max_section_rows, sectioned):
         (g_shards,) = vjp(g_loc)
         return g_shards
 
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         local_bwd, mesh=mesh,
         in_specs=(spec_in, P("cluster", "client")),
         out_specs=out_specs,
-        axis_names={"cluster", "client"})), packer
+        axis_names={"cluster", "client"}, check_vma=False)), packer
 
 
 # --- 1. sectioned ≡ full-slab backward, BITWISE, composed modes -------------

@@ -40,7 +40,6 @@ from repro.core.hota_slab import (
 from repro.core.hota_step import make_hota_train_step
 from repro.models.model import build_model
 from repro.models.params import abstract_params, init_params, logical_axes
-from repro.sharding.mesh_utils import shard_map_compat
 
 C, N, B, D = 2, 2, 4, 256
 MAXC = 8
@@ -156,11 +155,11 @@ out_specs = jax.tree.unflatten(
          for d in range(len(l.shape))]) if _fsdp_axis_full(ax) >= 0 else P()
      for l, ax in zip(jax.tree.leaves(template), axes_list)])
 
-jf = jax.jit(shard_map_compat(
+jf = jax.jit(jax.shard_map(
     local_bwd, mesh=mesh,
     in_specs=(spec_in, P("cluster", "client")),
     out_specs=out_specs,
-    axis_names={"cluster", "client"}))
+    axis_names={"cluster", "client"}, check_vma=False))
 ghat = jf(g_dev_major, p_dev)
 
 wg = jax.tree.map(lambda l: jnp.einsum("cn,cn...->c...", p_dev, l), g_full)

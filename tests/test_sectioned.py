@@ -349,15 +349,15 @@ def _client_kernel_inputs(c=5, n=2, rows=16, key=3):
                            (c, rows, K.LANE), jnp.uint32)
     nbits = jax.random.bits(jax.random.fold_in(k, 2),
                             (rows, K.LANE), jnp.uint32)
-    sig = jnp.linspace(0.4, 1.6, c, dtype=jnp.float32)
+    p_pass = jnp.linspace(0.2, 0.9, c, dtype=jnp.float32)
     p = jax.random.uniform(jax.random.fold_in(k, 4), (c, n), jnp.float32,
                            0.5, 1.5)
     live = jnp.ones((c,), jnp.float32).at[1].set(0.0)
     params = jnp.concatenate([
-        sig, p.reshape(c * n),
-        jnp.asarray([0.3, 0.7, 1.0], jnp.float32),      # H_th, z_std, on
+        p_pass, p.reshape(c * n),
+        jnp.asarray([0.7, 1.0], jnp.float32),           # z_std, on
         live, jnp.asarray([float(n)], jnp.float32),
-    ]).reshape(1, c * (n + 2) + 4)
+    ]).reshape(1, c * (n + 2) + 3)
     return x, bits, nbits, params
 
 
@@ -388,18 +388,18 @@ def test_auto_cluster_block_fits_budget():
 
 
 def test_client_params_blocked_layout():
-    """The re-tiled per-block params rows carry the same (σ², p, scalars,
-    live, N_eff) layout with live=0 padding on the tail block."""
+    """The re-tiled per-block params rows carry the same (p_pass, w,
+    scalars, live, N_eff) layout with live=0 padding on the tail block."""
     _, _, _, params = _client_kernel_inputs(c=5, n=2)
     cb, n_cb = 2, 3
     rows = K._client_params_blocked(params, 5, 2, cb, n_cb)
-    assert rows.shape == (n_cb, cb * (2 + 2) + 4)
-    sig = np.asarray(params[0, :5])
-    live = np.asarray(params[0, 5 + 10 + 3:5 + 10 + 3 + 5])
-    got_sig = np.asarray(rows[:, :cb]).reshape(-1)
-    got_live = np.asarray(rows[:, cb * 3 + 3:cb * 3 + 3 + cb]).reshape(-1)
-    np.testing.assert_array_equal(got_sig[:5], sig)
-    np.testing.assert_array_equal(got_sig[5:], 0.0)
+    assert rows.shape == (n_cb, cb * (2 + 2) + 3)
+    p_pass = np.asarray(params[0, :5])
+    live = np.asarray(params[0, 5 + 10 + 2:5 + 10 + 2 + 5])
+    got_p = np.asarray(rows[:, :cb]).reshape(-1)
+    got_live = np.asarray(rows[:, cb * 3 + 2:cb * 3 + 2 + cb]).reshape(-1)
+    np.testing.assert_array_equal(got_p[:5], p_pass)
+    np.testing.assert_array_equal(got_p[5:], 0.0)
     np.testing.assert_array_equal(got_live[:5], live)
     np.testing.assert_array_equal(got_live[5:], 0.0)    # padded dead
     np.testing.assert_array_equal(np.asarray(rows[:, -1]), 2.0)
@@ -447,7 +447,7 @@ def test_tpu_fused_kernel_traces():
     c, rows = 3, 2 * K.CHUNK_ROWS
     wg = jax.ShapeDtypeStruct((c, rows, K.LANE), jnp.float32)
     keys = jax.ShapeDtypeStruct((2, 2), jnp.uint32)
-    params = jax.ShapeDtypeStruct((1, c + 3), jnp.float32)
+    params = jax.ShapeDtypeStruct((1, c + 2), jnp.float32)
     out = jax.eval_shape(
         lambda w, k, pr: K.ota_aggregate_fused_pallas(
             w, k, pr, n_clients=2, interpret=False), wg, keys, params)

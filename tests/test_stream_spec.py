@@ -70,36 +70,40 @@ FOLD_VALUES = {
     "PACKED_SECTION_FOLD_2": 0x7FFF0102,
 }
 
+# Every golden below is drawn under JAX's default threefry, which is the
+# partitionable one since JAX 0.5 (digests taken with JAX 0.9.0): a
+# sharded draw stays local to its shard. The spec sets no override.
+
 # golden first u32 of the cluster-0 gain stream under PRNGKey(0):
 # stream_range_bits(section_gain_key(key, fold, 0), 0, 4)[0]
 GOLDEN_GAIN_U32 = {
-    "NOISE_FOLD": 0x0B686A7C,
-    "PACKED_HEAD_FOLD": 0xE2E0D19F,
-    "PACKED_TAIL_FOLD": 0x1BEF84B4,
-    "SIM_CHAN_FOLD": 0x3A418B11,
-    "PART_FOLD": 0xB89EA6A5,
-    "SAMPLE_FOLD": 0xDEABE9ED,
-    "PACKED_FINAL_FOLD": 0x3AEBBD34,
-    "PACKED_OMEGA_FOLD": 0x755B8C4B,
-    "PACKED_SECTION_FOLD_0": 0x0B3450D2,
-    "PACKED_SECTION_FOLD_1": 0xAB81093C,
-    "PACKED_SECTION_FOLD_2": 0x96C21E23,
+    "NOISE_FOLD": 0xC4D018C7,
+    "PACKED_HEAD_FOLD": 0x07CD6A8E,
+    "PACKED_TAIL_FOLD": 0x79A67452,
+    "SIM_CHAN_FOLD": 0x5CCC7490,
+    "PART_FOLD": 0xF8B2A85D,
+    "SAMPLE_FOLD": 0xBAE01355,
+    "PACKED_FINAL_FOLD": 0x8163EC7B,
+    "PACKED_OMEGA_FOLD": 0x889D730E,
+    "PACKED_SECTION_FOLD_0": 0xC679C106,
+    "PACKED_SECTION_FOLD_1": 0xBDE68ED0,
+    "PACKED_SECTION_FOLD_2": 0x08EC73FE,
 }
 
 # golden first u32 of the per-fold noise stream under PRNGKey(0):
 # stream_range_bits(section_noise_key(key, fold), 0, 4)[0]
 GOLDEN_NOISE_U32 = {
-    "NOISE_FOLD": 0xD9CF7EC3,
-    "PACKED_HEAD_FOLD": 0x32DFF2BA,
-    "PACKED_TAIL_FOLD": 0xF4999DB8,
-    "SIM_CHAN_FOLD": 0xE5AB619D,
-    "PART_FOLD": 0x8EEA33EF,
-    "SAMPLE_FOLD": 0xADDA1262,
-    "PACKED_FINAL_FOLD": 0x8007622F,
-    "PACKED_OMEGA_FOLD": 0x5032934A,
-    "PACKED_SECTION_FOLD_0": 0xF9C4A3E8,
-    "PACKED_SECTION_FOLD_1": 0x3E08D583,
-    "PACKED_SECTION_FOLD_2": 0x587C0806,
+    "NOISE_FOLD": 0xF3DCCBE8,
+    "PACKED_HEAD_FOLD": 0x73EC0EF3,
+    "PACKED_TAIL_FOLD": 0x7820E606,
+    "SIM_CHAN_FOLD": 0x7C91C72F,
+    "PART_FOLD": 0xE3797962,
+    "SAMPLE_FOLD": 0xECB34803,
+    "PACKED_FINAL_FOLD": 0xCDB9FA54,
+    "PACKED_OMEGA_FOLD": 0x859F44BF,
+    "PACKED_SECTION_FOLD_0": 0x958B3077,
+    "PACKED_SECTION_FOLD_1": 0x1F60CBA1,
+    "PACKED_SECTION_FOLD_2": 0x03C3E473,
 }
 
 # aux-class salts (DESIGN.md §4 table): folded off keys that never meet
@@ -133,14 +137,14 @@ AUX_VALUES = {
 # golden first u32 of bits(fold_in(PRNGKey(0), salt), (4,))[0] — the raw
 # derived-key digest (aux salts have no section/noise stream schedule)
 GOLDEN_AUX_U32 = {
-    "PART_DROP_FOLD": 0xA93D9CF0,
-    "PART_BLACK_FOLD": 0xBBE44D07,
-    "PART_STRAG_FOLD": 0x369464D0,
-    "FINAL_INIT_FOLD": 0xA42B7666,
-    "SAMPLE_INIT_FOLD": 0x58C7EA79,
-    "TUNE_PROBE_FOLD": 0x6B9484A4,
-    "REGION_SALT": 0x214AA0B2,
-    "HOTA_MASK_SALT": 0x47F7A328,
+    "PART_DROP_FOLD": 0xD7A1E7E1,
+    "PART_BLACK_FOLD": 0x01DE0365,
+    "PART_STRAG_FOLD": 0xE706EF41,
+    "FINAL_INIT_FOLD": 0x799CA2BA,
+    "SAMPLE_INIT_FOLD": 0x89B358BA,
+    "TUNE_PROBE_FOLD": 0x6D60FF09,
+    "REGION_SALT": 0x15401035,
+    "HOTA_MASK_SALT": 0x2318DD61,
 }
 
 # the dist backward's per-klass region-key salts — collision-free dict
@@ -255,6 +259,14 @@ def test_derived_stream_keys_pairwise_disjoint():
 
 
 # --------------------------------------------------------- golden digests
+def test_threefry_is_partitionable():
+    """The goldens assume JAX's default partitionable threefry; a process
+    that turns it off draws different bits from every stream."""
+    assert jax.config.jax_threefry_partitionable, (
+        "jax_threefry_partitionable is off — every §4 stream differs from "
+        "the spec'd draw (DESIGN.md §4)")
+
+
 @pytest.mark.parametrize("name", FOLD_NAMES)
 def test_golden_gain_first_u32(name):
     got = int(ota.stream_range_bits(
@@ -338,7 +350,7 @@ def test_sample_draw_golden():
     SAMPLE_FOLD — golden-pinned so a re-keying shows up by name."""
     ids = ota.draw_client_sample(KEY, 2, 3, 7)
     assert ids.dtype == jnp.int32
-    assert ids.tolist() == [[0, 6, 2], [5, 4, 6]], (
+    assert ids.tolist() == [[0, 5, 2], [4, 0, 0]], (
         f"SAMPLE_FOLD client-id draw drifted: {ids.tolist()} — the "
         f"sample stream was re-keyed (DESIGN.md §4)")
     assert bool(jnp.all((ids >= 0) & (ids < 7)))

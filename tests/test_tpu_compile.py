@@ -1,0 +1,151 @@
+"""The main-path Pallas kernels compile for a described TPU v5e.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses:
+unlowerable primitives, casts it has no rule for, blocks that break the
+(8, 128) tiling rule, VMEM overruns. Each test here lowers one kernel
+through its public wrapper for one chip of a described ``v5e:2x2``
+topology and compiles it with the TPU compiler — no chip is attached,
+nothing runs; a passing compile is not a chip run.
+
+Shapes are the paper MLP's (Table I, C=10 clusters × N=3 clients) at its
+largest leaf, ``fc2.w`` (1024 × 2048 = 16384 slab rows of 128 lanes).
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, and every pytest worker
+imports every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.masked_gradnorm.ops import masked_gradnorm
+from repro.kernels.ota_channel.kernel import (
+    ota_aggregate_client_pallas, ota_aggregate_fused_pallas,
+)
+from repro.kernels.ota_channel.ops import (
+    _channel_params_block, ota_aggregate, ota_client_fold_apply,
+    ota_mask_count_apply, ota_mask_weight_apply,
+)
+from repro.kernels.slab import LANE
+
+C, N = 10, 3                      # paper topology
+ROWS = 1024 * 2048 // LANE        # largest paper-MLP leaf as a slab
+P = ROWS * LANE
+FINAL_P = 512 * 256 + 256         # ω̃ (final shared layer) entries
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip cannot be read back without one:
+    keep the persistent cache out of these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+pytestmark = pytest.mark.usefixtures("no_persistent_cache")
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower ``fn`` on the described chip, compile, and check that the
+    program holds a Mosaic kernel (not an XLA fallback)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+F32, U32 = jnp.float32, jnp.uint32
+
+
+def test_client_fold_compiles(one_chip):
+    """The simulator's hot path: eqs. 3 + 8-10 in one kernel per leaf."""
+    def fn(g, p, bits, nbits, sig):
+        return ota_client_fold_apply(g, p, bits, nbits, sig, 3.2e-2, 1.0,
+                                     1.0, N, interpret=False, impl="pallas")
+    _compile(fn, one_chip, ((C, N, 1024, 2048), F32), ((C, N), F32),
+             ((C, P), U32), ((P,), U32), ((C,), F32))
+
+
+def test_client_fold_cluster_blocked_compiles(one_chip):
+    """The C-blocked variant (auto-selected once C·(N+1) outgrows VMEM):
+    its per-block params row must satisfy the block tiling rule."""
+    def fn(x, bits, nbits, params):
+        return ota_aggregate_client_pallas(x, bits, nbits, params,
+                                           n_clients=N, interpret=False,
+                                           cluster_block=2)
+    _compile(fn, one_chip, ((C, N, ROWS, LANE), F32), ((C, ROWS, LANE), U32),
+             ((ROWS, LANE), U32), ((1, C * (N + 2) + 3), F32))
+
+
+def test_fused_hw_prng_compiles(one_chip):
+    """The in-kernel hardware-PRNG aggregate (C-blocked grid)."""
+    def fn(wg, keys, sig):
+        params = _channel_params_block(sig, 3.2e-2, 1.0, 1.0, C)
+        return ota_aggregate_fused_pallas(wg, keys, params, n_clients=N,
+                                          interpret=False)
+    _compile(fn, one_chip, ((C, ROWS, LANE), F32), ((2, 2), U32),
+             ((C,), F32))
+
+
+def test_supplied_bits_aggregate_compiles(one_chip):
+    def fn(wg, bits, nbits, sig):
+        return ota_aggregate(wg, bits, nbits, sig, 3.2e-2, 1.0, 1.0,
+                             n_clients=N, interpret=False)
+    _compile(fn, one_chip, ((C, P), F32), ((C, P), U32), ((P,), U32),
+             ((C,), F32))
+
+
+def test_mask_weight_compiles(one_chip):
+    """The distributed backward's per-leaf w·g·M kernel."""
+    def fn(x, bits, w):
+        return ota_mask_weight_apply(x, bits, 0.7, 3.2e-2, 1.0, w,
+                                     interpret=False, impl="pallas")
+    _compile(fn, one_chip, ((1024, 2048), F32), ((P,), U32), ((), F32))
+
+
+def test_mask_count_compiles(one_chip):
+    """The distributed backward's collective-free |M| count kernel."""
+    def fn(x, bits_all, sig, w):
+        return ota_mask_count_apply(x, bits_all, 1, sig, 3.2e-2, 1.0, w,
+                                    interpret=False, impl="pallas")
+    _compile(fn, one_chip, ((1024, 2048), F32), ((C, P), U32), ((C,), F32),
+             ((), F32))
+
+
+def test_masked_gradnorm_compiles(one_chip):
+    """eq. 6's masked ω̃ norms for one cluster's N clients."""
+    def fn(g, m):
+        return masked_gradnorm(g, m, interpret=False, impl="pallas")
+    _compile(fn, one_chip, ((N, FINAL_P), F32), ((FINAL_P,), F32))
+
+
+def test_flash_attention_compiles(one_chip):
+    """Causal flash attention at StableLM-3B's head layout (32 × 80)."""
+    def fn(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+    shape = ((1, 2048, 32, 80), jnp.bfloat16)
+    _compile(fn, one_chip, shape, shape, shape)
