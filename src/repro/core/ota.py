@@ -349,11 +349,12 @@ def _chunked_stream(key: jax.Array, length: int) -> jax.Array:
     truncated — exactly the draws the fused kernel generates in-kernel
     (one chunk per grid step), independent of kernel blocking."""
     n_chunks = -(-length // CHUNK)
-    chunks = jax.vmap(
-        lambda j: jax.random.bits(jax.random.fold_in(key, j), (CHUNK,),
-                                  jnp.uint32)
-    )(jnp.arange(n_chunks))
-    return chunks.reshape(-1)[:length]
+    with jax.named_scope("hota.ota_draw"):
+        chunks = jax.vmap(
+            lambda j: jax.random.bits(jax.random.fold_in(key, j), (CHUNK,),
+                                      jnp.uint32)
+        )(jnp.arange(n_chunks))
+        return chunks.reshape(-1)[:length]
 
 
 def _section_bits(key: jax.Array, fold: int, n_clusters: int, length: int):
@@ -398,12 +399,13 @@ def stream_range_bits(key: jax.Array, start: int, length: int) -> jax.Array:
     ``TreePacker.leaf_runs``) maps to exactly one such range."""
     j0 = start // CHUNK
     j1 = (start + length - 1) // CHUNK
-    chunks = jax.vmap(
-        lambda j: jax.random.bits(jax.random.fold_in(key, j), (CHUNK,),
-                                  jnp.uint32)
-    )(jnp.arange(j0, j1 + 1))
     a = start - j0 * CHUNK
-    return jax.lax.slice(chunks.reshape(-1), (a,), (a + length,))
+    with jax.named_scope("hota.ota_draw"):
+        chunks = jax.vmap(
+            lambda j: jax.random.bits(jax.random.fold_in(key, j), (CHUNK,),
+                                      jnp.uint32)
+        )(jnp.arange(j0, j1 + 1))
+        return jax.lax.slice(chunks.reshape(-1), (a,), (a + length,))
 
 
 def section_gain_key(slab_key: jax.Array, fold: int,

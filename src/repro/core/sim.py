@@ -254,25 +254,27 @@ class HotaSim:
             upd = jax.vmap(jax.vmap(client_upd,
                                     in_axes=(None, None, 0, 0, 0, 0, 0, 0)),
                            in_axes=(None, None, 0, 0, 0, 0, 0, None))
-            heads, head_opt, g, F = upd(
-                state.omega, state.omega_stale, partc.stale, state.heads,
-                state.head_opt, xb, yb, self.n_classes)
-            # non-participant slots keep last round's head + optimizer
-            pm = partc.part
+            with jax.named_scope("hota.client_update"):
+                heads, head_opt, g, F = upd(
+                    state.omega, state.omega_stale, partc.stale, state.heads,
+                    state.head_opt, xb, yb, self.n_classes)
+                # non-participant slots keep last round's head + optimizer
+                pm = partc.part
 
-            def sel_slot(new, old):
-                m = pm.reshape(pm.shape + (1,) * (new.ndim - 2))
-                return jnp.where(m > 0.5, new, old)
+                def sel_slot(new, old):
+                    m = pm.reshape(pm.shape + (1,) * (new.ndim - 2))
+                    return jnp.where(m > 0.5, new, old)
 
-            heads = jax.tree.map(sel_slot, heads, state.heads)
-            head_opt = jax.tree.map(sel_slot, head_opt, state.head_opt)
+                heads = jax.tree.map(sel_slot, heads, state.heads)
+                head_opt = jax.tree.map(sel_slot, head_opt, state.head_opt)
         else:
             upd = jax.vmap(jax.vmap(self._client_update,
                                     in_axes=(None, 0, 0, 0, 0, 0)),
                            in_axes=(None, 0, 0, 0, 0, None))
-            heads, head_opt, g, F = upd(state.omega, state.heads,
-                                        state.head_opt, xb, yb,
-                                        self.n_classes)
+            with jax.named_scope("hota.client_update"):
+                heads, head_opt, g, F = upd(state.omega, state.heads,
+                                            state.head_opt, xb, yb,
+                                            self.n_classes)
         # g leaves: (C, N, ...); F: (C, N)
 
         chan_key = ota.sim_channel_key(key)   # reserved fold (DESIGN.md §4)
@@ -298,77 +300,84 @@ class HotaSim:
         # bank to -1 so a client first drawn at round k latches F at k.
         # Legacy states never hold a negative f0 (CE losses are ≥ 0 and
         # init is ones), so the extra clause is trace-only for them.
-        f0 = jnp.where(jnp.logical_or(state.step == 0, state.f0 < 0.0),
-                       F, state.f0)
-        ratios = F / jnp.maximum(f0, 1e-12)
+        with jax.named_scope("hota.fgn"):
+            f0 = jnp.where(jnp.logical_or(state.step == 0, state.f0 < 0.0),
+                           F, state.f0)
+            ratios = F / jnp.maximum(f0, 1e-12)
 
-        if packer is not None:   # tail section of the round's stream draw
-            final_masks = ota.final_layer_masks_packed(chan_key, chan, packer)
-        else:
-            final_masks = ota.final_layer_masks(
-                chan_key, state.omega["final"], chan)   # leaves (C, ...)
+            if packer is not None:   # tail section of the round's stream draw
+                final_masks = ota.final_layer_masks_packed(chan_key, chan,
+                                                           packer)
+            else:
+                final_masks = ota.final_layer_masks(
+                    chan_key, state.omega["final"], chan)   # leaves (C, ...)
 
-        norms = self._masked_final_norms(g["final"], final_masks)   # (C, N)
+            norms = self._masked_final_norms(g["final"], final_masks)  # (C, N)
 
-        # weighting gate is traced (chan.fgn_on): "equal" scenarios take the
-        # same trace and just select the passthrough; under faults a dead
-        # cluster's gate also drops, freezing its (p, FGN) state in place
-        if partc is not None:
-            p_new, fgn_state, fval = jax.vmap(
-                lambda pc, nc, rc, st, on: fgn_update_gated(
-                    pc, nc, rc, st, fl, on)
-            )(state.p, norms, ratios, state.fgn, chan.fgn_on * partc.live)
-        else:
-            p_new, fgn_state, fval = jax.vmap(
-                lambda pc, nc, rc, st: fgn_update_gated(
-                    pc, nc, rc, st, fl, chan.fgn_on)
-            )(state.p, norms, ratios, state.fgn)
+            # weighting gate is traced (chan.fgn_on): "equal" scenarios take
+            # the same trace and just select the passthrough; under faults a
+            # dead cluster's gate also drops, freezing its (p, FGN) state
+            if partc is not None:
+                p_new, fgn_state, fval = jax.vmap(
+                    lambda pc, nc, rc, st, on: fgn_update_gated(
+                        pc, nc, rc, st, fl, on)
+                )(state.p, norms, ratios, state.fgn, chan.fgn_on * partc.live)
+            else:
+                p_new, fgn_state, fval = jax.vmap(
+                    lambda pc, nc, rc, st: fgn_update_gated(
+                        pc, nc, rc, st, fl, chan.fgn_on)
+                )(state.p, norms, ratios, state.fgn)
 
         # --- eqs. (3), (8)-(10): weighted transmission + OTA --------------
         # under faults the transmit weights fold participation and the
         # FedBuff staleness discount into the (C, N) matrix the channel
         # already carries; live/n_eff generalize the eq.-10 guard
-        if partc is not None:
-            disc = jnp.where(partc.stale > 0.5,
-                             jax.lax.rsqrt(1.0 + state.stale_age), 1.0)
-            w_tx = p_new * partc.part * disc
-            live, n_eff = partc.live, partc.n_eff
-        else:
-            w_tx, live, n_eff = p_new, None, None
-        if packer is not None:
-            # client-folded: Σ_n p[l,n]·g[l,n] folds into the masked MAC
-            # sum leaf by leaf — the einsum'd weighted tree never exists.
-            # fl.ota_streaming (static, DESIGN.md §3.15) swaps in the
-            # scan-over-clusters fold: identical streams, one cluster's
-            # contribution resident at a time instead of all C.
-            # fl.ota_sectioned (static, DESIGN.md §3.16) walks the
-            # Section partition one section at a time — bit-identical
-            # per leaf, peak live streams one section — and composes
-            # with the cluster scan (the scan runs inside each section).
-            if fl.ota_sectioned:
-                ghat = ota.ota_aggregate_sectioned(
-                    chan_key, g, w_tx, chan, fl.n_clients, packer,
-                    bits_mode=ota_bits_mode, live=live, n_eff=n_eff,
-                    streaming=fl.ota_streaming)
+        with jax.named_scope("hota.ota_fold"):
+            if partc is not None:
+                disc = jnp.where(partc.stale > 0.5,
+                                 jax.lax.rsqrt(1.0 + state.stale_age), 1.0)
+                w_tx = p_new * partc.part * disc
+                live, n_eff = partc.live, partc.n_eff
             else:
-                agg = (ota.ota_aggregate_streaming if fl.ota_streaming
-                       else ota.ota_aggregate_client_folded)
-                ghat = agg(
-                    chan_key, g, w_tx, chan, fl.n_clients, packer,
-                    bits_mode=ota_bits_mode, live=live, n_eff=n_eff)
-            # slab-view PS update: moments stay one flat slab, params
-            # unpack exactly once (the model-apply boundary)
-            omega, ps_opt = slab_adam_update(ghat, state.ps_opt,
-                                             state.omega, tcfg.lr)
-        else:
-            weighted = jax.tree.map(
-                lambda gl: jnp.einsum("cn,cn...->c...", w_tx, gl), g)
-            ghat = ota.ota_aggregate_tree(chan_key, weighted, chan,
-                                          fl.n_clients, live=live,
-                                          n_eff=n_eff)
-            # --- PS update (line 20) ---------------------------------------
-            omega, ps_opt = adam_update(ghat, state.ps_opt, state.omega,
-                                        tcfg.lr)
+                w_tx, live, n_eff = p_new, None, None
+            if packer is not None:
+                # client-folded: Σ_n p[l,n]·g[l,n] folds into the masked MAC
+                # sum leaf by leaf — the einsum'd weighted tree never exists.
+                # fl.ota_streaming (static, DESIGN.md §3.15) swaps in the
+                # scan-over-clusters fold: identical streams, one cluster's
+                # contribution resident at a time instead of all C.
+                # fl.ota_sectioned (static, DESIGN.md §3.16) walks the
+                # Section partition one section at a time — bit-identical
+                # per leaf, peak live streams one section — and composes
+                # with the cluster scan (the scan runs inside each section).
+                if fl.ota_sectioned:
+                    ghat = ota.ota_aggregate_sectioned(
+                        chan_key, g, w_tx, chan, fl.n_clients, packer,
+                        bits_mode=ota_bits_mode, live=live, n_eff=n_eff,
+                        streaming=fl.ota_streaming)
+                else:
+                    agg = (ota.ota_aggregate_streaming if fl.ota_streaming
+                           else ota.ota_aggregate_client_folded)
+                    ghat = agg(
+                        chan_key, g, w_tx, chan, fl.n_clients, packer,
+                        bits_mode=ota_bits_mode, live=live, n_eff=n_eff)
+            else:
+                weighted = jax.tree.map(
+                    lambda gl: jnp.einsum("cn,cn...->c...", w_tx, gl), g)
+                ghat = ota.ota_aggregate_tree(chan_key, weighted, chan,
+                                              fl.n_clients, live=live,
+                                              n_eff=n_eff)
+
+        # --- PS update (line 20) -------------------------------------------
+        with jax.named_scope("hota.ps_update"):
+            if packer is not None:
+                # slab-view PS update: moments stay one flat slab, params
+                # unpack exactly once (the model-apply boundary)
+                omega, ps_opt = slab_adam_update(ghat, state.ps_opt,
+                                                 state.omega, tcfg.lr)
+            else:
+                omega, ps_opt = adam_update(ghat, state.ps_opt, state.omega,
+                                            tcfg.lr)
 
         metrics = {"loss": F, "p": p_new, "fgrad": fval,
                    "grad_norms": norms}

@@ -114,6 +114,7 @@ def flash_attention_pallas(
     return pl.pallas_call(
         kernel,
         grid=grid,
+        name="flash_attention",
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_kv, d),

@@ -56,6 +56,7 @@ def masked_gradnorm_pallas(
     out = pl.pallas_call(
         kernel,
         grid=grid,
+        name="masked_gradnorm",
         in_specs=[
             pl.BlockSpec((task_block, col_block), lambda i, j: (i, j)),
             pl.BlockSpec((1, col_block), lambda i, j: (0, j)),

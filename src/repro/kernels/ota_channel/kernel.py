@@ -160,6 +160,7 @@ def ota_mask_weight_pallas(
     out, mask = pl.pallas_call(
         _ota_mask_weight_kernel,
         grid=grid,
+        name="ota_mask_weight",
         in_specs=[
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
@@ -227,6 +228,7 @@ def ota_mask_count_pallas(
     out, cnt = pl.pallas_call(
         kernel,
         grid=grid,
+        name="ota_mask_count",
         in_specs=[
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
             pl.BlockSpec((n_clusters, br, LANE), lambda i: (0, i, 0)),
@@ -394,6 +396,7 @@ def ota_aggregate_client_pallas(
         return pl.pallas_call(
             kernel,
             grid=(rows // br, n_cb),
+            name="ota_client_fold_cblk",
             in_specs=[
                 pl.BlockSpec((cb, n_clients, br, LANE),
                              lambda i, j: (j, 0, i, 0)),
@@ -423,6 +426,7 @@ def ota_aggregate_client_pallas(
     return pl.pallas_call(
         kernel,
         grid=grid,
+        name="ota_client_fold",
         in_specs=[
             pl.BlockSpec((n_clusters, n_clients, br, LANE),
                          lambda i: (0, 0, i, 0)),
@@ -453,6 +457,7 @@ def ota_channel_pallas(
     out, mask = pl.pallas_call(
         _ota_channel_kernel,
         grid=grid,
+        name="ota_channel",
         in_specs=[
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
@@ -701,6 +706,7 @@ def ota_aggregate_fused_pallas(
         return pl.pallas_call(
             kernel,
             grid=(1,),
+            name="ota_aggregate_fused",
             in_specs=[
                 pl.BlockSpec((n_clusters, rows, LANE), lambda i: (0, 0, 0)),
                 pl.BlockSpec((n_clusters, rows, LANE), lambda i: (0, 0, 0)),
@@ -723,6 +729,7 @@ def ota_aggregate_fused_pallas(
         return pl.pallas_call(
             kernel,
             grid=(1,),
+            name="ota_aggregate_fused",
             in_specs=[
                 pl.BlockSpec((n_clusters, rows, LANE), lambda i: (0, 0, 0)),
                 pl.BlockSpec((2, 2), lambda i: (0, 0)),
@@ -748,6 +755,7 @@ def ota_aggregate_fused_pallas(
     return pl.pallas_call(
         kernel,
         grid=(pl.cdiv(rows, CHUNK_ROWS), n_cb),
+        name="ota_aggregate_fused",
         in_specs=[
             pl.BlockSpec((cb, CHUNK_ROWS, LANE),
                          lambda i, j: (j, i, 0)),
@@ -785,6 +793,7 @@ def ota_aggregate_pallas(
     return pl.pallas_call(
         kernel,
         grid=grid,
+        name="ota_aggregate",
         in_specs=[
             pl.BlockSpec((n_clusters, br, LANE), lambda i: (0, i, 0)),
             pl.BlockSpec((n_clusters, br, LANE), lambda i: (0, i, 0)),

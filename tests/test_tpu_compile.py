@@ -15,6 +15,7 @@ process may load the TPU library at a time, and every pytest worker
 imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,12 +70,19 @@ def no_persistent_cache():
 pytestmark = pytest.mark.usefixtures("no_persistent_cache")
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, kernel, *shapes):
     """Lower ``fn`` on the described chip, compile, and check that the
-    program holds a Mosaic kernel (not an XLA fallback)."""
+    program holds a Mosaic kernel (not an XLA fallback) under its stable
+    name ``kernel``: in the lowered module, and as the name of the
+    compiled instruction that a profiler trace shows."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    lowered = jax.jit(fn).lower(*args)
+    assert f'kernel_name = "{kernel}"' in lowered.as_text()
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"^\s*(ROOT )?%{kernel}(\.\d+)? = .*custom-call\(",
+                     text, re.M), kernel
     return compiled
 
 
@@ -86,8 +94,8 @@ def test_client_fold_compiles(one_chip):
     def fn(g, p, bits, nbits, sig):
         return ota_client_fold_apply(g, p, bits, nbits, sig, 3.2e-2, 1.0,
                                      1.0, N, interpret=False, impl="pallas")
-    _compile(fn, one_chip, ((C, N, 1024, 2048), F32), ((C, N), F32),
-             ((C, P), U32), ((P,), U32), ((C,), F32))
+    _compile(fn, one_chip, "ota_client_fold", ((C, N, 1024, 2048), F32),
+             ((C, N), F32), ((C, P), U32), ((P,), U32), ((C,), F32))
 
 
 def test_client_fold_cluster_blocked_compiles(one_chip):
@@ -97,7 +105,8 @@ def test_client_fold_cluster_blocked_compiles(one_chip):
         return ota_aggregate_client_pallas(x, bits, nbits, params,
                                            n_clients=N, interpret=False,
                                            cluster_block=2)
-    _compile(fn, one_chip, ((C, N, ROWS, LANE), F32), ((C, ROWS, LANE), U32),
+    _compile(fn, one_chip, "ota_client_fold_cblk",
+             ((C, N, ROWS, LANE), F32), ((C, ROWS, LANE), U32),
              ((ROWS, LANE), U32), ((1, C * (N + 2) + 3), F32))
 
 
@@ -107,16 +116,16 @@ def test_fused_hw_prng_compiles(one_chip):
         params = _channel_params_block(sig, 3.2e-2, 1.0, 1.0, C)
         return ota_aggregate_fused_pallas(wg, keys, params, n_clients=N,
                                           interpret=False)
-    _compile(fn, one_chip, ((C, ROWS, LANE), F32), ((2, 2), U32),
-             ((C,), F32))
+    _compile(fn, one_chip, "ota_aggregate_fused", ((C, ROWS, LANE), F32),
+             ((2, 2), U32), ((C,), F32))
 
 
 def test_supplied_bits_aggregate_compiles(one_chip):
     def fn(wg, bits, nbits, sig):
         return ota_aggregate(wg, bits, nbits, sig, 3.2e-2, 1.0, 1.0,
                              n_clients=N, interpret=False)
-    _compile(fn, one_chip, ((C, P), F32), ((C, P), U32), ((P,), U32),
-             ((C,), F32))
+    _compile(fn, one_chip, "ota_aggregate", ((C, P), F32), ((C, P), U32),
+             ((P,), U32), ((C,), F32))
 
 
 def test_mask_weight_compiles(one_chip):
@@ -124,7 +133,8 @@ def test_mask_weight_compiles(one_chip):
     def fn(x, bits, w):
         return ota_mask_weight_apply(x, bits, 0.7, 3.2e-2, 1.0, w,
                                      interpret=False, impl="pallas")
-    _compile(fn, one_chip, ((1024, 2048), F32), ((P,), U32), ((), F32))
+    _compile(fn, one_chip, "ota_mask_weight", ((1024, 2048), F32),
+             ((P,), U32), ((), F32))
 
 
 def test_mask_count_compiles(one_chip):
@@ -132,15 +142,16 @@ def test_mask_count_compiles(one_chip):
     def fn(x, bits_all, sig, w):
         return ota_mask_count_apply(x, bits_all, 1, sig, 3.2e-2, 1.0, w,
                                     interpret=False, impl="pallas")
-    _compile(fn, one_chip, ((1024, 2048), F32), ((C, P), U32), ((C,), F32),
-             ((), F32))
+    _compile(fn, one_chip, "ota_mask_count", ((1024, 2048), F32),
+             ((C, P), U32), ((C,), F32), ((), F32))
 
 
 def test_masked_gradnorm_compiles(one_chip):
     """eq. 6's masked ω̃ norms for one cluster's N clients."""
     def fn(g, m):
         return masked_gradnorm(g, m, interpret=False, impl="pallas")
-    _compile(fn, one_chip, ((N, FINAL_P), F32), ((FINAL_P,), F32))
+    _compile(fn, one_chip, "masked_gradnorm", ((N, FINAL_P), F32),
+             ((FINAL_P,), F32))
 
 
 def test_flash_attention_compiles(one_chip):
@@ -148,4 +159,4 @@ def test_flash_attention_compiles(one_chip):
     def fn(q, k, v):
         return flash_attention(q, k, v, interpret=False)
     shape = ((1, 2048, 32, 80), jnp.bfloat16)
-    _compile(fn, one_chip, shape, shape, shape)
+    _compile(fn, one_chip, "flash_attention", shape, shape, shape)
