@@ -50,15 +50,17 @@ from typing import Any, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax._src.prng import threefry2x32_p
 
 from repro.common.flatpack import TreePacker, check_tree_matches_packer
 from repro.core.channel import ChannelParams
-from repro.kernels.ota_channel.kernel import CHUNK_ROWS
+from repro.kernels.ota_channel.kernel import CHUNK_ROWS, _client_cluster_block
 from repro.kernels.ota_channel.ops import (
-    _ota_aggregate_fused_impl, ota_client_fold_apply, ota_stream_fold_apply,
+    _ota_aggregate_fused_impl, ota_client_fold_apply,
+    ota_client_fold_drawn_apply, ota_stream_fold_apply,
 )
 from repro.kernels.ota_channel.ref import bits_to_gaussian, bits_to_mask
-from repro.kernels.slab import LANE, on_tpu
+from repro.kernels.slab import LANE, ROW_QUANTUM, on_tpu
 
 
 # --------------------------------------------------------------------------
@@ -408,6 +410,59 @@ def stream_range_bits(key: jax.Array, start: int, length: int) -> jax.Array:
         return jax.lax.slice(chunks.reshape(-1), (a,), (a + length,))
 
 
+def stream_chunk_keys(key: jax.Array, start: int,
+                      length: int) -> Tuple[int, jax.Array]:
+    """The chunks of ``key``'s chunk-quantized stream that cover the
+    STATIC positions [start, start+length): ``(j0, keys)`` where
+    ``keys[i]`` is chunk j0+i's key ``fold_in(key, j0 + i)`` as a (2,)
+    uint32 pair — one fold per chunk, nothing else drawn."""
+    j0 = start // CHUNK
+    j1 = (start + length - 1) // CHUNK
+    with jax.named_scope("hota.ota_draw"):
+        keys = jax.vmap(lambda j: jax.random.key_data(
+            jax.random.fold_in(key, j)))(jnp.arange(j0, j1 + 1))
+    return j0, keys
+
+
+def stream_words(k0: jax.Array, k1: jax.Array, m: jax.Array) -> jax.Array:
+    """Word ``m`` of the chunk keyed (k0, k1), element by element: the
+    x0 ^ x1 of threefry2x32 on the counter pair (0, m) — what
+    ``bits(chunk_key, (CHUNK,))[m]`` is under the partitionable threefry
+    (DESIGN.md §4, position form). The ONE home of the word formula: the
+    jnp paths and the drawing client-fold kernel both call it. (Not
+    ``jax.extend.random.threefry_2x32``: it splits its count array into
+    two halves and gives other words.)"""
+    x0, x1 = threefry2x32_p.bind(k0, k1, jnp.zeros_like(m), m)
+    return x0 ^ x1
+
+
+def _chunk_key_table(keys: jax.Array, start: int, length: int):
+    """``stream_chunk_keys`` of each of the S streams keyed by ``keys``
+    (S, 2): (j0, (S, n_chunks, 2) uint32)."""
+    table = jax.vmap(lambda k: stream_chunk_keys(k, start, length)[1])(keys)
+    return start // CHUNK, table
+
+
+def stream_words_at(keys: jax.Array, start: int, length: int) -> jax.Array:
+    """(S, length) uint32: positions [start, start+length) (STATIC) of the
+    S chunk-quantized streams keyed by ``keys`` (S, 2) — exactly these
+    words, computed from their positions in one elementwise expression
+    (each position picks its chunk's key); bit-identical to slicing
+    ``_chunked_stream`` / ``stream_range_bits`` there."""
+    j0, table = _chunk_key_table(keys, start, length)
+    shape = (keys.shape[0], length)
+    with jax.named_scope("hota.ota_draw"):
+        pos = (start - j0 * CHUNK) + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1)
+        chunk = pos // CHUNK
+        k0, k1 = (jnp.broadcast_to(table[:, 0, h:h + 1], shape)
+                  for h in (0, 1))
+        for i in range(1, table.shape[1]):
+            k0, k1 = (jnp.where(chunk == i, table[:, i, h:h + 1], k)
+                      for h, k in ((0, k0), (1, k1)))
+        return stream_words(k0, k1, (pos % CHUNK).astype(jnp.uint32))
+
+
 def section_gain_key(slab_key: jax.Array, fold: int,
                      cluster: jax.Array | int) -> jax.Array:
     """Gain-bit stream key for one (section, cluster) — the same
@@ -422,15 +477,28 @@ def section_noise_key(slab_key: jax.Array, fold: int) -> jax.Array:
     return jax.random.fold_in(noise_key(slab_key), fold)
 
 
+def section_stream_keys(key: jax.Array, fold: int, n_clusters: int,
+                        noise: bool = True) -> jax.Array:
+    """(C+1, 2) uint32 stream keys of one section: the C gain streams
+    (``section_gain_key``), then the noise stream (``section_noise_key``)
+    — or (C, 2) with ``noise=False``."""
+    with jax.named_scope("hota.ota_draw"):
+        keys = [jax.vmap(lambda c: section_gain_key(key, fold, c))(
+            jnp.arange(n_clusters))]
+        if noise:
+            keys.append(section_noise_key(key, fold)[None])
+        return jax.random.key_data(jnp.concatenate(keys))
+
+
 def section_gain_streams(key: jax.Array, packer: TreePacker,
                          n_clusters: int) -> List[jax.Array]:
     """One (C, length) gain-bit stream per ``packer.sections`` entry,
     drawn under the fold ``packed_section_folds`` assigns it. The SINGLE
     source of the packed gain schedule: ``packed_gain_bits`` concatenates
-    these, the zero-copy consumers (``ota_aggregate_client_folded``,
-    ``repro.core.hota_slab``) slice them per leaf — so sim and
-    distributed paths draw identical bits for identical layouts (pinned
-    in tests/test_client_folded.py)."""
+    these, the zero-copy consumers (``ota_aggregate_client_folded`` in
+    supplied mode, ``repro.core.hota_slab``) slice them per leaf — so sim
+    and distributed paths draw identical bits for identical layouts
+    (pinned in tests/test_client_folded.py)."""
     folds = packed_section_folds(packer)
     return [_section_bits(key, folds[sec.index], n_clusters, sec.length)
             for sec in packer.sections]
@@ -517,9 +585,10 @@ def ota_aggregate_client_folded(
     chan: ChannelParams,         # traced knobs; chan.sigma2 is (C,)
     n_clients: int,
     packer: TreePacker,
-    bits_mode: str = "fused",    # accepted for API symmetry (see below)
+    bits_mode: str = "fused",    # "fused" | "supplied" (see below)
     live: Optional[jax.Array] = None,   # (C,) cluster participation (§3.14)
     n_eff: Optional[jax.Array] = None,  # () traced effective N
+    impl: Optional[str] = None,  # "pallas" | "jnp"; None: by platform
 ):
     """Slab-native sim-path OTA aggregation (DESIGN.md §3.12): fold the
     client-weight einsum INTO the channel and consume every gradient
@@ -530,18 +599,27 @@ def ota_aggregate_client_folded(
     the traced ``ota_on`` gate — but computed leaf by leaf against the
     static zero-copy maps (``TreePacker.leaf_runs``): neither the
     client-weighted tree nor the (C, P) packed slab is ever
-    materialized. Streams are the per-section chunk-quantized draws of
-    ``packed_section_folds`` — identical bits to the packed kernel and
-    to the slab-native distributed engine on the same layout — drawn
-    once per (section, cluster) and sliced per leaf, so leaves sharing a
-    chunk never redraw it.
+    materialized. Streams are the per-section chunk-quantized streams of
+    ``packed_section_folds`` — identical words to the packed kernel and
+    to the slab-native distributed engine on the same layout.
 
-    ``bits_mode``: "fused" | "supplied" — both return identical values.
-    In this zero-copy formulation the draw always happens outside the
-    kernel and depends only on ``key``, so under ``ScenarioBank``'s
-    scenario vmap (shared key, ``in_axes=None``) it hoists out of the
-    scenario axis in EITHER mode; the parameter survives so the sweep
-    engines compose unchanged.
+    ``bits_mode`` picks where the words are computed; both modes return
+    identical values:
+
+    * ``"fused"``: each leaf's words come from their stream positions
+      (DESIGN.md §4, position form). On the kernel path the leaf's
+      ROW_QUANTUM main body runs ``ota_client_fold_drawn``, which
+      computes them in the kernel from a table of chunk keys, so no word
+      reaches HBM; the ragged remainder and the jnp path draw exactly
+      their positions with ``stream_words_at``.
+    * ``"supplied"``: the streams are drawn once per (section, cluster)
+      outside the kernels and sliced per leaf. The draw depends only on
+      ``key``, so under ``ScenarioBank``'s scenario vmap (shared key,
+      ``in_axes=None``) it hoists out of the scenario axis, where an
+      in-kernel draw would be repeated once per scenario.
+
+    ``impl``: the per-leaf dispatch of ``ota_client_fold_apply`` —
+    "pallas" on TPU, "jnp" elsewhere; tests force "pallas" + interpret.
     """
     if bits_mode not in ("fused", "supplied"):
         raise ValueError(bits_mode)
@@ -549,20 +627,60 @@ def ota_aggregate_client_folded(
                               "gradient pytree (client-folded OTA)",
                               batch_ndim=2)
     n_clusters = int(chan.sigma2.shape[0])
-    gbits = section_gain_streams(key, packer, n_clusters)
-    nbits = section_noise_streams(key, packer)
+    if impl is None:
+        impl = "pallas" if on_tpu() else "jnp"
     leaves = packer.treedef.flatten_up_to(grads)
     out = [None] * len(leaves)
+    fold_kw = dict(live=live, n_eff=n_eff, interpret=not on_tpu())
+    if bits_mode == "supplied":
+        gbits = section_gain_streams(key, packer, n_clusters)
+        nbits = section_noise_streams(key, packer)
+        for run in packer.leaf_runs():
+            b = jax.lax.slice(gbits[run.section], (0, run.offset),
+                              (n_clusters, run.offset + run.size))
+            nb = jax.lax.slice(nbits[run.section], (run.offset,),
+                               (run.offset + run.size,))
+            out[run.leaf] = ota_client_fold_apply(
+                leaves[run.leaf], p, b, nb, chan.sigma2, chan.h_threshold,
+                chan.noise_std, chan.ota_on, n_clients, impl=impl,
+                **fold_kw)
+        return packer.treedef.unflatten(out)
+
+    folds = packed_section_folds(packer)
+    skeys = {}
+    # the drawing kernel has no C-blocked variant: where the C·N blocks
+    # outgrow VMEM (no benchmark cell does) the words are drawn by
+    # position outside and ota_client_fold_apply takes its C-blocked
+    # kernel
+    drawn = (impl == "pallas" and _client_cluster_block(
+        n_clusters, n_clients, not on_tpu()) == n_clusters)
     for run in packer.leaf_runs():
-        b = jax.lax.slice(gbits[run.section], (0, run.offset),
-                          (n_clusters, run.offset + run.size))
-        nb = jax.lax.slice(nbits[run.section], (run.offset,),
-                           (run.offset + run.size,))
-        out[run.leaf] = ota_client_fold_apply(
-            leaves[run.leaf], p, b, nb, chan.sigma2, chan.h_threshold,
-            chan.noise_std, chan.ota_on, n_clients,
-            live=live, n_eff=n_eff,
-            interpret=not on_tpu())
+        if run.section not in skeys:
+            skeys[run.section] = section_stream_keys(
+                key, folds[run.section], n_clusters)
+        keys = skeys[run.section]
+        g = leaves[run.leaf]
+        flat = g.reshape(n_clusters, n_clients, run.size)
+        main = run.size - run.size % ROW_QUANTUM if drawn else 0
+        parts = []
+        if main:
+            j0, table = _chunk_key_table(keys, run.offset, main)
+            parts.append(ota_client_fold_drawn_apply(
+                jax.lax.slice(flat, (0, 0, 0),
+                              (n_clusters, n_clients, main)),
+                p, table.reshape(-1), run.offset - j0 * CHUNK, stream_words,
+                chan.sigma2, chan.h_threshold, chan.noise_std, chan.ota_on,
+                n_clients, **fold_kw))
+        if run.size - main:
+            w = stream_words_at(keys, run.offset + main, run.size - main)
+            parts.append(ota_client_fold_apply(
+                jax.lax.slice(flat, (0, 0, main),
+                              (n_clusters, n_clients, run.size)),
+                p, w[:n_clusters], w[n_clusters], chan.sigma2,
+                chan.h_threshold, chan.noise_std, chan.ota_on, n_clients,
+                impl=impl, **fold_kw))
+        ghat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        out[run.leaf] = ghat.reshape(g.shape[2:])
     return packer.treedef.unflatten(out)
 
 
@@ -808,12 +926,13 @@ def final_layer_masks_packed(key: jax.Array, chan: ChannelParams,
     from the tail section's stream — bit-identical to the masks
     ``ota_aggregate_packed`` applies to the same entries.
 
-    Consumes the stream per leaf through the SAME ``leaf_runs`` slices
-    the zero-copy engines walk (the tail section is never coalesced, so
-    its fold and runs are layout-stable): each mask leaf is a static
-    slice of the tail draw reshaped in place — the full (C, tail_len)
-    slab is never unpacked. ``bits_to_mask`` is elementwise, so slicing
-    before masking is bit-identical to masking the whole tail.
+    Draws the stream per leaf at the SAME ``leaf_runs`` positions the
+    zero-copy engines walk (the tail section is never coalesced, so its
+    fold and runs are layout-stable): each mask leaf is computed from
+    exactly its positions (``stream_words_at``) and reshaped in place —
+    no chunk is drawn whole and the (C, tail_len) slab is never
+    unpacked. ``bits_to_mask`` is elementwise, so this is bit-identical
+    to masking the whole tail draw.
     """
     if packer.tail_name is None or not packer.tail_len:
         raise ValueError(
@@ -823,15 +942,14 @@ def final_layer_masks_packed(key: jax.Array, chan: ChannelParams,
     n_clusters = chan.sigma2.shape[0]
     tail_sec = next(s for s in packer.sections
                     if s.name == packer.tail_name)
-    bits = _section_bits(key, PACKED_TAIL_FOLD, n_clusters,
-                         tail_sec.length)                       # (C, tail)
+    keys = section_stream_keys(key, PACKED_TAIL_FOLD, n_clusters,
+                               noise=False)                     # (C, 2)
     sig = chan.sigma2.reshape(n_clusters, 1)
     sub_leaves = []
     for run in packer.leaf_runs():
         if run.section != tail_sec.index:
             continue
-        b = jax.lax.slice(bits, (0, run.offset),
-                          (n_clusters, run.offset + run.size))
+        b = stream_words_at(keys, run.offset, run.size)
         m = bits_to_mask(b, sig, chan.h_threshold, chan.ota_on)
         sub_leaves.append(
             m.reshape((n_clusters,) + packer.slots[run.leaf].shape))

@@ -29,6 +29,13 @@
   stream (no (C, P) bits slab in HBM, and blocking can never shift the
   draw); the bits-supplied variant is the oracle bridge for tests.
 
+* ``ota_client_fold_drawn_pallas`` — the client-folded estimator that
+  computes its own channel words: each element's gain and noise words
+  are the stream's word formula (``repro.core.ota.stream_words``)
+  evaluated at its stream position under its chunk's key, read from a
+  small key table in SMEM — the same words as the chunked draw, none of
+  them in HBM (DESIGN.md §4, position form).
+
 Channel knobs (the per-cluster pass probabilities, noise std, the ota_on
 gate) arrive as one traced (1, C+2) params block, so scenario sweeps
 (``ScenarioBank``) vmap over them without re-tracing; ``ota_on < 0.5``
@@ -48,6 +55,7 @@ ref.ota_aggregate_slab_ref on the same bits stream.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -247,10 +255,12 @@ def ota_mask_count_pallas(
     return out, cnt
 
 
-def _client_fold_block(x_ref, bits_ref, params_ref, n_clusters, n_clients,
+def _client_fold_block(x_of, bits_of, params_ref, n_clusters, n_clients,
                        acc, cnt):
     """Fold ``n_clusters`` clusters' masked, client-weighted gradients
-    into (acc, cnt), in cluster order. The params row is [p_pass_·,
+    into (acc, cnt), in cluster order. ``x_of(l, i)`` is client i of
+    cluster l's gradient block and ``bits_of(l)`` cluster l's gain
+    words, both shaped like ``acc``. The params row is [p_pass_·,
     w_··, z_std, ota_on, live_·, N_eff] over these clusters."""
     c, n = n_clusters, n_clients
     off = params_ref[0, c + c * n + 1] < 0.5     # traced error-free gate
@@ -258,10 +268,10 @@ def _client_fold_block(x_ref, bits_ref, params_ref, n_clusters, n_clients,
         wg = jnp.zeros_like(acc)
         for i in range(n):                   # eq. 3: Σ_n p[l,n]·g[l,n]
             wg = wg + params_ref[0, c + l * n + i] * (
-                x_ref[l, i].astype(jnp.float32))
+                x_of(l, i).astype(jnp.float32))
         live_l = params_ref[0, c + c * n + 2 + l]
         mask = jnp.logical_and(
-            _bits_mask(bits_ref[l], params_ref[0, l], off), live_l >= 0.5)
+            _bits_mask(bits_of(l), params_ref[0, l], off), live_l >= 0.5)
         acc = acc + jnp.where(mask, wg, 0.0)
         cnt = cnt + mask.astype(jnp.float32)
     return acc, cnt
@@ -290,8 +300,9 @@ def _ota_aggregate_client_kernel(x_ref, bits_ref, nbits_ref, params_ref,
     the masks AFTER the ``ota_on`` all-pass gate, and live=ones/n_eff=N
     is the bit-exact full-participation identity."""
     zeros = jnp.zeros(out_ref.shape, jnp.float32)
-    acc, cnt = _client_fold_block(x_ref, bits_ref, params_ref, n_clusters,
-                                  n_clients, zeros, zeros)
+    acc, cnt = _client_fold_block(lambda l, i: x_ref[l, i],
+                                  lambda l: bits_ref[l], params_ref,
+                                  n_clusters, n_clients, zeros, zeros)
     out_ref[...] = _client_finish(acc, cnt, nbits_ref[...], params_ref,
                                   n_clusters, n_clients)
 
@@ -317,8 +328,8 @@ def _ota_aggregate_client_cblk_kernel(x_ref, bits_ref, nbits_ref, params_ref,
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     acc_ref[...], cnt_ref[...] = _client_fold_block(
-        x_ref, bits_ref, params_ref, cb, n_clients, acc_ref[...],
-        cnt_ref[...])
+        lambda l, i: x_ref[l, i], lambda l: bits_ref[l], params_ref, cb,
+        n_clients, acc_ref[...], cnt_ref[...])
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _done():
@@ -439,6 +450,118 @@ def ota_aggregate_client_pallas(
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
         interpret=interpret,
     )(x, bits, nbits, params.astype(jnp.float32))
+
+
+# rows per in-kernel draw tile: the threefry chain of one tile's stream
+# (8 vregs per operand) stays near the registers while the tile loop
+# walks the block (64 timed fastest of 8-128 on one v5e at the largest
+# paper leaf)
+DRAW_TILE_ROWS = 64
+
+
+def _ota_client_fold_drawn_kernel(x_ref, keys_ref, params_ref, out_ref,
+                                  words_ref, *, n_clusters, n_clients,
+                                  n_chunks, word0, words):
+    """Client-folded PS estimator that computes its channel words itself
+    (DESIGN.md §4, position form): word m of chunk j of a stream is
+    ``words(k0, k1, m)`` under that chunk's key, so each element's gain
+    and noise words come from its stream position and no word is read
+    from HBM. ``keys_ref`` (SMEM, flat) holds the (k0, k1) chunk keys of
+    the C gain streams and the noise stream over the leaf's chunks;
+    ``word0`` is the leaf's first position counted from the first of
+    them. A tile spans fewer words than a chunk, so it touches at most
+    two chunks: each element picks the first or the next key. Per tile a
+    loop over the C+1 streams (one threefry in the program, not C+1)
+    fills ``words_ref`` (VMEM); the mask, MAC and estimate are the
+    supplied-words kernel's, unchanged."""
+    br = out_ref.shape[0]
+    tile = words_ref.shape[1]
+    shift = CHUNK_ROWS.bit_length() - 1 + LANE.bit_length() - 1
+    base = word0 + pl.program_id(0) * (br * LANE)
+    lane_pos = (jax.lax.broadcasted_iota(jnp.int32, (tile, LANE), 0) * LANE
+                + jax.lax.broadcasted_iota(jnp.int32, (tile, LANE), 1))
+
+    def body(t, carry):
+        r0 = pl.multiple_of(t * tile, tile)
+        p0 = base + r0 * LANE
+        pos = p0 + lane_pos
+        m = (pos & ((1 << shift) - 1)).astype(jnp.uint32)
+        ja = p0 >> shift
+        jb = jnp.minimum(ja + 1, n_chunks - 1)
+        first = (pos >> shift) == ja
+
+        def draw(s, c):
+            k0, k1 = (jnp.where(first, keys_ref[(s * n_chunks + ja) * 2 + h],
+                                keys_ref[(s * n_chunks + jb) * 2 + h])
+                      for h in (0, 1))
+            words_ref[s] = words(k0, k1, m)
+            return c
+
+        jax.lax.fori_loop(0, n_clusters + 1, draw, 0)
+        rows = pl.ds(r0, tile)
+        zeros = jnp.zeros((tile, LANE), jnp.float32)
+        acc, cnt = _client_fold_block(lambda l, i: x_ref[l, i, rows, :],
+                                      lambda l: words_ref[l], params_ref,
+                                      n_clusters, n_clients, zeros, zeros)
+        out_ref[rows, :] = _client_finish(acc, cnt, words_ref[n_clusters],
+                                          params_ref, n_clusters, n_clients)
+        return carry
+
+    jax.lax.fori_loop(0, br // tile, body, 0)
+
+
+def ota_client_fold_drawn_pallas(
+    x: jax.Array,            # (C, N, rows, 128) f32 — RAW per-client grads
+    keys: jax.Array,         # ((C+1)·n_chunks·2,) uint32 chunk keys
+    params: jax.Array,       # (1, C·(N+2)+3), as ota_aggregate_client_pallas
+    *,
+    word0: int,
+    words,
+    n_clients: int,
+    block_rows: int = DEFAULT_BLOCK_ROWS,
+    interpret: bool = False,
+) -> jax.Array:
+    """``ota_aggregate_client_pallas`` with the channel words drawn in
+    the kernel. ``keys`` is the flat table of chunk keys, stream-major
+    (C gain streams, then the noise stream), chunk, then (k0, k1);
+    ``word0`` (static) is the position of the slab's first element in the
+    first chunk; ``words(k0, k1, m)`` is the stream's word formula
+    (``repro.core.ota.stream_words``). Operand 0 stays the gradient block
+    and the table a plain uint32 operand in SMEM, so a trace reads this
+    call as a client-fold kernel that takes channel words. Callers check
+    ``_client_cluster_block`` first: the drawing kernel has no C-blocked
+    variant."""
+    n_clusters, n_cl, rows, lane = x.shape
+    assert lane == LANE and n_cl == n_clients, (x.shape, n_clients)
+    assert params.shape == (1, n_clusters * (n_clients + 2) + 3), params.shape
+    n_chunks = keys.shape[0] // (2 * (n_clusters + 1))
+    assert keys.shape == (2 * (n_clusters + 1) * n_chunks,), keys.shape
+    assert 0 <= word0 and word0 + rows * LANE <= n_chunks * CHUNK_ROWS * LANE
+    from jax.experimental.pallas import tpu as pltpu
+    # C·N grad blocks + out resident at once; the words never leave the core
+    br = _pick_block_rows(rows, n_clusters * n_clients + 2, block_rows,
+                          interpret)
+    kernel = functools.partial(
+        _ota_client_fold_drawn_kernel, n_clusters=n_clusters,
+        n_clients=n_clients, n_chunks=n_chunks, word0=word0, words=words)
+    return pl.pallas_call(
+        kernel,
+        grid=(rows // br,),
+        name="ota_client_fold_drawn",
+        in_specs=[
+            pl.BlockSpec((n_clusters, n_clients, br, LANE),
+                         lambda i: (0, 0, i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, n_clusters * (n_clients + 2) + 3),
+                         lambda i: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((br, LANE), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+        scratch_shapes=[pltpu.VMEM(
+            (n_clusters + 1, math.gcd(br, DRAW_TILE_ROWS), LANE),
+            jnp.uint32)],
+        interpret=interpret,
+    )(x, keys.astype(jnp.uint32), params.astype(jnp.float32))
 
 
 def ota_channel_pallas(

@@ -22,8 +22,8 @@ import jax.numpy as jnp
 
 from repro.kernels.ota_channel.kernel import (
     ota_aggregate_client_pallas, ota_aggregate_fused_pallas,
-    ota_aggregate_pallas, ota_channel_pallas, ota_mask_count_pallas,
-    ota_mask_weight_pallas,
+    ota_aggregate_pallas, ota_channel_pallas, ota_client_fold_drawn_pallas,
+    ota_mask_count_pallas, ota_mask_weight_pallas,
 )
 from repro.kernels.ota_channel.ref import (
     bits_to_mask, ota_aggregate_client_ref, ota_aggregate_slab_ref,
@@ -126,6 +126,27 @@ def ota_mask_weight_apply(x: jax.Array, bits: jax.Array, sigma2, h_th,
     return out.reshape(x.shape), mask.reshape(x.shape)
 
 
+def _client_params_row(p32, sig, h_th, noise_std, ota_on, live, n_eff,
+                       n_clients: int):
+    """The client-fold kernels' (1, C·(N+2)+3) row: [p_pass_·, w_··,
+    z_std, ota_on, live_·, N_eff]; live=None / n_eff=None give the
+    full-participation identity (ones, N)."""
+    n_clusters = sig.shape[0]
+    live_v = (jnp.ones((n_clusters,), jnp.float32) if live is None
+              else jnp.asarray(live, jnp.float32).reshape(n_clusters))
+    n_eff_v = (jnp.float32(n_clients) if n_eff is None
+               else jnp.maximum(jnp.asarray(n_eff, jnp.float32), 1.0)
+               .reshape(()))
+    return jnp.concatenate([
+        pass_probability(sig, h_th),
+        p32.reshape(n_clusters * n_clients),
+        jnp.stack([jnp.asarray(noise_std, jnp.float32).reshape(()),
+                   jnp.asarray(ota_on, jnp.float32).reshape(())]),
+        live_v,
+        n_eff_v.reshape(1),
+    ]).reshape(1, n_clusters * (n_clients + 2) + 3)
+
+
 def ota_client_fold_apply(g: jax.Array, p: jax.Array, bits: jax.Array,
                           nbits: jax.Array, sigma2, h_th, noise_std, ota_on,
                           n_clients: int,
@@ -176,19 +197,8 @@ def ota_client_fold_apply(g: jax.Array, p: jax.Array, bits: jax.Array,
                                        noise_std, ota_on, n_clients,
                                        live=live, n_eff=n_eff)
         return out.reshape(shape)
-    live_v = (jnp.ones((n_clusters,), jnp.float32) if live is None
-              else jnp.asarray(live, jnp.float32).reshape(n_clusters))
-    n_eff_v = (jnp.float32(n_clients) if n_eff is None
-               else jnp.maximum(jnp.asarray(n_eff, jnp.float32), 1.0)
-               .reshape(()))
-    params = jnp.concatenate([
-        pass_probability(sig, h_th),
-        p32.reshape(n_clusters * n_clients),
-        jnp.stack([jnp.asarray(noise_std, jnp.float32).reshape(()),
-                   jnp.asarray(ota_on, jnp.float32).reshape(())]),
-        live_v,
-        n_eff_v.reshape(1),
-    ]).reshape(1, n_clusters * (n_clients + 2) + 3)
+    params = _client_params_row(p32, sig, h_th, noise_std, ota_on, live,
+                                n_eff, n_clients)
     main = n - n % ROW_QUANTUM
     outs = []
     if main:
@@ -211,6 +221,35 @@ def ota_client_fold_apply(g: jax.Array, p: jax.Array, bits: jax.Array,
             live=live, n_eff=n_eff))
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
     return out.reshape(shape)
+
+
+def ota_client_fold_drawn_apply(g: jax.Array, p: jax.Array, keys: jax.Array,
+                                word0: int, words, sigma2, h_th, noise_std,
+                                ota_on, n_clients: int, live=None,
+                                n_eff=None, interpret: bool = None):
+    """``ota_client_fold_apply``'s kernel path with the channel words
+    computed in the kernel (``ota_client_fold_drawn_pallas``): the same
+    ĝ from the RAW (C, N, *shape) gradient leaf, whose element count must
+    be a ROW_QUANTUM multiple. ``keys`` is the leaf's flat chunk-key
+    table (C gain streams, then noise), ``word0`` the leaf's first stream
+    position counted from the table's first chunk, ``words`` the word
+    formula. Returns the (*shape,) f32 PS estimate."""
+    if interpret is None:
+        interpret = not on_tpu()
+    n_clusters, n_cl = g.shape[:2]
+    assert n_cl == n_clients, (g.shape, n_clients)
+    n = int(g.size) // (n_clusters * n_clients)
+    assert n % ROW_QUANTUM == 0, (g.shape, ROW_QUANTUM)
+    p32 = jnp.asarray(p, jnp.float32).reshape(n_clusters, n_clients)
+    sig = jnp.asarray(sigma2, jnp.float32).reshape(n_clusters)
+    params = _client_params_row(p32, sig, h_th, noise_std, ota_on, live,
+                                n_eff, n_clients)
+    out = ota_client_fold_drawn_pallas(
+        g.astype(jnp.float32).reshape(n_clusters, n_clients, n // LANE,
+                                      LANE),
+        keys, params, word0=word0, words=words, n_clients=n_clients,
+        interpret=interpret)
+    return out.reshape(g.shape[2:])
 
 
 def ota_stream_fold_apply(g: jax.Array, p_c: jax.Array, bits: jax.Array,

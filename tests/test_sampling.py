@@ -176,18 +176,20 @@ def test_streaming_hlo_holds_one_cluster():
     """Peak-memory pin: the compiled streaming aggregation contains NO
     (C, L_s) stream/mask buffer for any section and no (C, P) slab — the
     scan body holds one cluster's draw plus the leaf-shaped running sum.
-    The all-at-once path compiles exactly such a buffer (positive
-    control, so this pin cannot rot into vacuity)."""
+    The all-at-once path in supplied mode, which draws whole chunks,
+    compiles exactly such a buffer (positive control, so this pin cannot
+    rot into vacuity)."""
     fl, chan, key, g, p, packer = _setup()
     P = packer.size
     lengths = sorted({sec.length for sec in packer.sections})
 
-    def lower(agg):
+    def lower(agg, **kw):
         return jax.jit(lambda k, gg, pp: agg(
-            k, gg, pp, chan, N, packer)).lower(key, g, p).compile().as_text()
+            k, gg, pp, chan, N, packer, **kw)).lower(
+                key, g, p).compile().as_text()
 
     hlo_s = lower(ota.ota_aggregate_streaming)
-    hlo_c = lower(ota.ota_aggregate_client_folded)
+    hlo_c = lower(ota.ota_aggregate_client_folded, bits_mode="supplied")
     hlo_audit.assert_hlo_pins(
         hlo_s,
         hlo_audit.no_cluster_stream_pins(C, lengths + [P, ota.CHUNK]),

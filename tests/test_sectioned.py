@@ -7,7 +7,7 @@ client-folded engine (streaming=False) and to the cluster-scan streaming
 engine (streaming=True) under every composed feature (faults via
 live/n_eff, split layouts via max_section_rows); the peak-memory HLO
 pins with positive controls (the packed engine's (C, P) slab, the
-client-folded engine's (C, CHUNK) stream draw); the no-silent-inertness
+supplied-mode client-folded engine's (C, CHUNK) stream draw); the no-silent-inertness
 refusals (HotaSim build guards, the distributed step's ota_streaming
 rejection, ``apply_layout``'s named LayoutUnavailableError, the stale
 disk-cache re-measure path, LayoutBudgetError); the C-axis-blocked
@@ -188,8 +188,9 @@ def test_sectioned_hlo_no_full_slab():
 def test_sectioned_streaming_hlo_holds_one_cluster_one_section():
     """Composed with the cluster scan, the peak drops further: no
     (C, ·) stream buffer at ANY size — per-section AND per-cluster.
-    Positive control: the all-clusters engines (client-folded and
-    sectioned streaming=False) draw the (C, CHUNK) chunked stream."""
+    Positive control: the all-clusters engines that draw whole chunks
+    (client-folded in supplied mode, sectioned streaming=False) draw the
+    (C, CHUNK) chunked stream."""
     setup = _setup()
     _, chan, key, g, p, packer = setup
     lengths = sorted({sec.length for sec in packer.sections})
@@ -199,7 +200,8 @@ def test_sectioned_streaming_hlo_holds_one_cluster_one_section():
         hlo_audit.no_cluster_stream_pins(
             C, lengths + [packer.size, ota.CHUNK]),
         context="sectioned(streaming=True) — one-cluster peak (§3.16)")
-    for agg, kw in ((ota.ota_aggregate_client_folded, {}),
+    for agg, kw in ((ota.ota_aggregate_client_folded,
+                     {"bits_mode": "supplied"}),
                     (ota.ota_aggregate_sectioned, {})):
         hlo_c = _lower(agg, setup, **kw)
         hlo_audit.assert_hlo_pins(

@@ -19,9 +19,11 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import ota
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.masked_gradnorm.ops import masked_gradnorm
 from repro.kernels.ota_channel.kernel import (
@@ -29,7 +31,7 @@ from repro.kernels.ota_channel.kernel import (
 )
 from repro.kernels.ota_channel.ops import (
     _channel_params_block, ota_aggregate, ota_client_fold_apply,
-    ota_mask_count_apply, ota_mask_weight_apply,
+    ota_client_fold_drawn_apply, ota_mask_count_apply, ota_mask_weight_apply,
 )
 from repro.kernels.slab import LANE
 
@@ -96,6 +98,95 @@ def test_client_fold_compiles(one_chip):
                                      1.0, N, interpret=False, impl="pallas")
     _compile(fn, one_chip, "ota_client_fold", ((C, N, 1024, 2048), F32),
              ((C, N), F32), ((C, P), U32), ((P,), U32), ((C,), F32))
+
+
+def test_client_fold_drawn_compiles(one_chip):
+    """The fused-mode hot path: the kernel computes its gain and noise
+    words from their stream positions (fc2.w starts 2048 words into its
+    section, so its 16384 rows span 17 chunks of the key table)."""
+    word0 = 2048
+    n_chunks = (word0 + P - 1) // ota.CHUNK + 1
+
+    def fn(g, p, keys, sig):
+        return ota_client_fold_drawn_apply(g, p, keys, word0,
+                                           ota.stream_words, sig, 3.2e-2,
+                                           1.0, 1.0, N, interpret=False)
+    _compile(fn, one_chip, "ota_client_fold_drawn",
+             ((C, N, 1024, 2048), F32), ((C, N), F32),
+             (((C + 1) * n_chunks * 2,), U32), ((C,), F32))
+
+
+@pytest.fixture(scope="module")
+def fused_round(one_chip, no_persistent_cache):
+    """The paper round (``HotaSim._step``, fused words) compiled for the
+    described chip, steered onto its TPU path: (module text, the same
+    module printed with operand shapes, as a profiler trace names ops)."""
+    from jax._src.lib import xla_client
+    from repro.common.config import FLConfig, ModelConfig, TrainConfig
+    from repro.core.sim import HotaSim
+    from repro.kernels.masked_gradnorm import ops as gradnorm_ops
+    from repro.kernels.ota_channel import ops as channel_ops
+    from repro.models.model import build_model
+    # wrappers such as masked_gradnorm are jitted and pick their platform
+    # path at trace time: no trace may cross the patch in either direction
+    jax.clear_caches()
+    with pytest.MonkeyPatch.context() as mp:
+        for mod in (ota, channel_ops, gradnorm_ops):
+            mp.setattr(mod, "on_tpu", lambda: True)
+        sim = HotaSim(build_model(ModelConfig(family="mlp")),
+                      FLConfig(n_clusters=C, n_clients=N),
+                      TrainConfig(lr=3e-4), [8, 8, 8])
+
+        def spec(tree):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), tree)
+        state = jax.eval_shape(sim.init, jax.random.PRNGKey(0))
+        compiled = HotaSim._step.lower(
+            sim, spec(state),
+            jax.ShapeDtypeStruct((C, N, 24, 256), F32, sharding=one_chip),
+            jax.ShapeDtypeStruct((C, N, 24), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((2,), U32, sharding=one_chip),
+            spec(sim.chan), spec(sim.faults)).compile()
+    jax.clear_caches()
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    opts.print_metadata = False
+    opts.print_backend_config = False
+    shaped = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return compiled.as_text(), shaped
+
+
+def test_fused_round_holds_no_chunk_words(fused_round):
+    """No array the round keeps in memory is a chunked draw: every u32
+    array outside a fusion body is smaller than C × CHUNK words (the old
+    draw kept u32[C, 17·CHUNK] and its pads and copies)."""
+    from repro.launch.hlo_cost import parse_hlo
+    text, _ = fused_round
+    assert re.search(r"%ota_client_fold_drawn(\.\d+)? = .*custom-call\(",
+                     text)
+    assert not re.search(r"%ota_client_fold(\.\d+)? = ", text)
+    comps, _ = parse_hlo(text)
+    fused = {m for comp in comps.values() for op in comp.ops
+             if op.opcode == "fusion"
+             for m in re.findall(r"calls=%?([\w.\-]+)", op.attrs)}
+    big = sorted({
+        (op.name, shape) for name, comp in comps.items() if name not in fused
+        for op in comp.ops for dtype, shape in op.result_shapes
+        if dtype == "u32" and np.prod(shape) >= C * ota.CHUNK})
+    assert not big, big
+
+
+def test_roofline_reader_finds_the_drawing_kernel(fused_round):
+    """``ota_client_fold_roofline`` reads the kernel by its operands:
+    the gradient block first, a u32 operand (the key table) among them.
+    Each drawing-kernel call of the round must still match."""
+    from bench.metrics.ota_client_fold_roofline import is_client_fold
+    _, shaped = fused_round
+    calls = [line for line in shaped.splitlines()
+             if re.match(r"\s*(ROOT )?%?ota_client_fold_drawn(\.\d+)? = ",
+                         line)]
+    assert len(calls) == 7           # the paper MLP's leaves of >= 1024
+    assert all(is_client_fold(line) for line in calls)
 
 
 def test_client_fold_cluster_blocked_compiles(one_chip):
