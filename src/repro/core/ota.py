@@ -608,10 +608,12 @@ def ota_aggregate_client_folded(
 
     * ``"fused"``: each leaf's words come from their stream positions
       (DESIGN.md §4, position form). On the kernel path the leaf's
-      ROW_QUANTUM main body runs ``ota_client_fold_drawn``, which
-      computes them in the kernel from a table of chunk keys, so no word
-      reaches HBM; the ragged remainder and the jnp path draw exactly
-      their positions with ``stream_words_at``.
+      ROW_QUANTUM main body runs the drawing kernel, which computes
+      them from a table of chunk keys, so no word reaches HBM; a 2-D
+      leaf of whole (8, 128) tiles goes in its own layout
+      (``ota_client_fold_drawn2d``), others as their flat (rows, 128)
+      view (``ota_client_fold_drawn``). The ragged remainder and the
+      jnp path draw exactly their positions with ``stream_words_at``.
     * ``"supplied"``: the streams are drawn once per (section, cluster)
       outside the kernels and sliced per leaf. The draw depends only on
       ``key``, so under ``ScenarioBank``'s scenario vmap (shared key,
@@ -660,22 +662,22 @@ def ota_aggregate_client_folded(
                 key, folds[run.section], n_clusters)
         keys = skeys[run.section]
         g = leaves[run.leaf]
-        flat = g.reshape(n_clusters, n_clients, run.size)
         main = run.size - run.size % ROW_QUANTUM if drawn else 0
         parts = []
         if main:
+            # a whole leaf goes in its own layout: the kernel wrapper picks
+            # the native 2-D blocks or the flat view from its shape
             j0, table = _chunk_key_table(keys, run.offset, main)
             parts.append(ota_client_fold_drawn_apply(
-                jax.lax.slice(flat, (0, 0, 0),
-                              (n_clusters, n_clients, main)),
+                g if main == run.size else g.reshape(
+                    n_clusters, n_clients, run.size)[..., :main],
                 p, table.reshape(-1), run.offset - j0 * CHUNK, stream_words,
                 chan.sigma2, chan.h_threshold, chan.noise_std, chan.ota_on,
                 n_clients, **fold_kw))
         if run.size - main:
             w = stream_words_at(keys, run.offset + main, run.size - main)
             parts.append(ota_client_fold_apply(
-                jax.lax.slice(flat, (0, 0, main),
-                              (n_clusters, n_clients, run.size)),
+                g.reshape(n_clusters, n_clients, run.size)[..., main:],
                 p, w[:n_clusters], w[n_clusters], chan.sigma2,
                 chan.h_threshold, chan.noise_std, chan.ota_on, n_clients,
                 impl=impl, **fold_kw))

@@ -34,7 +34,11 @@
   are the stream's word formula (``repro.core.ota.stream_words``)
   evaluated at its stream position under its chunk's key, read from a
   small key table in SMEM — the same words as the chunked draw, none of
-  them in HBM (DESIGN.md §4, position form).
+  them in HBM (DESIGN.md §4, position form). A 2-D leaf of whole (8, 128)
+  tiles is read in its own (a, b) layout (``ota_client_fold_drawn2d``),
+  element (r, c) at position ``word0 + r·b + c``, with each draw tile
+  spanning at most one chunk (``drawn_native``); any other leaf is read
+  as its flat (rows, 128) view (``ota_client_fold_drawn``).
 
 Channel knobs (the per-cluster pass probabilities, noise std, the ota_on
 gate) arrive as one traced (1, C+2) params block, so scenario sweeps
@@ -101,16 +105,17 @@ def _bits_mask(bits, p_pass, off):
 
 def _pick_block_rows(rows: int, n_slabs: int,
                      block_rows: int = DEFAULT_BLOCK_ROWS,
-                     interpret: bool = False) -> int:
+                     interpret: bool = False, width: int = LANE) -> int:
     """Largest row-block <= block_rows dividing ``rows`` that keeps
-    ``n_slabs`` concurrent (block, 128) f32 buffers under the VMEM budget.
+    ``n_slabs`` concurrent (block, width) f32 buffers under the VMEM
+    budget.
 
     Interpret mode has no VMEM: one whole-slab grid step avoids the
     interpreter's per-block copy overhead (~10x on the 1M-param slab).
     """
     if interpret:
         return rows
-    cap = max(SUBLANE, VMEM_BUDGET_BYTES // (n_slabs * LANE * 4))
+    cap = max(SUBLANE, VMEM_BUDGET_BYTES // (n_slabs * width * 4))
     br = min(block_rows, rows, cap - cap % SUBLANE)
     br = max(SUBLANE, br - br % SUBLANE)
     while rows % br:
@@ -452,39 +457,74 @@ def ota_aggregate_client_pallas(
     )(x, bits, nbits, params.astype(jnp.float32))
 
 
-# rows per in-kernel draw tile: the threefry chain of one tile's stream
-# (8 vregs per operand) stays near the registers while the tile loop
-# walks the block (64 timed fastest of 8-128 on one v5e at the largest
-# paper leaf)
-DRAW_TILE_ROWS = 64
+# words per in-kernel draw tile, 8 vregs per operand: the threefry chain
+# of one tile's stream stays near the registers while the tile loop walks
+# the block (64 rows of 128 timed fastest of 8-128 rows on one v5e at the
+# largest paper leaf). A native block's tile holds as many words in
+# (rows, bc): (8, 1024) at the paper's fc2.w.
+DRAW_TILE_WORDS = 64 * LANE
+# widest column block of the native path: its draw tile keeps 8 rows
+DRAW_TILE_COLS = DRAW_TILE_WORDS // SUBLANE
+
+
+def _draw_tile_rows(bc: int) -> int:
+    """Most rows of a draw tile over ``bc`` columns: DRAW_TILE_WORDS
+    words, never fewer than one sublane group."""
+    t = DRAW_TILE_WORDS // bc
+    return max(SUBLANE, t - t % SUBLANE)
+
+
+def _native_cols(b: int) -> int:
+    """Column block of a native (a, b) leaf: the widest multiple of LANE
+    up to DRAW_TILE_COLS that divides ``b``."""
+    bc = min(b, DRAW_TILE_COLS)
+    while b % bc:
+        bc -= LANE
+    return bc
+
+
+def drawn_native(shape) -> bool:
+    """Whether the drawing kernel reads a leaf of this per-client shape in
+    its own layout: two dims (a, b) of whole (8, 128) tiles (``a % 8 ==
+    0``, ``b % 128 == 0``) whose draw tile spans at most one chunk of
+    positions, so it touches at most two chunks. Other leaves take the
+    flat (rows, 128) view."""
+    if len(shape) != 2 or shape[0] % SUBLANE or shape[1] % LANE:
+        return False
+    b = shape[1]
+    bc = _native_cols(b)
+    return (_draw_tile_rows(bc) - 1) * b + bc <= CHUNK_ROWS * LANE
 
 
 def _ota_client_fold_drawn_kernel(x_ref, keys_ref, params_ref, out_ref,
                                   words_ref, *, n_clusters, n_clients,
-                                  n_chunks, word0, words):
+                                  n_chunks, word0, stride, words):
     """Client-folded PS estimator that computes its channel words itself
     (DESIGN.md §4, position form): word m of chunk j of a stream is
     ``words(k0, k1, m)`` under that chunk's key, so each element's gain
     and noise words come from its stream position and no word is read
     from HBM. ``keys_ref`` (SMEM, flat) holds the (k0, k1) chunk keys of
-    the C gain streams and the noise stream over the leaf's chunks;
-    ``word0`` is the leaf's first position counted from the first of
-    them. A tile spans fewer words than a chunk, so it touches at most
-    two chunks: each element picks the first or the next key. Per tile a
-    loop over the C+1 streams (one threefry in the program, not C+1)
-    fills ``words_ref`` (VMEM); the mask, MAC and estimate are the
-    supplied-words kernel's, unchanged."""
-    br = out_ref.shape[0]
+    the C gain streams and the noise stream over the leaf's chunks.
+    Element (r, c) of the (a, ``stride``) leaf sits at position
+    ``word0 + r·stride + c`` counted from the first of them (the flat
+    view is stride 128). A draw tile spans fewer positions than a chunk
+    (``drawn_native``), so it touches at most two chunks: each element
+    picks the first or the next key. Per tile a loop over the C+1
+    streams (one threefry in the program, not C+1) fills ``words_ref``
+    (VMEM); the mask, MAC and estimate are the supplied-words kernel's,
+    unchanged."""
+    br, bc = out_ref.shape
     tile = words_ref.shape[1]
     shift = CHUNK_ROWS.bit_length() - 1 + LANE.bit_length() - 1
-    base = word0 + pl.program_id(0) * (br * LANE)
-    lane_pos = (jax.lax.broadcasted_iota(jnp.int32, (tile, LANE), 0) * LANE
-                + jax.lax.broadcasted_iota(jnp.int32, (tile, LANE), 1))
+    base = (word0 + pl.program_id(0) * (br * stride)
+            + pl.program_id(1) * bc)
+    tile_pos = (jax.lax.broadcasted_iota(jnp.int32, (tile, bc), 0) * stride
+                + jax.lax.broadcasted_iota(jnp.int32, (tile, bc), 1))
 
     def body(t, carry):
         r0 = pl.multiple_of(t * tile, tile)
-        p0 = base + r0 * LANE
-        pos = p0 + lane_pos
+        p0 = base + r0 * stride
+        pos = p0 + tile_pos
         m = (pos & ((1 << shift) - 1)).astype(jnp.uint32)
         ja = p0 >> shift
         jb = jnp.minimum(ja + 1, n_chunks - 1)
@@ -499,7 +539,7 @@ def _ota_client_fold_drawn_kernel(x_ref, keys_ref, params_ref, out_ref,
 
         jax.lax.fori_loop(0, n_clusters + 1, draw, 0)
         rows = pl.ds(r0, tile)
-        zeros = jnp.zeros((tile, LANE), jnp.float32)
+        zeros = jnp.zeros((tile, bc), jnp.float32)
         acc, cnt = _client_fold_block(lambda l, i: x_ref[l, i, rows, :],
                                       lambda l: words_ref[l], params_ref,
                                       n_clusters, n_clients, zeros, zeros)
@@ -511,7 +551,7 @@ def _ota_client_fold_drawn_kernel(x_ref, keys_ref, params_ref, out_ref,
 
 
 def ota_client_fold_drawn_pallas(
-    x: jax.Array,            # (C, N, rows, 128) f32 — RAW per-client grads
+    x: jax.Array,            # (C, N, a, b) f32 — RAW per-client grads
     keys: jax.Array,         # ((C+1)·n_chunks·2,) uint32 chunk keys
     params: jax.Array,       # (1, C·(N+2)+3), as ota_aggregate_client_pallas
     *,
@@ -520,45 +560,57 @@ def ota_client_fold_drawn_pallas(
     n_clients: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     interpret: bool = False,
+    name: str = "ota_client_fold_drawn",
 ) -> jax.Array:
     """``ota_aggregate_client_pallas`` with the channel words drawn in
-    the kernel. ``keys`` is the flat table of chunk keys, stream-major
-    (C gain streams, then the noise stream), chunk, then (k0, k1);
-    ``word0`` (static) is the position of the slab's first element in the
-    first chunk; ``words(k0, k1, m)`` is the stream's word formula
-    (``repro.core.ota.stream_words``). Operand 0 stays the gradient block
-    and the table a plain uint32 operand in SMEM, so a trace reads this
-    call as a client-fold kernel that takes channel words. Callers check
-    ``_client_cluster_block`` first: the drawing kernel has no C-blocked
-    variant."""
-    n_clusters, n_cl, rows, lane = x.shape
-    assert lane == LANE and n_cl == n_clients, (x.shape, n_clients)
+    the kernel; returns the (a, b) PS estimate. ``keys`` is the flat
+    table of chunk keys, stream-major (C gain streams, then the noise
+    stream), chunk, then (k0, k1); ``word0`` (static) is the position of
+    element (0, 0) in the first chunk; ``words(k0, k1, m)`` is the
+    stream's word formula (``repro.core.ota.stream_words``).
+
+    ``x`` is any (C, N, a, b) block of whole (8, 128) tiles
+    (``drawn_native``): a 2-D leaf in its own layout, or a leaf's flat
+    (rows, 128) view. It is read in (br, bc) blocks, bc the widest LANE
+    multiple up to DRAW_TILE_COLS dividing b, br at most ``block_rows``
+    with the C·N + 2 resident blocks under the VMEM budget (one whole
+    block in interpret mode). ``name`` names the call, so a trace tells
+    the layouts apart (``ops.ota_client_fold_drawn_apply``). Operand 0
+    stays the gradient block and the table a plain uint32 operand in
+    SMEM, so a trace reads the call as a kernel that takes channel words.
+    Callers check ``_client_cluster_block`` first: the drawing kernel has
+    no C-blocked variant."""
+    n_clusters, n_cl, a, b = x.shape
+    assert n_cl == n_clients, (x.shape, n_clients)
+    assert drawn_native((a, b)), x.shape
     assert params.shape == (1, n_clusters * (n_clients + 2) + 3), params.shape
     n_chunks = keys.shape[0] // (2 * (n_clusters + 1))
     assert keys.shape == (2 * (n_clusters + 1) * n_chunks,), keys.shape
-    assert 0 <= word0 and word0 + rows * LANE <= n_chunks * CHUNK_ROWS * LANE
+    assert 0 <= word0 and word0 + a * b <= n_chunks * CHUNK_ROWS * LANE
     from jax.experimental.pallas import tpu as pltpu
+    bc = _native_cols(b)
     # C·N grad blocks + out resident at once; the words never leave the core
-    br = _pick_block_rows(rows, n_clusters * n_clients + 2, block_rows,
-                          interpret)
+    br = _pick_block_rows(a, n_clusters * n_clients + 2, block_rows,
+                          interpret, width=bc)
     kernel = functools.partial(
         _ota_client_fold_drawn_kernel, n_clusters=n_clusters,
-        n_clients=n_clients, n_chunks=n_chunks, word0=word0, words=words)
+        n_clients=n_clients, n_chunks=n_chunks, word0=word0, stride=b,
+        words=words)
     return pl.pallas_call(
         kernel,
-        grid=(rows // br,),
-        name="ota_client_fold_drawn",
+        grid=(a // br, b // bc),
+        name=name,
         in_specs=[
-            pl.BlockSpec((n_clusters, n_clients, br, LANE),
-                         lambda i: (0, 0, i, 0)),
+            pl.BlockSpec((n_clusters, n_clients, br, bc),
+                         lambda i, j: (0, 0, i, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, n_clusters * (n_clients + 2) + 3),
-                         lambda i: (0, 0)),
+                         lambda i, j: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+        out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((a, b), jnp.float32),
         scratch_shapes=[pltpu.VMEM(
-            (n_clusters + 1, math.gcd(br, DRAW_TILE_ROWS), LANE),
+            (n_clusters + 1, math.gcd(br, _draw_tile_rows(bc)), bc),
             jnp.uint32)],
         interpret=interpret,
     )(x, keys.astype(jnp.uint32), params.astype(jnp.float32))

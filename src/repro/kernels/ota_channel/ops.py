@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.ota_channel.kernel import (
-    ota_aggregate_client_pallas, ota_aggregate_fused_pallas,
+    drawn_native, ota_aggregate_client_pallas, ota_aggregate_fused_pallas,
     ota_aggregate_pallas, ota_channel_pallas, ota_client_fold_drawn_pallas,
     ota_mask_count_pallas, ota_mask_weight_pallas,
 )
@@ -230,26 +230,32 @@ def ota_client_fold_drawn_apply(g: jax.Array, p: jax.Array, keys: jax.Array,
     """``ota_client_fold_apply``'s kernel path with the channel words
     computed in the kernel (``ota_client_fold_drawn_pallas``): the same
     ĝ from the RAW (C, N, *shape) gradient leaf, whose element count must
-    be a ROW_QUANTUM multiple. ``keys`` is the leaf's flat chunk-key
-    table (C gain streams, then noise), ``word0`` the leaf's first stream
-    position counted from the table's first chunk, ``words`` the word
-    formula. Returns the (*shape,) f32 PS estimate."""
+    be a ROW_QUANTUM multiple. A leaf of whole (8, 128) tiles
+    (``drawn_native``) is read in its own (a, b) layout, in a call named
+    ``ota_client_fold_drawn2d``; any other takes the flat (rows, 128)
+    view, named ``ota_client_fold_drawn``. ``keys`` is the leaf's flat
+    chunk-key table (C gain streams, then noise), ``word0`` the leaf's
+    first stream position counted from the table's first chunk, ``words``
+    the word formula. Returns the (*shape,) f32 PS estimate."""
     if interpret is None:
         interpret = not on_tpu()
     n_clusters, n_cl = g.shape[:2]
     assert n_cl == n_clients, (g.shape, n_clients)
+    shape = g.shape[2:]
     n = int(g.size) // (n_clusters * n_clients)
     assert n % ROW_QUANTUM == 0, (g.shape, ROW_QUANTUM)
     p32 = jnp.asarray(p, jnp.float32).reshape(n_clusters, n_clients)
     sig = jnp.asarray(sigma2, jnp.float32).reshape(n_clusters)
     params = _client_params_row(p32, sig, h_th, noise_std, ota_on, live,
                                 n_eff, n_clients)
+    x, name = g.astype(jnp.float32), "ota_client_fold_drawn2d"
+    if not drawn_native(shape):
+        x = x.reshape(n_clusters, n_clients, n // LANE, LANE)
+        name = "ota_client_fold_drawn"
     out = ota_client_fold_drawn_pallas(
-        g.astype(jnp.float32).reshape(n_clusters, n_clients, n // LANE,
-                                      LANE),
-        keys, params, word0=word0, words=words, n_clients=n_clients,
-        interpret=interpret)
-    return out.reshape(g.shape[2:])
+        x, keys, params, word0=word0, words=words, n_clients=n_clients,
+        interpret=interpret, name=name)
+    return out.reshape(shape)
 
 
 def ota_stream_fold_apply(g: jax.Array, p_c: jax.Array, bits: jax.Array,

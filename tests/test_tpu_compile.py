@@ -101,9 +101,10 @@ def test_client_fold_compiles(one_chip):
 
 
 def test_client_fold_drawn_compiles(one_chip):
-    """The fused-mode hot path: the kernel computes its gain and noise
-    words from their stream positions (fc2.w starts 2048 words into its
-    section, so its 16384 rows span 17 chunks of the key table)."""
+    """The fused-mode hot path on the flat view: the kernel computes its
+    gain and noise words from their stream positions. A 1-D leaf of
+    fc2.w's size takes the (rows, 128) view (starting 2048 words into
+    its section, its 16384 rows span 17 chunks of the key table)."""
     word0 = 2048
     n_chunks = (word0 + P - 1) // ota.CHUNK + 1
 
@@ -112,7 +113,26 @@ def test_client_fold_drawn_compiles(one_chip):
                                            ota.stream_words, sig, 3.2e-2,
                                            1.0, 1.0, N, interpret=False)
     _compile(fn, one_chip, "ota_client_fold_drawn",
-             ((C, N, 1024, 2048), F32), ((C, N), F32),
+             ((C, N, P), F32), ((C, N), F32),
+             (((C + 1) * n_chunks * 2,), U32), ((C,), F32))
+
+
+@pytest.mark.parametrize("shape,word0", [((1024, 2048), 2048),
+                                         ((512, 256), 1024)],
+                         ids=["fc2_w", "final_w"])
+def test_client_fold_drawn2d_compiles(one_chip, shape, word0):
+    """The drawing kernel on a leaf in its own (a, b) layout, at fc2.w
+    (column blocks of 1024, draw tiles of 8 rows) and final.w (whole
+    256-wide rows, tiles of 32)."""
+    size = shape[0] * shape[1]
+    n_chunks = (word0 + size - 1) // ota.CHUNK + 1
+
+    def fn(g, p, keys, sig):
+        return ota_client_fold_drawn_apply(g, p, keys, word0,
+                                           ota.stream_words, sig, 3.2e-2,
+                                           1.0, 1.0, N, interpret=False)
+    _compile(fn, one_chip, "ota_client_fold_drawn2d",
+             ((C, N) + shape, F32), ((C, N), F32),
              (((C + 1) * n_chunks * 2,), U32), ((C,), F32))
 
 
@@ -177,16 +197,42 @@ def test_fused_round_holds_no_chunk_words(fused_round):
 
 
 def test_roofline_reader_finds_the_drawing_kernel(fused_round):
-    """``ota_client_fold_roofline`` reads the kernel by its operands:
-    the gradient block first, a u32 operand (the key table) among them.
-    Each drawing-kernel call of the round must still match."""
+    """``ota_client_fold_drawn_roofline`` reads the kernel by its name and
+    a u32 operand (the key table) among its operands. Each drawing-kernel
+    call of the round must match: the flat-view calls (fc1.b, fc2.b) and
+    the 2-D leaves' calls (``ota_client_fold_drawn2d``, operand (C, N, a,
+    b)); the HBM bytes it counts hold the largest leaf's gradients.
+    ``ota_client_fold_roofline``'s shape match, a (d, d, d, 128) first
+    operand, sees the two flat-view calls alone."""
+    from bench.metrics.ota_client_fold_drawn_roofline import (
+        hbm_bytes, is_drawn,
+    )
     from bench.metrics.ota_client_fold_roofline import is_client_fold
     _, shaped = fused_round
-    calls = [line for line in shaped.splitlines()
-             if re.match(r"\s*(ROOT )?%?ota_client_fold_drawn(\.\d+)? = ",
-                         line)]
+    call = re.compile(r"\s*(ROOT )?%?ota_client_fold_drawn(2d)?(\.\d+)? = ")
+    calls = [line for line in shaped.splitlines() if call.match(line)]
     assert len(calls) == 7           # the paper MLP's leaves of >= 1024
-    assert all(is_client_fold(line) for line in calls)
+    assert all(is_drawn(line) for line in calls)
+    flat = [line for line in calls if is_client_fold(line)]
+    assert len(flat) == 2
+    assert all("ota_client_fold_drawn2d" not in line for line in flat)
+    # at least fc2.w's (C, N, 1024, 2048) f32 gradients come from HBM
+    assert sum(hbm_bytes(line) for line in calls) >= 4 * C * N * P
+
+
+def test_fused_round_reads_gradients_in_place(fused_round):
+    """The five 2-D leaves reach the drawing kernel as (C, N, a, b): no op
+    of the round makes their flat (C, N, rows, 128) view (fc2.w 16384
+    rows, fc3.w 8192, fc1.w 4096, fc0.w and final.w 1024)."""
+    text, shaped = fused_round
+    views = re.findall(r"= f32\[10,3,(?:16384|8192|4096|1024),128\]", text)
+    assert not views, views
+    operands = re.findall(
+        r"%?ota_client_fold_drawn2d(?:\.\d+)? = .*?custom-call\("
+        r"f32\[10,3,(\d+),(\d+)\]", shaped)
+    assert sorted(operands) == sorted([
+        ("1024", "2048"), ("2048", "512"), ("512", "1024"), ("256", "512"),
+        ("512", "256")]), operands
 
 
 def test_client_fold_cluster_blocked_compiles(one_chip):
