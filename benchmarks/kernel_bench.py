@@ -1,12 +1,6 @@
 """Kernel microbenchmarks: us/call of each Pallas kernel (interpret mode on
 this CPU container — wall times are NOT TPU times; the oracle comparison
 shows relative cost of the fused formulation) and of the pure-jnp oracle.
-
-``packed_rows`` is the tentpole comparison: the flat-packed whole-model
-``ota_aggregate`` (one fused pass) vs the per-leaf jnp path
-(``ota.ota_aggregate_tree``, one gain/mask/noise draw per leaf per
-cluster), at paper-MLP scale and at 1M/16M params, banked (S scenarios
-vmapped over a ChannelParams bank) and unbanked.
 """
 from __future__ import annotations
 
@@ -18,7 +12,6 @@ import jax.numpy as jnp
 from repro.kernels import (
     flash_attention, flash_attention_reference,
     masked_gradnorm, masked_gradnorm_reference,
-    ota_channel, ota_channel_reference,
 )
 
 
@@ -35,13 +28,6 @@ def _time(fn, *args, iters=5, **kw):
 def run():
     rows = []
     key = jax.random.PRNGKey(0)
-
-    x = jax.random.normal(key, (1 << 20,))
-    rows.append(("ota_channel_pallas_1M", _time(ota_channel, x, key, 1.0, 0.032),
-                 "fused bits->gauss->mask->apply"))
-    rows.append(("ota_channel_ref_1M",
-                 _time(ota_channel_reference, x, key, 1.0, 0.032),
-                 "jnp oracle"))
 
     g = jax.random.normal(key, (8, 1 << 16))
     m = jax.random.uniform(jax.random.fold_in(key, 1), (1 << 16,)) > 0.3
@@ -62,101 +48,6 @@ def run():
         "interpret mode"))
     rows.append(("flash_attn_ref_1k", _time(
         flash_attention_reference, q, k, v, iters=2), "jnp oracle"))
-    return rows
-
-
-def _ota_tree(n_params: int, n_leaves: int, C: int, key) -> dict:
-    """Synthetic per-cluster weighted-grad pytree: ``n_leaves`` trunk
-    leaves + a final leaf of ~5% of the params (the ω̃ tail)."""
-    final_n = max(128, n_params // 20)
-    trunk_n = max(128, (n_params - final_n) // n_leaves)
-    tree = {"final": {"w": jax.random.normal(key, (C, final_n))},
-            "trunk": {}}
-    for i in range(n_leaves):
-        tree["trunk"][f"l{i}"] = jax.random.normal(
-            jax.random.fold_in(key, i + 1), (C, trunk_n))
-    return tree
-
-
-def _paper_mlp_tree(C: int, key) -> dict:
-    """The real paper-MLP omega shapes (Table I), per-cluster batched."""
-    from repro.common.config import ModelConfig
-    from repro.models.model import build_model
-    from repro.models.params import ParamSpec
-
-    model = build_model(ModelConfig(family="mlp"))
-    specs = {"final": model.final_specs(), "trunk": model.trunk_specs()}
-    i = [0]
-
-    def draw(spec):
-        i[0] += 1
-        return jax.random.normal(jax.random.fold_in(key, i[0]),
-                                 (C,) + spec.shape)
-    return jax.tree.map(draw, specs,
-                        is_leaf=lambda x: isinstance(x, ParamSpec))
-
-
-def packed_rows(n_scenarios: int = 8, iters: int = 3, quick: bool = False):
-    """Flat-packed kernel vs per-leaf jnp OTA aggregation."""
-    from repro.common.config import FLConfig
-    from repro.common.flatpack import packer_for
-    from repro.core import ota
-    from repro.core.channel import channel_params, stack_channel_params
-
-    rows = []
-    key = jax.random.PRNGKey(0)
-    cases = [
-        ("paperMLP_3.9M", None, 10),            # real Table-I shapes
-        ("1M_x32leaves", (1 << 20, 32), 10),
-        ("16M_x64leaves", (1 << 24, 64), 10),   # (C, P) slab = 640 MB
-    ]
-    if quick:                                   # CI smoke: small case only
-        cases, n_scenarios, iters = cases[:1], min(n_scenarios, 4), 1
-    for label, spec, C in cases:
-        if spec is None:
-            wg = _paper_mlp_tree(C, key)
-        else:
-            wg = _ota_tree(spec[0], spec[1], C, key)
-        n_leaves = len(jax.tree.leaves(wg))
-        fl = FLConfig(n_clusters=C, n_clients=3,
-                      sigma2=tuple(0.25 + 0.25 * i for i in range(C)))
-        chan = channel_params(fl)
-        template = jax.tree.map(
-            lambda l: jax.ShapeDtypeStruct(l.shape[1:], l.dtype), wg)
-        packer = packer_for(template, tail="final")
-
-        f_pack = jax.jit(lambda k, w, ch: ota.ota_aggregate_packed(
-            k, w, ch, 3, packer))
-        f_leaf = jax.jit(lambda k, w, ch: ota.ota_aggregate_tree(k, w, ch, 3))
-        t_pack = _time(f_pack, key, wg, chan, iters=iters)
-        t_leaf = _time(f_leaf, key, wg, chan, iters=iters)
-        rows.append((f"ota_agg_packed_{label}", t_pack,
-                     f"{n_leaves} leaves,C={C};1 fused kernel"))
-        rows.append((f"ota_agg_perleaf_{label}", t_leaf,
-                     f"jnp per-leaf;packed_speedup={t_leaf / t_pack:.2f}x"))
-
-        # banked: vmap over an (S,)-batched ChannelParams bank (CRN: shared
-        # key and weighted grads — the ScenarioBank composition)
-        bank = stack_channel_params(
-            [channel_params(FLConfig(
-                n_clusters=C, n_clients=3,
-                sigma2=(0.25 + 0.25 * (s % 8),),
-                ota=(s % 4 != 3))) for s in range(n_scenarios)])
-        # supplied bits mode = ScenarioBank's composition: the bit draw
-        # hoists out of the scenario vmap (it depends only on the shared key)
-        fb_pack = jax.jit(jax.vmap(
-            lambda ch, k, w: ota.ota_aggregate_packed(
-                k, w, ch, 3, packer, bits_mode="supplied"),
-            in_axes=(0, None, None)))
-        fb_leaf = jax.jit(jax.vmap(
-            lambda ch, k, w: ota.ota_aggregate_tree(k, w, ch, 3),
-            in_axes=(0, None, None)))
-        tb_pack = _time(fb_pack, bank, key, wg, iters=iters)
-        tb_leaf = _time(fb_leaf, bank, key, wg, iters=iters)
-        rows.append((f"ota_agg_packed_S{n_scenarios}_{label}", tb_pack,
-                     "banked vmap"))
-        rows.append((f"ota_agg_perleaf_S{n_scenarios}_{label}", tb_leaf,
-                     f"packed_speedup={tb_leaf / tb_pack:.2f}x"))
     return rows
 
 
@@ -196,9 +87,7 @@ def client_folded_rows(n_scenarios: int = 8, iters: int = 3,
     formulation (einsum the client weights, then per-leaf jnp channel) —
     BOTH paths start from the RAW (C, N, ...) gradient tree + the (C, N)
     weight matrix, i.e. exactly what ``HotaSim.step_with_channel`` holds
-    after the local phase. This is the sim-hot-path comparison the old
-    ``packed_rows`` (pre-weighted wg input, pack-copy path) could not
-    express; those rows stay for the trajectory."""
+    after the local phase."""
     from repro.common.config import FLConfig
     from repro.common.flatpack import packer_for
     from repro.core import ota
@@ -430,6 +319,6 @@ def sweep_rows(n_scenarios: int = 8, steps: int = 3, n_clusters: int = 10,
 
 
 if __name__ == "__main__":
-    for name, us, note in (run() + packed_rows() + client_folded_rows()
+    for name, us, note in (run() + client_folded_rows()
                            + layout_tune_rows() + sweep_rows()):
         print(f"{name},{us:.0f},{note}")

@@ -223,12 +223,9 @@ def main() -> None:
             pass
 
     # --- kernel microbenchmarks ------------------------------------------
-    from benchmarks.kernel_bench import client_folded_rows, packed_rows, \
+    from benchmarks.kernel_bench import client_folded_rows, \
         run as kbench, sweep_rows
     kernel_rows = kbench()
-
-    # --- flat-packed OTA engine vs per-leaf jnp path ----------------------
-    kernel_rows += packed_rows(quick=args.smoke)
 
     # --- client-folded zero-copy sim channel vs einsum+per-leaf ----------
     kernel_rows += client_folded_rows(quick=args.smoke)

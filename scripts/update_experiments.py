@@ -70,7 +70,7 @@ def roofline_section() -> str:
     LEVERS = {
         ("compute",): "raise MXU utilization (bigger per-chip batch, fused attn)",
         ("memory",): "cut HBM traffic: flash-attn keeps S² scores in VMEM; "
-                     "fuse channel-mask (ota_channel kernel)",
+                     "fuse channel-mask (ota_mask_weight kernel)",
         ("collective",): "shard-level OTA (defer), 2D-sharded gathers, "
                          "overlap gather with compute",
     }

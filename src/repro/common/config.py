@@ -120,10 +120,11 @@ class FLConfig:
     weighting: str = "fedgradnorm"    # fedgradnorm | equal (paper baseline)
     ota: bool = True                  # over-the-air aggregation on/off
     p_min: float = 0.0                # clamp for loss weights before renorm
-    # Flat-packed OTA: ravel the shared tree into one lane-aligned slab and
-    # run eqs. 7-10 in a single fused Pallas kernel (repro.common.flatpack +
-    # repro.kernels.ota_channel.ota_aggregate). False keeps the per-leaf jnp
-    # path — the property-test oracle (different PRNG stream, same math).
+    # Slab-native OTA: lay the shared tree out as one lane-aligned slab
+    # (repro.common.flatpack) and run eqs. 3 + 7-10 leaf by leaf in the
+    # client-fold kernels (repro.kernels.ota_channel) against its section
+    # streams. False keeps the per-leaf jnp path — the property-test
+    # oracle (different PRNG stream, same math).
     use_pallas_ota: bool = True
     # gradient-transmission implementation (same math — DESIGN.md §3.1):
     #  * "naive":   paper-literal — per-layer full-size weighted psum over

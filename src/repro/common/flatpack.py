@@ -7,10 +7,10 @@ the math cares about the pytree structure. The per-leaf implementation in
 memory-bound kernels (one gain/mask/noise draw per leaf per cluster) per
 round, multiplied by the scenario count under ``ScenarioBank``.
 
-``TreePacker`` ravels the shared-model pytree ONCE into a lane-aligned
+``TreePacker`` lays the shared-model pytree ONCE out as a lane-aligned
 ``(P,)`` slab with static per-leaf offsets, so the whole round's channel
-can be drawn, thresholded and aggregated in a single fused Pallas pass
-(``repro.kernels.ota_channel.ota_aggregate``).
+is one set of section streams that the client-fold kernels
+(``repro.kernels.ota_channel``) read leaf by leaf at static positions.
 
 Layout contract (relied on by ``repro.core.ota.final_layer_masks_packed``):
 
@@ -474,9 +474,9 @@ def check_tree_matches_packer(packer: TreePacker, tree, what: str,
     """Raise a readable error when ``tree`` does not match the packer
     template: names the first offending leaf path and the section it was
     expected in, instead of letting a zip mispair leaves and die in an
-    opaque downstream shape error (used by the packed gathers in
-    repro.core.hota / repro.core.hota_slab and the client-folded sim
-    path in repro.core.ota).
+    opaque downstream shape error (used by the packed gather in
+    repro.core.hota_slab and the client-folded sim path in
+    repro.core.ota).
 
     ``batch_ndim`` allows every leaf to carry that many IDENTICAL
     leading batch axes on top of its template shape — the zero-copy

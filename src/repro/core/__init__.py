@@ -20,15 +20,14 @@ from repro.core.fedgradnorm import (
 )
 from repro.core.ota import (
     final_layer_masks_packed, gain_mask, ota_aggregate_leaf,
-    ota_aggregate_packed, ota_aggregate_tree, packed_gain_bits,
-    power_allocation, sample_gain, transmit_signal, tree_channel,
+    ota_aggregate_tree, packed_gain_bits, power_allocation, sample_gain,
+    transmit_signal, tree_channel,
 )
 from repro.core.sim import HotaSim, SimState, masked_cls_loss
 from repro.core.sweep import DistScenarioBank, ScenarioBank, \
     ShardedScenarioBank
 from repro.core.hota import (
-    OTACtx, build_axes_registry, make_ota_gather, make_packed_final_gather,
-    make_param_hook, packed_final_norm,
+    OTACtx, build_axes_registry, make_ota_gather, make_param_hook,
 )
 from repro.core.hota_slab import (
     make_packed_omega_gather, packed_omega_key, sectioned_final_norm,
@@ -44,11 +43,10 @@ __all__ = [
     "stack_channel_params", "ScenarioBank", "ShardedScenarioBank",
     "FGNState", "fgn_init", "fgn_update", "fgn_update_gated", "fgn_grad_p",
     "fgn_targets", "fgrad_value", "masked_tree_norm", "gain_mask",
-    "final_layer_masks_packed", "ota_aggregate_leaf", "ota_aggregate_packed",
-    "ota_aggregate_tree", "packed_gain_bits", "power_allocation",
-    "sample_gain", "transmit_signal", "tree_channel", "HotaSim", "SimState",
-    "masked_cls_loss", "OTACtx", "build_axes_registry", "make_ota_gather",
-    "make_packed_final_gather", "make_param_hook", "packed_final_norm",
+    "final_layer_masks_packed", "ota_aggregate_leaf", "ota_aggregate_tree",
+    "packed_gain_bits", "power_allocation", "sample_gain", "transmit_signal",
+    "tree_channel", "HotaSim", "SimState", "masked_cls_loss", "OTACtx",
+    "build_axes_registry", "make_ota_gather", "make_param_hook",
     "make_packed_omega_gather", "packed_omega_key", "sectioned_final_norm",
     "HotaState", "StepParts", "make_hota_step_parts", "make_hota_train_step",
     "DistScenarioBank",

@@ -29,21 +29,16 @@ hook the models behave as plain (non-FL) networks.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common.config import FLConfig, ModelConfig, TrainConfig
 from repro.core.ota import HOTA_MASK_SALT
-from repro.common.flatpack import check_tree_matches_packer, packer_for
 from repro.core.channel import ChannelParams
-from repro.kernels.ota_channel.ops import _ota_channel_impl
-from repro.kernels.slab import flat_to_slab, on_tpu
-from repro.models.model import Model, lm_loss
+from repro.models.model import Model
 from repro.models.params import logical_axes
-from repro.optim.adam import adam_init, adam_update
 
 CLIENT_AXIS = "client"
 
@@ -235,145 +230,11 @@ def make_ota_gather(data_axes: Tuple[str, ...],
     return ota_gather
 
 
-# --------------------------------------------------------------------------
-# flat-packed final-subtree gather (ω̃ as ONE slab through the OTA MAC)
-# --------------------------------------------------------------------------
-# The last shared layer is where FedGradNorm reads its masked norms (eq. 5)
-# and where the per-leaf machinery costs the most bookkeeping: every leaf
-# used to pay its own mask draw + 3 collectives in the backward. Packing
-# ω̃'s full-size gradients into one lane-aligned slab runs the whole
-# subtree through ONE fused Pallas mask+apply kernel and ONE set of psums,
-# and gives the FGN phase bit-identical masks from the same flat draw.
-
-PACKED_FINAL_FOLD = 0x7FFF00F1   # reserved fold — disjoint from leaf indices
-
-
-def packed_final_key(base_key: jax.Array) -> jax.Array:
-    """The single channel key of the packed ω̃ slab (replaces per-leaf
-    fold_tags(base_key, "final", (), i))."""
-    return jax.random.fold_in(
-        jax.random.fold_in(base_key, KLASS_SALT["final"]), PACKED_FINAL_FOLD)
-
-
-def _packed_mask_apply(x_slab: jax.Array, key: jax.Array, sigma2, h_th,
-                       ota_on, cluster_axes):
-    """This cluster's fused bits→gaussian→threshold→apply on a (P,) slab.
-
-    Returns (masked_x, mask) as (P,) f32 — the Pallas ota_channel kernel
-    on the packed layout. Both the gather backward and the FGN norm call
-    this with the same key, so eq. 5 sees exactly the transmission masks.
-    """
-    ckey = jax.random.fold_in(key, cluster_index(cluster_axes))
-    bits = jax.random.bits(ckey, x_slab.shape, jnp.uint32)
-    out, mask = _ota_channel_impl(
-        flat_to_slab(x_slab), flat_to_slab(bits), sigma2, h_th, ota_on,
-        interpret=not on_tpu())
-    p = x_slab.shape[-1]
-    return out.reshape(p), mask.reshape(p)
-
-
-def make_packed_final_gather(data_axes: Tuple[str, ...],
-                             cluster_axes: Tuple[str, ...],
-                             n_clients: int, n_shards: int, compute_dtype,
-                             axes_list: List[tuple], template=None):
-    """Custom-vjp gather for the WHOLE final subtree.
-
-    forward : per-leaf all-gather of the FSDP shards (as before)
-    backward: pack full-size cotangents -> (P,) slab; weighted psum over
-              "client" (LAN, eq. 3); fused Pallas mask+apply; masked psum
-              over clusters (MAC, eq. 8) + AWGN; guarded |M|·N estimate
-              (eq. 10); unpack; slice each leaf's own FSDP shard.
-
-    3 collectives + 1 kernel for the subtree instead of 3·L psums and L
-    mask draws. Masks are whole-tensor draws (the scatter-mode per-region
-    scheme does not apply to the packed slab); ω̃ is small, so the full-
-    size psums cost less than the per-leaf dispatch they replace.
-
-    ``template`` (optional, full-size ω̃ shapes — e.g.
-    ``abstract_params(model.final_specs())``) turns a mismatched
-    gradient pytree into a readable error naming the leaf path and its
-    expected section, instead of an opaque downstream shape error.
-    """
-    tpl_packer = (packer_for(jax.tree.map(
-        lambda l: jax.ShapeDtypeStruct(tuple(l.shape), jnp.float32),
-        template), tail=None) if template is not None else None)
-
-    def _check(tree, what):
-        if tpl_packer is not None:
-            check_tree_matches_packer(tpl_packer, tree, what)
-        elif len(jax.tree.leaves(tree)) != len(axes_list):
-            raise ValueError(
-                f"{what}: got {len(jax.tree.leaves(tree))} leaves but this "
-                f"gather was built over {len(axes_list)} ω̃ leaves (the "
-                f"tail section 'final') — the pytree must mirror "
-                f"model.final_specs() exactly.")
-
-    @jax.custom_vjp
-    def gather_final(shard_tree, ctx: OTACtx):
-        if tpl_packer is not None:   # structure only — shards are smaller
-            check_tree_matches_packer(tpl_packer, shard_tree,
-                                      "parameter pytree (packed final "
-                                      "gather)", check_shapes=False)
-        leaves, treedef = jax.tree.flatten(shard_tree)
-        out = []
-        for leaf, axes in zip(leaves, axes_list):
-            ax = _fsdp_axis(axes)
-            if ax >= 0:
-                leaf = jax.lax.all_gather(leaf, data_axes, axis=ax,
-                                          tiled=True)
-            out.append(leaf.astype(compute_dtype))
-        return jax.tree.unflatten(treedef, out)
-
-    def _fwd(shard_tree, ctx):
-        return gather_final(shard_tree, ctx), (ctx,)
-
-    def _bwd(res, g_tree):
-        (ctx,) = res
-        _check(g_tree, "gradient pytree (packed final gather)")
-        g_tree = jax.tree.map(lambda g: g.astype(jnp.float32), g_tree)
-        packer = packer_for(g_tree, tail=None)
-        g_slab = packer.pack(g_tree)                       # (P,) full-size
-        x = jax.lax.psum(ctx.p_weight * g_slab, CLIENT_AXIS)
-        xm, mask = _packed_mask_apply(x, ctx.key, ctx.sigma2, ctx.h_th,
-                                      ctx.ota_on, cluster_axes)
-        y = jax.lax.psum(xm, cluster_axes)
-        cnt = jax.lax.psum(mask, cluster_axes)
-        z = (jax.random.normal(jax.random.fold_in(ctx.key, HOTA_MASK_SALT),
-                               g_slab.shape, jnp.float32)
-             * ctx.noise_std * ctx.ota_on)
-        ghat = jnp.where(cnt > 0,
-                         (y + z) / (jnp.maximum(cnt, 1.0) * n_clients), 0.0)
-        gh_tree = packer.unpack(ghat)
-        me = jax.lax.axis_index(data_axes[0])
-        for a in data_axes[1:]:
-            me = me * jax.lax.axis_size(a) + jax.lax.axis_index(a)
-        leaves = jax.tree.leaves(gh_tree)
-        out = []
-        for leaf, axes in zip(leaves, axes_list):
-            ax = _fsdp_axis(axes)
-            if ax >= 0:
-                sz = leaf.shape[ax] // n_shards
-                leaf = jax.lax.dynamic_slice_in_dim(leaf, me * sz, sz, ax)
-            out.append(leaf)
-        grads = jax.tree.unflatten(jax.tree.structure(gh_tree), out)
-        return (grads, jax.tree.map(_zero_cot, ctx))
-
-    gather_final.defvjp(_fwd, _bwd)
-    return gather_final
-
-
-def packed_final_norm(g_final, base_key: jax.Array, chan_c: ChannelParams,
-                      cluster_axes) -> jax.Array:
-    """n_i = ‖M ∘ ∇_{ω̃}F_i‖ (eq. 6) on the packed slab — the SAME flat
-    mask draw the packed gather backward applies (one fused kernel, no
-    per-leaf loop)."""
-    g32 = jax.tree.map(lambda g: g.astype(jnp.float32), g_final)
-    packer = packer_for(g32, tail=None)
-    g_slab = packer.pack(g32)
-    masked, _ = _packed_mask_apply(
-        g_slab, packed_final_key(base_key), chan_c.sigma2, chan_c.h_threshold,
-        chan_c.ota_on, cluster_axes)
-    return jnp.sqrt(jnp.sum(jnp.square(masked)))
+# The distributed packed-ω̃ gather's key fold. That gather is retired (the
+# slab-native engine, repro.core.hota_slab, draws ω̃ as its tail section);
+# the value stays reserved in the DESIGN.md §4 registry so no new stream
+# takes it.
+PACKED_FINAL_FOLD = 0x7FFF00F1
 
 
 # --------------------------------------------------------------------------
@@ -416,17 +277,12 @@ def build_axes_registry(model: Model) -> Dict[str, List[tuple]]:
 
 
 def make_param_hook(gather, registry: Dict[str, List[tuple]],
-                    base_key: jax.Array, p_weight, chan: ChannelParams,
-                    final_packed_gather=None):
+                    base_key: jax.Array, p_weight, chan: ChannelParams):
     """hook(subtree, klass, *tags) -> gathered/OTA-wrapped subtree.
 
     ``chan`` is this cluster's traced channel view (scalar σ² — see
     ``repro.core.channel.cluster_channel``); its knobs become the OTACtx
-    consts, so sweeping scenarios never re-traces the gather.
-
-    When ``final_packed_gather`` is set (see make_packed_final_gather),
-    the "final" klass routes the WHOLE ω̃ subtree through one packed
-    gather under one channel key instead of per-leaf calls."""
+    consts, so sweeping scenarios never re-traces the gather."""
     consts = dict(
         p_weight=jnp.asarray(p_weight, jnp.float32),
         sigma2=jnp.asarray(chan.sigma2, jnp.float32),
@@ -436,9 +292,6 @@ def make_param_hook(gather, registry: Dict[str, List[tuple]],
     )
 
     def hook(lp, klass, *tags):
-        if klass == "final" and final_packed_gather is not None:
-            ctx = OTACtx(key=packed_final_key(base_key), **consts)
-            return final_packed_gather(lp, ctx)
         leaves, treedef = jax.tree.flatten(lp)
         axes = registry[klass]
         assert len(leaves) == len(axes), (klass, len(leaves), len(axes))

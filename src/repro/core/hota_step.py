@@ -248,11 +248,8 @@ def make_hota_step_parts(
             int(np.prod(l.shape)) // (n_shards if ax >= 0 else 1)
             for l, ax in zip(jax.tree.leaves(omega_template), omega_fsdp))
     else:
-        # the PR-2 combination (per-leaf trunk + packed-ω̃ gather) is
-        # retired: use_pallas_ota=True now means the whole-model slab
-        # engine, and False is the all-per-leaf oracle
-        # (make_packed_final_gather stays exported + tested as the
-        # subtree-scale reference of the packed formulation).
+        # use_pallas_ota=True means the whole-model slab engine, and
+        # False is the all-per-leaf oracle
         omega_gather = omega_pk = None
         omega_axes = omega_fsdp = slab_local_len = None
 

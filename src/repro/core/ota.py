@@ -15,22 +15,23 @@ faithful-but-redundant inversion is implemented in ``faithful=True`` mode
 (used by property tests to verify the cancellation); the fast path sums the
 masked weighted gradients directly, which is bit-for-bit the same math.
 
-Two implementations share the math:
+The implementations share the math:
 
 * the **per-leaf path** (this module's historical core) walks the pytree,
   drawing gains/masks/noise per leaf per cluster with ``jax.random`` —
   the readable oracle the property tests pin everything to;
-* the **flat-packed path** (``ota_aggregate_packed``) ravels the whole
-  tree into a lane-aligned slab (``repro.common.flatpack.TreePacker``)
-  and runs eqs. 7-10 for every parameter of every cluster in ONE fused
-  Pallas kernel (``repro.kernels.ota_channel.ota_aggregate``); the
-  last-shared-layer masks FedGradNorm needs (eq. 5) are the tail slice
-  of the same flat draw (``final_layer_masks_packed``);
 * the **client-folded zero-copy path** (``ota_aggregate_client_folded``,
   the simulator's hot path — DESIGN.md §3.12) folds eq. 3's Σ_i p_i g_i
   INTO the masked MAC sum and consumes each raw (C, N, ·) gradient leaf
-  in place against the multi-section stream layout — no weighted tree,
-  no (C, P) pack copy;
+  in place against the section streams of the packer's layout
+  (``repro.common.flatpack.TreePacker``) — no weighted tree, no (C, P)
+  pack copy. The tests hold it to the per-leaf estimator run on the
+  same words (``packed_gain_bits`` / ``packed_noise_bits``, the whole
+  slab's draw); the last-shared-layer masks FedGradNorm needs (eq. 5)
+  are the tail section's words of the same draw
+  (``final_layer_masks_packed``);
+* the **cluster-streaming path** (``ota_aggregate_streaming`` —
+  DESIGN.md §3.15) folds one cluster at a time into a running sum;
 * the **section-streaming path** (``ota_aggregate_sectioned`` —
   DESIGN.md §3.16) schedules the client-folded math one SECTION at a
   time (optionally with the §3.15 cluster scan inside each section), so
@@ -41,8 +42,8 @@ Per-leaf channel keys are derived with ``fold_in(cluster_key, leaf_index)``,
 which realizes the paper's "one i.i.d. gain per parameter entry" over an
 arbitrary pytree. Noise keys live in a disjoint fold-in domain
 (``NOISE_FOLD``, near 2³¹) so they can never collide with a cluster
-index; the packed path folds section salts (``PACKED_*_FOLD``) from the
-same reserved range.
+index; the section streams fold section salts (``PACKED_*_FOLD``) from
+the same reserved range.
 """
 from __future__ import annotations
 
@@ -56,8 +57,8 @@ from repro.common.flatpack import TreePacker, check_tree_matches_packer
 from repro.core.channel import ChannelParams
 from repro.kernels.ota_channel.kernel import CHUNK_ROWS, _client_cluster_block
 from repro.kernels.ota_channel.ops import (
-    _ota_aggregate_fused_impl, ota_client_fold_apply,
-    ota_client_fold_drawn_apply, ota_stream_fold_apply,
+    ota_client_fold_apply, ota_client_fold_drawn_apply,
+    ota_stream_fold_apply,
 )
 from repro.kernels.ota_channel.ref import bits_to_gaussian, bits_to_mask
 from repro.kernels.slab import LANE, ROW_QUANTUM, on_tpu
@@ -331,14 +332,14 @@ def ota_aggregate_tree(
 
 
 # --------------------------------------------------------------------------
-# flat-packed OTA aggregation (the fused hot path)
+# section streams (DESIGN.md §4)
 # --------------------------------------------------------------------------
 #
 # Key schedule: one gain-bit stream per (section, cluster) —
 #   bits_sec[c] = random.bits(fold_in(fold_in(key, PACKED_*_FOLD), c))
-# — and one AWGN stream per round (fold_in(key, NOISE_FOLD)). Sections are
-# the TreePacker's head (trunk) and tail (ω̃) slices, each lane-aligned,
-# so ``final_layer_masks_packed`` re-draws ONLY the tail stream and gets
+# — and one AWGN stream per section (fold_in(noise_key(key), fold)).
+# Sections are the TreePacker's slices, each lane-aligned, so
+# ``final_layer_masks_packed`` re-draws ONLY the tail (ω̃) stream and gets
 # bit-identical masks to the full aggregation's tail slice — no second
 # per-leaf loop, no full-model draw in the FGN phase.
 
@@ -348,8 +349,8 @@ CHUNK = CHUNK_ROWS * LANE    # the stream quantum (entries per chunk draw)
 def _chunked_stream(key: jax.Array, length: int) -> jax.Array:
     """(length,) uint32 of the chunk-quantized stream: chunk j is
     ``bits(fold_in(key, j), (CHUNK,))``; a partial last chunk is
-    truncated — exactly the draws the fused kernel generates in-kernel
-    (one chunk per grid step), independent of kernel blocking."""
+    truncated — exactly the words the drawing kernel computes from their
+    positions, independent of kernel blocking."""
     n_chunks = -(-length // CHUNK)
     with jax.named_scope("hota.ota_draw"):
         chunks = jax.vmap(
@@ -361,10 +362,9 @@ def _chunked_stream(key: jax.Array, length: int) -> jax.Array:
 
 def _section_bits(key: jax.Array, fold: int, n_clusters: int, length: int):
     """(C, length) uint32 gain bits for one packed section: cluster c's
-    stream is chunk-quantized under ``fold_in(section_key, c)`` — the
-    fused kernel's in-kernel draw at grid steps (·, c), and the section
-    fold keeps head/tail streams disjoint so the FGN phase re-draws just
-    the tail."""
+    stream is chunk-quantized under ``fold_in(section_key, c)``, and the
+    section fold keeps head/tail streams disjoint so the FGN phase
+    re-draws just the tail."""
     skey = jax.random.fold_in(key, fold)
     return jax.vmap(
         lambda c: _chunked_stream(cluster_key(skey, c), length)
@@ -524,58 +524,10 @@ def packed_gain_bits(key: jax.Array, packer: TreePacker, n_clusters: int):
 
 
 def packed_noise_bits(key: jax.Array, packer: TreePacker) -> jax.Array:
-    """The round's (P,) AWGN bit stream (per-section, chunk-quantized —
-    the fused kernel's in-kernel draw at each section's final steps)."""
+    """The round's (P,) AWGN bit stream: the per-section chunk-quantized
+    noise streams of ``section_noise_streams`` in layout order."""
     parts = section_noise_streams(key, packer)
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-
-
-def ota_aggregate_packed(
-    key: jax.Array,
-    weighted_grads,              # pytree with leading (C, ...) leaves
-    chan: ChannelParams,         # traced knobs; chan.sigma2 is (C,)
-    n_clients: int,
-    packer: TreePacker,
-    bits_mode: str = "fused",    # "fused" | "supplied" (see below)
-):
-    """Fused-path OTA aggregation: pack -> one Pallas kernel -> unpack.
-
-    Same math as ``ota_aggregate_tree`` (eqs. 8-10, traced ``ota_on``
-    gate included), but the per-cluster gains, masks and the noise tree
-    never materialize in HBM — property-tested against the per-leaf
-    oracle on a shared bit stream (tests/test_ota_packed.py).
-
-    ``bits_mode="fused"`` generates the bit streams in-kernel (no (C, P)
-    bits slab — the single-scenario fast path); ``"supplied"`` pre-draws
-    the IDENTICAL streams outside and feeds them to the kernel, which
-    only depends on ``key`` — under ``ScenarioBank``'s vmap the draw
-    hoists out of the scenario axis, paying the RNG once per round
-    instead of once per scenario. Both modes return the same values.
-    """
-    leaves = jax.tree.leaves(weighted_grads)
-    n_clusters = leaves[0].shape[0]
-    wg = packer.pack(weighted_grads)                       # (C, P)
-    if bits_mode == "supplied":
-        bits = packed_gain_bits(key, packer, n_clusters)
-        nbits = packed_noise_bits(key, packer)
-    elif bits_mode == "fused":
-        bits = nbits = None
-    else:
-        raise ValueError(bits_mode)
-    # per-section stream schedule from the packer's own layout (DESIGN.md
-    # §4): NOT a hard-coded head/tail pair — a "toplevel" packer's trunk
-    # sections fold PACKED_SECTION_FOLD_BASE + s here exactly as the
-    # slab-native distributed engine (repro.core.hota_slab) draws them
-    folds = packed_section_folds(packer)
-    nk = noise_key(key)
-    section_keys = jnp.stack([
-        jnp.stack([jax.random.fold_in(key, f), jax.random.fold_in(nk, f)])
-        for f in folds]).astype(jnp.uint32)                # (S, 2, 2)
-    ghat = _ota_aggregate_fused_impl(
-        wg, section_keys, tuple(sec.length for sec in packer.sections),
-        chan.sigma2, chan.h_threshold, chan.noise_std, chan.ota_on,
-        n_clients, interpret=not on_tpu(), bits=bits, nbits=nbits)
-    return packer.unpack(ghat)
 
 
 def ota_aggregate_client_folded(
@@ -594,13 +546,14 @@ def ota_aggregate_client_folded(
     client-weight einsum INTO the channel and consume every gradient
     leaf's storage in place.
 
-    Same math as ``einsum("cn,cn...->c...", p, g)`` followed by
-    ``ota_aggregate_packed`` on a matching layout — eqs. 3 + 8-10 with
-    the traced ``ota_on`` gate — but computed leaf by leaf against the
-    static zero-copy maps (``TreePacker.leaf_runs``): neither the
-    client-weighted tree nor the (C, P) packed slab is ever
-    materialized. Streams are the per-section chunk-quantized streams of
-    ``packed_section_folds`` — identical words to the packed kernel and
+    Same math as ``einsum("cn,cn...->c...", p, g)`` followed by the
+    reference estimator (``ota_aggregate_slab_ref``, or the per-leaf
+    ``ota_aggregate_leaf``) on the words of ``packed_gain_bits`` /
+    ``packed_noise_bits`` — eqs. 3 + 8-10 with the traced ``ota_on``
+    gate — but computed leaf by leaf against the static zero-copy maps
+    (``TreePacker.leaf_runs``): neither the client-weighted tree nor the
+    (C, P) packed slab is ever materialized. Streams are the per-section
+    chunk-quantized streams of ``packed_section_folds`` — identical words
     to the slab-native distributed engine on the same layout.
 
     ``bits_mode`` picks where the words are computed; both modes return
@@ -926,7 +879,7 @@ def final_layer_masks_packed(key: jax.Array, chan: ChannelParams,
                              packer: TreePacker):
     """Masks M^(l) on the last-shared-layer params ω̃ (eq. 5-7), drawn
     from the tail section's stream — bit-identical to the masks
-    ``ota_aggregate_packed`` applies to the same entries.
+    ``ota_aggregate_client_folded`` applies to the same entries.
 
     Draws the stream per leaf at the SAME ``leaf_runs`` positions the
     zero-copy engines walk (the tail section is never coalesced, so its

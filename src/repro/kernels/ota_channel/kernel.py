@@ -1,33 +1,26 @@
 """Pallas TPU kernels for the OTA fading-MAC channel (paper Sec. III).
 
-* ``ota_channel_pallas`` — per-cluster mask + apply for ONE slab via the
-  Box-Muller core (bits -> N(0, σ²) gains, eq. 7's threshold — H is
-  never materialized in HBM). Used by the distributed path (the MAC psum
-  runs across the mesh, so masking is the only local per-entry work).
+* ``ota_mask_weight_pallas`` / ``ota_mask_count_pallas`` — the
+  slab-native distributed engine's local channel work (DESIGN.md §3.10):
+  one device's masked, weighted gradient M ∘ (w·x), and with it the
+  Σ_l M_l pass count over every cluster's stream (the MAC psum runs
+  across the mesh, so masking is the only local per-entry work).
 
-* ``ota_aggregate_pallas`` / ``ota_aggregate_fused_pallas`` — the full
-  PS estimator (eqs. 8-10) for the simulator hot path: input a
-  (C, rows, 128) weighted-grad slab (already Σ_i p_i g_i per cluster)
-  and the traced channel knobs; an in-kernel loop over the cluster axis
-  fuses mask draw→Σ_l mask·wg accumulation→AWGN→guarded |M|·N estimate.
-
-* ``ota_aggregate_client_pallas`` — the client-folded variant (DESIGN.md
-  §3.12): input the RAW (C, N, rows, 128) per-client gradient slab and
-  the (C, N) loss-weight matrix (riding the params block); the MAC loop
-  computes Σ_l mask_l · (Σ_n p[l,n]·g[l,n]) in block — eqs. 3 + 8-10 in
-  one pass, so the caller never materializes the client-weighted tree.
+* ``ota_aggregate_client_pallas`` — the client-folded PS estimator
+  (DESIGN.md §3.12) on caller-supplied channel words: input the RAW
+  (C, N, rows, 128) per-client gradient slab and the (C, N) loss-weight
+  matrix (riding the params block); the MAC loop computes
+  Σ_l mask_l · (Σ_n p[l,n]·g[l,n]) in block — eqs. 3 + 8-10 in one
+  pass, so the caller never materializes the client-weighted tree.
   Masks are drawn by inverse-CDF thresholding (``u < erfc(√(H_th/2σ²))``
   — exactly the law of 1{|H|² ≥ H_th}; the estimator never consumes H
   because channel inversion cancels it on passing entries), so the
   per-entry cost is one compare, not a transcendental chain. The pass
   probability is a per-cluster scalar computed by the wrappers (Mosaic
   has no erfc lowering) and the uniform is the word's top 24 bits, so
-  every cast the kernel makes is exact. Per-cluster
-  masks and the noise tree never touch HBM — one output slab per round
-  instead of ~4·C·L small leaf kernels. The ``_fused`` variant generates
-  its bits in-kernel from per-section threefry keys on a chunk-quantized
-  stream (no (C, P) bits slab in HBM, and blocking can never shift the
-  draw); the bits-supplied variant is the oracle bridge for tests.
+  every cast the kernel makes is exact. Per-cluster masks and the noise
+  tree never touch HBM. At large C the cluster axis is blocked
+  (``ota_client_fold_cblk``).
 
 * ``ota_client_fold_drawn_pallas`` — the client-folded estimator that
   computes its own channel words: each element's gain and noise words
@@ -41,20 +34,20 @@
   as its flat (rows, 128) view (``ota_client_fold_drawn``).
 
 Channel knobs (the per-cluster pass probabilities, noise std, the ota_on
-gate) arrive as one traced (1, C+2) params block, so scenario sweeps
+gate) arrive as one traced params block, so scenario sweeps
 (``ScenarioBank``) vmap over them without re-tracing; ``ota_on < 0.5``
 forces every mask all-pass and zeroes the AWGN (the error-free baseline)
 inside the same kernel.
 
-Tiling: slabs are (rows, 128) — lane-aligned for the VPU — processed in
-(CHUNK_ROWS, 128) chunks (sublane-aligned for f32 packing) with the
+Tiling: slabs are (rows, 128) — lane-aligned for the VPU — with the
 cluster loop unrolled in-kernel (C is static). All compute is
-elementwise VPU work. The chunk-quantized key schedule — which streams
-exist, what CHUNK_ROWS pins, and why blocking can never shift a draw —
-is specified normatively in DESIGN.md §4 (the RNG stream spec).
+elementwise VPU work. Every channel word is a threefry word of the
+chunk-quantized stream, bit-reproducible from the round key: which
+streams exist, what CHUNK_ROWS pins, and why blocking can never shift a
+draw is specified normatively in DESIGN.md §4 (the RNG stream spec).
 
-Validated in interpret mode against ref.ota_channel_ref /
-ref.ota_aggregate_slab_ref on the same bits stream.
+Validated in interpret mode against ``ref.bits_to_mask`` /
+``ref.ota_aggregate_client_ref`` on the same words.
 """
 from __future__ import annotations
 
@@ -70,9 +63,11 @@ from repro.kernels.slab import LANE, SUBLANE
 TWO_PI = 6.283185307179586
 DEFAULT_BLOCK_ROWS = 256
 VMEM_BUDGET_BYTES = 6 * 1024 * 1024
-# per-grid-step wg budget of the in-kernel-RNG TPU path; C beyond
-# 8MB / (CHUNK_ROWS·128·4) = 16 clusters loops the cluster axis in blocks
-TPU_WG_BLOCK_BUDGET = 8 * 1024 * 1024
+# The stream quantum: channel words are drawn in (CHUNK_ROWS, 128) chunks
+# keyed by fold_in(stream_key, chunk), so the stream NEVER depends on how
+# a kernel is blocked. Changing CHUNK_ROWS changes the draw — it is part
+# of the stream spec (DESIGN.md §4).
+CHUNK_ROWS = 1024
 
 
 def _u32_to_f32(v):
@@ -124,19 +119,8 @@ def _pick_block_rows(rows: int, n_slabs: int,
 
 
 # ---------------------------------------------------------------------------
-# per-cluster mask + apply (distributed path)
+# mask + weighted apply, mask count (distributed path)
 # ---------------------------------------------------------------------------
-
-def _ota_channel_kernel(x_ref, bits_ref, params_ref, out_ref, mask_ref):
-    sigma2 = params_ref[0, 0]
-    h_th = params_ref[0, 1]
-    ota_on = params_ref[0, 2]
-    h = _box_muller(bits_ref[...], sigma2)
-    mask = jnp.logical_or((h * h) >= h_th, ota_on < 0.5)
-    x = x_ref[...]
-    out_ref[...] = jnp.where(mask, x, jnp.zeros_like(x))
-    mask_ref[...] = mask.astype(mask_ref.dtype)
-
 
 def _ota_mask_weight_kernel(x_ref, bits_ref, params_ref, out_ref, mask_ref):
     """Weighted-einsum fold (DESIGN.md §3.10): out = M ∘ (w·x) in ONE pass.
@@ -144,9 +128,9 @@ def _ota_mask_weight_kernel(x_ref, bits_ref, params_ref, out_ref, mask_ref):
     This is the slab-native distributed trunk's local kernel — the
     FedGradNorm weight w multiplies inside the masked apply, so the
     LAN/MAC psum consumes the kernel output directly (no separate p·g
-    materialization). Masks use the same inverse-CDF law as the fused
-    aggregate kernel (one compare per entry, matches ref.bits_to_mask on
-    the identical bit stream)."""
+    materialization). Masks use the same inverse-CDF law as the
+    client-fold kernels (one compare per entry, matches ref.bits_to_mask
+    on the identical bit stream)."""
     p_pass = params_ref[0, 0]
     ota_on = params_ref[0, 1]
     w = params_ref[0, 2]
@@ -614,368 +598,3 @@ def ota_client_fold_drawn_pallas(
             jnp.uint32)],
         interpret=interpret,
     )(x, keys.astype(jnp.uint32), params.astype(jnp.float32))
-
-
-def ota_channel_pallas(
-    x: jax.Array,            # (rows, 128) slab
-    bits: jax.Array,         # (rows, 128) uint32
-    params: jax.Array,       # (1, 3) f32: [sigma2, h_th, ota_on] (traced)
-    *,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = False,
-):
-    rows, lane = x.shape
-    assert lane == LANE, x.shape
-    br = _pick_block_rows(rows, 4, block_rows, interpret)
-    grid = (rows // br,)
-
-    out, mask = pl.pallas_call(
-        _ota_channel_kernel,
-        grid=grid,
-        name="ota_channel",
-        in_specs=[
-            pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((1, 3), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, LANE), x.dtype),
-            jax.ShapeDtypeStruct((rows, LANE), x.dtype),
-        ],
-        interpret=interpret,
-    )(x, bits, params.astype(jnp.float32))
-    return out, mask
-
-
-# ---------------------------------------------------------------------------
-# full OTA aggregation (simulator hot path, eqs. 8-10)
-# ---------------------------------------------------------------------------
-
-def _ota_aggregate_kernel(wg_ref, bits_ref, nbits_ref, params_ref, out_ref,
-                          *, n_clusters, n_clients):
-    c = n_clusters
-    noise_std = params_ref[0, c]
-    ota_on = params_ref[0, c + 1]
-    off = ota_on < 0.5                       # traced error-free gate
-
-    acc = jnp.zeros_like(out_ref[...], jnp.float32)
-    cnt = jnp.zeros_like(acc)
-    for l in range(n_clusters):              # static unrolled cluster loop
-        mask = _bits_mask(bits_ref[l], params_ref[0, l], off)
-        acc = acc + jnp.where(mask, wg_ref[l].astype(jnp.float32), 0.0)
-        cnt = cnt + mask.astype(jnp.float32)
-
-    z = _box_muller(nbits_ref[...], 1.0) * noise_std * ota_on
-    y = acc + z
-    # |M_k(j)| = 0 -> nothing received but noise; estimator guarded to 0
-    out_ref[...] = jnp.where(cnt > 0,
-                             y / (jnp.maximum(cnt, 1.0) * n_clients), 0.0)
-
-
-# The stream quantum of the in-kernel RNG: bits are always drawn in
-# (CHUNK_ROWS, 128) pieces keyed by fold_in(fold_in(section_key, cluster),
-# chunk) — so the stream NEVER depends on how the loop is blocked, and a
-# chunk (512 KB of f32) is also the VMEM/cache-sized work unit per step.
-# Changing CHUNK_ROWS changes the draw — it is part of the stream spec
-# (DESIGN.md §4).
-CHUNK_ROWS = 1024
-# chunk loops up to this long are unrolled (faster in interpret mode);
-# longer slabs use fori_loop so compile time stays independent of P
-UNROLL_CHUNKS = 16
-
-
-def _interp_chunk_bits(key2, cluster, chunk):
-    """One (CHUNK_ROWS, 128) uint32 draw of the chunk-quantized threefry
-    stream (chunk j of ``fold_in(section_key, cluster)``'s stream).
-    ``cluster`` is None for the per-entry AWGN stream (no cluster axis).
-    """
-    k = key2
-    if cluster is not None:
-        k = jax.random.fold_in(k, cluster)
-    k = jax.random.fold_in(k, chunk)
-    return jax.random.bits(k, (CHUNK_ROWS, LANE), jnp.uint32)
-
-
-def _fused_body(wg, bits_fn, nbits_fn, params_ref, n_clusters, n_clients,
-                r0, br):
-    """Accumulate one row-chunk [r0, r0+br) over the cluster axis and
-    finish it with AWGN + the guarded |M|·N estimate (eqs. 8-10)."""
-    c = n_clusters
-    noise_std = params_ref[0, c]
-    ota_on = params_ref[0, c + 1]
-    off = ota_on < 0.5
-
-    acc = jnp.zeros((br, LANE), jnp.float32)
-    cnt = jnp.zeros_like(acc)
-    for l in range(n_clusters):              # static unrolled cluster loop
-        bits = bits_fn(l)[:br]
-        mask = _bits_mask(bits, params_ref[0, l], off)
-        acc = acc + jnp.where(mask, wg(l, r0, br).astype(jnp.float32), 0.0)
-        cnt = cnt + mask.astype(jnp.float32)
-    z = _box_muller(nbits_fn()[:br], 1.0) * noise_std * ota_on
-    y = acc + z
-    return jnp.where(cnt > 0, y / (jnp.maximum(cnt, 1.0) * n_clients), 0.0)
-
-
-def _chunk_sweep(out_ref, chunk):
-    """Drive ``chunk(j, rows_ds, br)`` over the slab's row-chunks and
-    write its results: unrolled for small slabs (faster in interpret
-    mode), a PURE lax.map for big ones (compile size independent of P;
-    the ref is written once after — a ref store inside the loop would
-    batch as a full-slab update per chunk under ScenarioBank's vmap)."""
-    rows = out_ref.shape[0]
-    n_full = rows // CHUNK_ROWS
-    if 0 < n_full <= UNROLL_CHUNKS:
-        for j in range(n_full):
-            r0 = j * CHUNK_ROWS
-            out_ref[r0:r0 + CHUNK_ROWS, :] = chunk(
-                j, pl.ds(r0, CHUNK_ROWS), CHUNK_ROWS)
-    elif n_full:
-        ys = jax.lax.map(
-            lambda j: chunk(j, pl.ds(j * CHUNK_ROWS, CHUNK_ROWS),
-                            CHUNK_ROWS),
-            jnp.arange(n_full))
-        out_ref[:n_full * CHUNK_ROWS, :] = ys.reshape(-1, LANE)
-    rem = rows - n_full * CHUNK_ROWS
-    if rem:                                  # static partial last chunk
-        r0 = n_full * CHUNK_ROWS
-        out_ref[r0:, :] = chunk(n_full, pl.ds(r0, rem), rem)
-
-
-def _ota_aggregate_interp_kernel(wg_ref, keys_ref, params_ref, out_ref, *,
-                                 n_clusters, n_clients):
-    """Interpret-mode body, in-kernel RNG: every temp is one cache-sized
-    chunk and the chunk-quantized threefry stream matches the oracle's
-    draw (repro.core.ota._section_bits) bit for bit."""
-    def chunk(j, r0, br):
-        return _fused_body(
-            lambda l, r, b: wg_ref[l, r, :],
-            lambda l: _interp_chunk_bits(keys_ref[0], l, j),
-            lambda: _interp_chunk_bits(keys_ref[1], None, j),
-            params_ref, n_clusters, n_clients, r0, br)
-
-    _chunk_sweep(out_ref, chunk)
-
-
-def _ota_aggregate_supplied_kernel(wg_ref, bits_ref, nbits_ref, params_ref,
-                                   out_ref, *, n_clusters, n_clients):
-    """Interpret-mode body, caller-supplied bits: same chunk sweep, but
-    the gain/AWGN streams are read from (C, rows, 128)/(rows, 128) slabs.
-    Under ScenarioBank's vmap the bit draw does not depend on the banked
-    knobs, so it hoists out of the scenario axis — the RNG cost is paid
-    once per round, not once per scenario."""
-    def chunk(j, r0, br):
-        return _fused_body(
-            lambda l, r, b: wg_ref[l, r, :],
-            lambda l, r=r0: bits_ref[l, r, :],
-            lambda r=r0: nbits_ref[r, :],
-            params_ref, n_clusters, n_clients, r0, br)
-
-    _chunk_sweep(out_ref, chunk)
-
-
-def tpu_hw_seed(key2, l, i):
-    """The compiled TPU branch's hardware-PRNG seed for (cluster ``l``,
-    row-chunk ``i``) of the stream keyed by the (2,) uint32 threefry key
-    ``key2`` (``l=None`` = the AWGN stream). ONE home for the seed
-    arithmetic — the kernels below and the validation pass
-    (tests/test_sectioned.py) both call it, so the schedule the tests
-    check for (cluster, chunk) collisions and C-blocking invariance is
-    the schedule the hardware actually seeds. All arithmetic wraps mod
-    2³²; ``l``/``i`` may be traced."""
-    s = key2[0] ^ key2[1]
-    if l is not None:
-        s = s + jnp.asarray(l, jnp.uint32) * jnp.uint32(0x10001)
-    return s + jnp.asarray(i, jnp.uint32)
-
-
-def _hw_chunk_bits(key_row, l, i):
-    """One hardware-PRNG (CHUNK_ROWS, 128) uint32 chunk draw. The
-    int32->uint32 astype is a bit-preserving cast (mod 2³²):
-    ``prng_random_bits`` yields int32, and consuming it signed would
-    sign-extend in ``_bits_mask``'s uniform compare and ``_box_muller``'s
-    ``>> 16`` — the mask law would be biased (the bug the hardware-PRNG
-    validation pass exists to catch)."""
-    from jax.experimental.pallas import tpu as pltpu
-    pltpu.prng_seed(tpu_hw_seed(key_row, l, i))
-    return pltpu.prng_random_bits((CHUNK_ROWS, LANE)).astype(jnp.uint32)
-
-
-def _ota_aggregate_tpu_kernel(wg_ref, keys_ref, params_ref, out_ref,
-                              acc_ref, cnt_ref, *, cb, n_clusters,
-                              n_clients):
-    """Compiled TPU body: grid (row-chunks, cluster-blocks) with the
-    cluster axis minor, hardware PRNG (pltpu.prng_random_bits — an
-    i.i.d. stream distinct from the interpret/oracle threefry stream;
-    statistical tests only). Each step folds ``cb`` clusters' masked
-    contributions into VMEM scratch SEQUENTIALLY (the same float order —
-    and, via ``tpu_hw_seed`` on GLOBAL cluster indices, the same seeds —
-    as the old single-block kernel), so VMEM holds cb·CHUNK_ROWS wg rows
-    however large C grows; the last cluster block adds AWGN and writes
-    the guarded estimate."""
-    c = n_clusters
-    noise_std = params_ref[0, c]
-    ota_on = params_ref[0, c + 1]
-    off = ota_on < 0.5
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-    acc = acc_ref[...]
-    cnt = cnt_ref[...]
-    for l_loc in range(cb):                  # static unrolled local loop
-        l = j * cb + l_loc                   # traced GLOBAL cluster index
-        bits = _hw_chunk_bits(keys_ref[0], l, i)
-        valid = l < n_clusters               # padded tail cluster block
-        p_l = jnp.sum(jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
-            == jnp.minimum(l, c - 1),
-            params_ref[0, :c].reshape(c, 1), 0.0))
-        mask = jnp.logical_and(_bits_mask(bits, p_l, off), valid)
-        acc = acc + jnp.where(mask, wg_ref[l_loc].astype(jnp.float32), 0.0)
-        cnt = cnt + mask.astype(jnp.float32)
-    acc_ref[...] = acc
-    cnt_ref[...] = cnt
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _done():
-        nbits = _hw_chunk_bits(keys_ref[1], None, i)
-        z = _box_muller(nbits, 1.0) * noise_std * ota_on
-        y = acc_ref[...] + z
-        out_ref[...] = jnp.where(
-            cnt_ref[...] > 0,
-            y / (jnp.maximum(cnt_ref[...], 1.0) * n_clients), 0.0)
-
-
-def ota_aggregate_fused_pallas(
-    wg: jax.Array,           # (C, rows, 128) f32 — ONE section's slab
-    keys: jax.Array,         # (2, 2) uint32 threefry keys [gains, AWGN]
-    params: jax.Array,       # (1, C+2) f32: [p_pass_0..p_pass_{C-1}, z_std, ota_on]
-    *,
-    n_clients: int,
-    interpret: bool = False,
-    bits: jax.Array = None,     # optional (C, rows, 128) uint32 pre-drawn
-    nbits: jax.Array = None,    # optional (rows, 128) uint32 pre-drawn
-) -> jax.Array:
-    """OTA aggregation for one packed section (the sim hot path). The
-    bit stream is quantized to CHUNK_ROWS blocks keyed by (section,
-    cluster, chunk), so kernel blocking never shifts the draw; a partial
-    last chunk just truncates its stream (the oracle does the same).
-    Pass pre-drawn ``bits``/``nbits`` (the identical stream) to hoist
-    the RNG out of a scenario vmap."""
-    n_clusters, rows, lane = wg.shape
-    assert lane == LANE, wg.shape
-
-    if interpret and bits is not None:
-        kernel = functools.partial(_ota_aggregate_supplied_kernel,
-                                   n_clusters=n_clusters,
-                                   n_clients=n_clients)
-        return pl.pallas_call(
-            kernel,
-            grid=(1,),
-            name="ota_aggregate_fused",
-            in_specs=[
-                pl.BlockSpec((n_clusters, rows, LANE), lambda i: (0, 0, 0)),
-                pl.BlockSpec((n_clusters, rows, LANE), lambda i: (0, 0, 0)),
-                pl.BlockSpec((rows, LANE), lambda i: (0, 0)),
-                pl.BlockSpec((1, n_clusters + 2), lambda i: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((rows, LANE), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
-            interpret=True,
-        )(wg, bits, nbits, params.astype(jnp.float32))
-
-    if bits is not None:         # compiled: block-gridded supplied-bits
-        return ota_aggregate_pallas(wg, bits, nbits, params,
-                                    n_clients=n_clients, interpret=False)
-
-    if interpret:
-        kernel = functools.partial(_ota_aggregate_interp_kernel,
-                                   n_clusters=n_clusters,
-                                   n_clients=n_clients)
-        return pl.pallas_call(
-            kernel,
-            grid=(1,),
-            name="ota_aggregate_fused",
-            in_specs=[
-                pl.BlockSpec((n_clusters, rows, LANE), lambda i: (0, 0, 0)),
-                pl.BlockSpec((2, 2), lambda i: (0, 0)),
-                pl.BlockSpec((1, n_clusters + 2), lambda i: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((rows, LANE), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
-            interpret=True,
-        )(wg, keys, params.astype(jnp.float32))
-
-    # the wg block is (cb, CHUNK_ROWS, 128) f32 — CHUNK_ROWS is part of
-    # the stream spec and cannot shrink per call, so at large C the
-    # CLUSTER axis is blocked (scratch accumulation over a minor grid
-    # dim); seeds key on global cluster indices, so blocking never
-    # shifts the hardware draw (tpu_hw_seed — validated in
-    # tests/test_sectioned.py).
-    from jax.experimental.pallas import tpu as pltpu
-    cb_cap = max(1, TPU_WG_BLOCK_BUDGET // (CHUNK_ROWS * LANE * 4))
-    cb = min(n_clusters, cb_cap)
-    n_cb = pl.cdiv(n_clusters, cb)
-    kernel = functools.partial(_ota_aggregate_tpu_kernel, cb=cb,
-                               n_clusters=n_clusters, n_clients=n_clients)
-    return pl.pallas_call(
-        kernel,
-        grid=(pl.cdiv(rows, CHUNK_ROWS), n_cb),
-        name="ota_aggregate_fused",
-        in_specs=[
-            pl.BlockSpec((cb, CHUNK_ROWS, LANE),
-                         lambda i, j: (j, i, 0)),
-            pl.BlockSpec((2, 2), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, n_clusters + 2), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((CHUNK_ROWS, LANE), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((CHUNK_ROWS, LANE), jnp.float32),
-                        pltpu.VMEM((CHUNK_ROWS, LANE), jnp.float32)],
-        interpret=False,
-    )(wg, keys, params.astype(jnp.float32))
-
-
-def ota_aggregate_pallas(
-    wg: jax.Array,           # (C, rows, 128) f32 — Σ_i p_i g_i per cluster
-    bits: jax.Array,         # (C, rows, 128) uint32 — gain bits per cluster
-    nbits: jax.Array,        # (rows, 128) uint32 — AWGN bits
-    params: jax.Array,       # (1, C+2) f32: [p_pass_0..p_pass_{C-1}, z_std, ota_on]
-    *,
-    n_clients: int,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = False,
-) -> jax.Array:
-    n_clusters, rows, lane = wg.shape
-    assert lane == LANE, wg.shape
-    assert bits.shape == wg.shape, (bits.shape, wg.shape)
-    assert nbits.shape == (rows, LANE), nbits.shape
-    # 2C cluster blocks + noise + out resident at once
-    br = _pick_block_rows(rows, 2 * n_clusters + 2, block_rows, interpret)
-    grid = (rows // br,)
-
-    kernel = functools.partial(_ota_aggregate_kernel,
-                               n_clusters=n_clusters, n_clients=n_clients)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        name="ota_aggregate",
-        in_specs=[
-            pl.BlockSpec((n_clusters, br, LANE), lambda i: (0, i, 0)),
-            pl.BlockSpec((n_clusters, br, LANE), lambda i: (0, i, 0)),
-            pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_clusters + 2), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
-        interpret=interpret,
-    )(wg, bits, nbits, params.astype(jnp.float32))

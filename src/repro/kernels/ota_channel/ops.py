@@ -1,69 +1,36 @@
-"""Jit'd public wrappers for the ota_channel kernel package.
+"""Public wrappers for the ota_channel kernel package.
 
-``ota_channel(x, key, sigma2, h_th)`` accepts an arbitrary-shape slab,
-pads/reshapes it to the kernels' (rows, 128) layout (shared helper in
-``repro.kernels.slab``), draws the uniform bits with JAX's counter-based
-threefry (cheap, fused by XLA), and invokes the Pallas kernel (interpret
-mode on CPU — this container has no TPU).
+Each wrapper takes one gradient leaf in its own storage and the leaf's
+channel words (or, for the drawing kernel, its chunk-key table), runs the
+Pallas kernel on the ROW_QUANTUM main body and the jnp reference
+(``ref.py``) on the ragged remainder, and returns leaf-shaped results:
 
-``ota_aggregate(wg, bits, nbits, sigma2, ...)`` is the flat-packed whole-
-model aggregation (eqs. 8-10): the caller supplies the lane-aligned
-(C, P) weighted-grad slab and bit streams (see ``repro.core.ota``'s
-packed path, which owns the key schedule), and one fused kernel returns
-the (P,) PS estimate. All channel knobs are traced, so ``ScenarioBank``
-vmaps over them freely.
+* ``ota_client_fold_apply`` / ``ota_client_fold_drawn_apply`` — the
+  simulator's client-folded PS estimate (eqs. 3 + 8-10), on supplied
+  words or on words the kernel draws from their stream positions;
+* ``ota_mask_weight_apply`` / ``ota_mask_count_apply`` — the slab-native
+  distributed engine's masked weighted gradient and |M| count;
+* ``ota_stream_fold_apply`` — one cluster's term of the streaming fold.
+
+All channel knobs are traced, so ``ScenarioBank`` vmaps over them
+freely. Off TPU the wrappers that take an ``impl`` default to the jnp
+form; tests force ``impl="pallas"`` (interpret mode) to validate the
+kernels themselves.
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.ota_channel.kernel import (
-    drawn_native, ota_aggregate_client_pallas, ota_aggregate_fused_pallas,
-    ota_aggregate_pallas, ota_channel_pallas, ota_client_fold_drawn_pallas,
+    drawn_native, ota_aggregate_client_pallas, ota_client_fold_drawn_pallas,
     ota_mask_count_pallas, ota_mask_weight_pallas,
 )
 from repro.kernels.ota_channel.ref import (
-    bits_to_mask, ota_aggregate_client_ref, ota_aggregate_slab_ref,
-    ota_channel_ref, ota_stream_fold_ref, pass_probability,
+    bits_to_mask, ota_aggregate_client_ref, ota_stream_fold_ref,
+    pass_probability,
 )
-from repro.kernels.slab import (
-    LANE, ROW_QUANTUM, flat_to_slab, on_tpu, pad_to_lanes,
-)
-
-
-def _ota_channel_impl(slab, bits, sigma2, h_th, ota_on, interpret: bool):
-    """Un-jitted mask+apply on a (rows, 128) slab — the single home for
-    the (1, 3) params-block layout (also used by the packed final gather
-    in repro.core.hota, so the two call sites can never diverge)."""
-    params = jnp.stack([jnp.asarray(sigma2, jnp.float32).reshape(()),
-                        jnp.asarray(h_th, jnp.float32).reshape(()),
-                        jnp.asarray(ota_on, jnp.float32).reshape(())])
-    return ota_channel_pallas(slab, bits, params.reshape(1, 3),
-                              interpret=interpret)
-
-
-@partial(jax.jit, static_argnames=("interpret",))
-def ota_channel(x: jax.Array, key: jax.Array, sigma2, h_th,
-                ota_on=1.0, interpret: bool = None):
-    """Fused channel mask+apply. Returns (masked_x, mask) shaped like x.
-
-    All channel knobs (σ², H_th, the ota_on gate) are traced — one
-    compiled kernel serves every scenario. ``interpret=None`` resolves
-    the platform at trace time (compiled on TPU, interpret elsewhere) —
-    never baked at import, so late backend selection dispatches right.
-    """
-    if interpret is None:
-        interpret = not on_tpu()
-    slab, n = pad_to_lanes(x)
-    bits = jax.random.bits(key, slab.shape, jnp.uint32)
-    out, mask = _ota_channel_impl(slab, bits, sigma2, h_th, ota_on,
-                                  interpret)
-    out = out.reshape(-1)[:n].reshape(x.shape)
-    mask = mask.reshape(-1)[:n].reshape(x.shape)
-    return out, mask
+from repro.kernels.slab import LANE, ROW_QUANTUM, on_tpu
 
 
 def ota_mask_weight_apply(x: jax.Array, bits: jax.Array, sigma2, h_th,
@@ -377,112 +344,3 @@ def ota_mask_count_apply(x: jax.Array, bits_all: jax.Array, me, sigma2_all,
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
     cnt = cnts[0] if len(cnts) == 1 else jnp.concatenate(cnts)
     return out.reshape(x.shape), cnt.reshape(x.shape)
-
-
-@jax.jit
-def ota_channel_reference(x: jax.Array, key: jax.Array, sigma2, h_th,
-                          ota_on=1.0):
-    """Oracle path on the same bit stream (for tests/benchmarks)."""
-    slab, n = pad_to_lanes(x)
-    bits = jax.random.bits(key, slab.shape, jnp.uint32)
-    out, mask, _ = ota_channel_ref(slab, bits, sigma2, h_th, ota_on)
-    return (out.reshape(-1)[:n].reshape(x.shape),
-            mask.reshape(-1)[:n].reshape(x.shape))
-
-
-def _channel_params_block(sigma2, h_th, noise_std, ota_on, c: int):
-    """The aggregate kernels' (1, C+2) row: [p_pass_·, z_std, ota_on]."""
-    return jnp.concatenate([
-        pass_probability(jnp.asarray(sigma2, jnp.float32).reshape(c), h_th),
-        jnp.asarray(noise_std, jnp.float32).reshape(1),
-        jnp.asarray(ota_on, jnp.float32).reshape(1),
-    ]).reshape(1, c + 2)
-
-
-def _ota_aggregate_fused_impl(wg, section_keys, section_lens, sigma2, h_th,
-                              noise_std, ota_on, n_clients: int,
-                              interpret: bool, bits=None,
-                              nbits=None) -> jax.Array:
-    """In-kernel-RNG whole-model aggregation (the packed slab path).
-
-    ``section_keys``: (S, 2, 2) uint32 threefry keys — [section][gain|awgn]
-    for each of the packer's sections in layout order (the caller derives
-    the folds from ``ota.packed_section_folds``); ``section_lens``: the
-    matching static lengths. Each section runs its own kernel call
-    (disjoint row ranges of the slab, disjoint chunk-quantized streams),
-    so the FGN phase can re-draw just the ω̃ tail. The interpret-mode
-    stream is reproducible outside the kernel (see
-    repro.core.ota._section_bits); pass the pre-drawn ``bits``/``nbits``
-    slabs (the identical stream) to hoist the RNG out of a scenario vmap
-    (ScenarioBank's supplied mode).
-    """
-    c, p = wg.shape
-    params = _channel_params_block(sigma2, h_th, noise_std, ota_on, c)
-    keys = jnp.asarray(section_keys, jnp.uint32)
-    wg32 = wg.astype(jnp.float32)
-    outs, off = [], 0
-    for s, length in enumerate(section_lens):
-        if not length:
-            continue
-        sec = jax.lax.slice_in_dim(wg32, off, off + length, axis=1)
-        kw = {}
-        if bits is not None:
-            kw = dict(
-                bits=flat_to_slab(
-                    jax.lax.slice_in_dim(bits, off, off + length, axis=1)),
-                nbits=flat_to_slab(
-                    jax.lax.slice_in_dim(nbits, off, off + length, axis=0)))
-        out = ota_aggregate_fused_pallas(
-            flat_to_slab(sec), keys[s], params,
-            n_clients=n_clients, interpret=interpret, **kw)
-        outs.append(out.reshape(length))
-        off += length
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
-
-
-def _ota_aggregate_impl(wg, bits, nbits, sigma2, h_th, noise_std, ota_on,
-                        n_clients: int, interpret: bool) -> jax.Array:
-    """Un-jitted body of ``ota_aggregate`` — callers inside a jit use this
-    directly so slab prep fuses with the kernel."""
-    c, p = wg.shape
-    params = _channel_params_block(sigma2, h_th, noise_std, ota_on, c)
-    out = ota_aggregate_pallas(
-        flat_to_slab(wg.astype(jnp.float32)),
-        flat_to_slab(bits),
-        flat_to_slab(nbits),
-        params,
-        n_clients=n_clients,
-        interpret=interpret,
-    )
-    return out.reshape(p)
-
-
-@partial(jax.jit, static_argnames=("n_clients", "interpret"))
-def ota_aggregate(
-    wg: jax.Array,           # (C, P) f32 slab, P lane-aligned (packer layout)
-    bits: jax.Array,         # (C, P) uint32 gain bits
-    nbits: jax.Array,        # (P,) uint32 AWGN bits
-    sigma2: jax.Array,       # (C,) traced per-cluster variance
-    h_th, noise_std, ota_on,
-    n_clients: int,
-    interpret: bool = None,
-) -> jax.Array:
-    """Whole-model OTA aggregation (eqs. 8-10) in one fused kernel pass.
-
-    Returns the (P,) PS estimate ĝ. Bit streams are the caller's (the
-    packed key schedule lives in ``repro.core.ota``), so the jnp oracle
-    ``ota_aggregate_reference`` consumes the identical stream.
-    ``interpret=None`` resolves the platform at trace time.
-    """
-    if interpret is None:
-        interpret = not on_tpu()
-    return _ota_aggregate_impl(wg, bits, nbits, sigma2, h_th, noise_std,
-                               ota_on, n_clients, interpret)
-
-
-@partial(jax.jit, static_argnames=("n_clients",))
-def ota_aggregate_reference(wg, bits, nbits, sigma2, h_th, noise_std, ota_on,
-                            n_clients: int) -> jax.Array:
-    """Oracle for ``ota_aggregate`` on the same bit stream."""
-    return ota_aggregate_slab_ref(wg, bits, nbits, sigma2, h_th, noise_std,
-                                  ota_on, n_clients)

@@ -1,10 +1,11 @@
 """Pure-jnp oracles for the ota_channel kernel package.
 
-Math (paper eqs. 3, 7-10): from counter-based uniform bits, draw per-entry
-channel gains H ~ N(0, σ²) via Box-Muller, threshold |H|² ≥ H_th into the
-sparsification mask M, and either apply it to one weighted-gradient slab
-(``ota_channel_ref``) or run the whole PS estimator across the cluster
-axis (``ota_aggregate_slab_ref``):
+Math (paper eqs. 3, 7-10): from counter-based uniform bits, draw the
+per-entry sparsification mask M = 1{|H|² ≥ H_th} for H ~ N(0, σ²) by
+inverse-CDF thresholding (``bits_to_mask``), the AWGN by Box-Muller
+(``bits_to_gaussian``), and run the PS estimator across the cluster axis
+(``ota_aggregate_slab_ref``, and ``ota_aggregate_client_ref`` with the
+client weights folded in):
 
     y(j)  = Σ_{l∈M(j)} wg_l(j) + z(j)          (eq. 8, channel inverted)
     ĝ(j)  = y(j) / (|M_k(j)| · N), 0 if |M|=0  (eq. 10, guarded)
@@ -55,15 +56,6 @@ def bits_to_mask(bits: jax.Array, sigma2, h_th, ota_on=1.0) -> jax.Array:
         2.0 ** -24)
     p = pass_probability(sigma2, h_th)
     return jnp.logical_or(u < p, jnp.asarray(ota_on, jnp.float32) < 0.5)
-
-
-def ota_channel_ref(x: jax.Array, bits: jax.Array, sigma2, h_th, ota_on=1.0):
-    """x: any-shape slab; bits: same-shape uint32. Returns (masked_x, mask, gain)."""
-    h = bits_to_gaussian(bits, sigma2)
-    mask = jnp.logical_or((h * h) >= h_th,
-                          jnp.asarray(ota_on, jnp.float32) < 0.5)
-    out = jnp.where(mask, x, jnp.zeros_like(x))
-    return out, mask.astype(x.dtype), h
 
 
 def ota_aggregate_client_ref(
@@ -122,9 +114,10 @@ def ota_aggregate_slab_ref(
 ) -> jax.Array:
     """eqs. (8)-(10) on flat slabs, per-cluster where+sum in plain jnp.
 
-    The packed kernel's oracle: same bits, same inverse-CDF mask rule
-    (``bits_to_mask``), same Box-Muller AWGN, same |M|·N guard — but
-    per-cluster masks materialize as full (C, ...) arrays. A non-None
+    The oracle ``ota_aggregate_client_ref`` is built on: same bits, same
+    inverse-CDF mask rule (``bits_to_mask``), same Box-Muller AWGN, same
+    |M|·N guard as the kernels — but per-cluster masks materialize as
+    full (C, ...) arrays. A non-None
     ``live`` ANDs cluster participation into the masks AFTER the
     ``ota_on`` all-pass gate (blackout removes a cluster even in the
     error-free baseline); ``n_eff`` replaces the static N denominator.

@@ -4,8 +4,8 @@ Every kernel in this package views its operands as lane-aligned slabs:
 the last dimension is the 128-wide VPU lane axis, the second-to-last is
 padded to a multiple of 8 sublanes (f32 packing). Historically each
 wrapper re-implemented the ravel/pad/reshape dance; this module is the
-single home for that logic — used by ota_channel, masked_gradnorm,
-flash_attention and the flat-pack OTA engine (repro.common.flatpack).
+single home for that logic — used by masked_gradnorm, flash_attention
+and the flat-pack layout (repro.common.flatpack).
 """
 from __future__ import annotations
 
@@ -51,22 +51,6 @@ def pad_to_lanes(x: jax.Array):
     rows = slab_rows(n)
     flat = jnp.pad(flat, (0, rows * LANE - n))
     return flat.reshape(rows, LANE), n
-
-
-def flat_to_slab(flat: jax.Array) -> jax.Array:
-    """View an already lane-aligned (..., P) flat array as (..., rows, LANE).
-
-    ``P`` must be a multiple of ROW_QUANTUM (the flat-packer guarantees
-    this); leading batch dims (cluster/scenario axes) pass through.
-    """
-    p = flat.shape[-1]
-    assert p % ROW_QUANTUM == 0, (flat.shape, ROW_QUANTUM)
-    return flat.reshape(flat.shape[:-1] + (p // LANE, LANE))
-
-
-def slab_to_flat(slab: jax.Array) -> jax.Array:
-    """Inverse of :func:`flat_to_slab`."""
-    return slab.reshape(slab.shape[:-2] + (slab.shape[-2] * slab.shape[-1],))
 
 
 def pad_axis(x: jax.Array, axis: int, multiple: int) -> jax.Array:

@@ -1,6 +1,6 @@
 """Slab-native simulator channel (DESIGN.md §3.12): the client-folded
-zero-copy OTA aggregation vs the per-leaf/packed oracles on shared bit
-streams, the sim-vs-distributed stream-schedule pin, the SIM_CHAN_FOLD
+zero-copy OTA aggregation vs the per-leaf and slab oracles on shared bit
+streams, its fused and supplied word modes, the sim-vs-distributed stream-schedule pin, the SIM_CHAN_FOLD
 reserved-domain pin, and the HLO assertion that the new sim step
 allocates no (C, P) slab-sized buffer."""
 import jax
@@ -15,7 +15,9 @@ from repro.common.flatpack import packer_for
 from repro.core import ota
 from repro.core.channel import channel_params, stack_channel_params
 from repro.kernels.ota_channel.ops import ota_client_fold_apply
-from repro.kernels.ota_channel.ref import bits_to_gaussian, bits_to_mask
+from repro.kernels.ota_channel.ref import (
+    bits_to_gaussian, bits_to_mask, ota_aggregate_slab_ref,
+)
 
 C, N = 3, 2
 
@@ -44,10 +46,12 @@ def _packer(tree):
 
 
 # ----------------------------------------------------------------- oracle
-def test_client_folded_matches_einsum_plus_packed():
-    """Client-folded == einsum("cn,cn...->c...") followed by the packed
-    kernel on the SAME multi-section layout — the weighted tree is
-    mathematically folded in, not re-derived from different streams."""
+def test_client_folded_matches_einsum_plus_slab_ref():
+    """Client-folded == einsum("cn,cn...->c...") followed by the slab
+    oracle (``ota_aggregate_slab_ref``) on the whole layout's words
+    (``packed_gain_bits`` / ``packed_noise_bits``) of the SAME
+    multi-section layout — the weighted tree is mathematically folded
+    in, not re-derived from different streams."""
     fl = FLConfig(n_clusters=C, n_clients=N, sigma2=(0.5, 1.0, 2.0),
                   noise_std=0.7)
     chan = channel_params(fl)
@@ -59,7 +63,10 @@ def test_client_folded_matches_einsum_plus_packed():
 
     ghat = ota.ota_aggregate_client_folded(key, g, p, chan, N, packer)
     wg = jax.tree.map(lambda l: jnp.einsum("cn,cn...->c...", p, l), g)
-    oracle = ota.ota_aggregate_packed(key, wg, chan, N, packer)
+    oracle = packer.unpack(ota_aggregate_slab_ref(
+        packer.pack(wg), ota.packed_gain_bits(key, packer, C),
+        ota.packed_noise_bits(key, packer), chan.sigma2, chan.h_threshold,
+        chan.noise_std, chan.ota_on, N))
     for (kp, a), (_, b) in zip(jax.tree_util.tree_flatten_with_path(ghat)[0],
                                jax.tree_util.tree_flatten_with_path(oracle)[0]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -67,24 +74,25 @@ def test_client_folded_matches_einsum_plus_packed():
                                    err_msg=jax.tree_util.keystr(kp))
 
 
-def test_client_folded_matches_per_leaf_oracle_on_shared_streams():
+@pytest.mark.parametrize("c,sigmas", [(2, (1.0, 0.25)), (4, (0.5,)),
+                                      (10, (0.25, 0.5, 1.0, 2.0))])
+def test_client_folded_matches_per_leaf_oracle_on_shared_streams(c, sigmas):
     """Decode the SAME section streams into per-leaf masks/noise and run
     the seed per-leaf estimator (ota_aggregate_leaf) on the einsum'd
     weighted tree — the client-folded path must reproduce it."""
-    fl = FLConfig(n_clusters=C, n_clients=N, sigma2=(0.25, 0.5, 1.0),
-                  noise_std=0.4)
+    fl = FLConfig(n_clusters=c, n_clients=N, sigma2=sigmas, noise_std=0.4)
     chan = channel_params(fl)
     key = jax.random.PRNGKey(5)
-    g = _grad_tree(jax.random.fold_in(key, 1), C, N)
-    p = jax.random.uniform(jax.random.fold_in(key, 2), (C, N), jnp.float32,
+    g = _grad_tree(jax.random.fold_in(key, 1), c, N)
+    p = jax.random.uniform(jax.random.fold_in(key, 2), (c, N), jnp.float32,
                            0.5, 1.5)
     packer = _packer(g)
 
     ghat = ota.ota_aggregate_client_folded(key, g, p, chan, N, packer)
 
-    bits = ota.packed_gain_bits(key, packer, C)              # (C, P)
+    bits = ota.packed_gain_bits(key, packer, c)              # (C, P)
     nbits = ota.packed_noise_bits(key, packer)
-    sig = chan.sigma2.reshape(C, 1)
+    sig = chan.sigma2.reshape(c, 1)
     mask_tree = packer.unpack(
         bits_to_mask(bits, sig, chan.h_threshold, chan.ota_on)
         .astype(jnp.float32))
@@ -99,7 +107,11 @@ def test_client_folded_matches_per_leaf_oracle_on_shared_streams():
                                    rtol=1e-5, atol=1e-6)
 
 
-def test_client_folded_ota_off_is_weighted_mean():
+BITS_MODES = ["fused", "supplied"]
+
+
+@pytest.mark.parametrize("bits_mode", BITS_MODES)
+def test_client_folded_ota_off_is_weighted_mean(bits_mode):
     """ota=False: all-pass masks, zero AWGN -> ĝ = Σ_l Σ_n p·g / (C·N)."""
     fl = FLConfig(n_clusters=C, n_clients=N, noise_std=7.0, ota=False)
     chan = channel_params(fl)
@@ -108,13 +120,15 @@ def test_client_folded_ota_off_is_weighted_mean():
                            0.5, 1.5)
     packer = _packer(g)
     ghat = ota.ota_aggregate_client_folded(jax.random.PRNGKey(8), g, p,
-                                           chan, N, packer)
+                                           chan, N, packer,
+                                           bits_mode=bits_mode)
     for a, l in zip(jax.tree.leaves(ghat), jax.tree.leaves(g)):
         ref = np.einsum("cn,cn...->...", np.asarray(p), np.asarray(l)) / (C * N)
         np.testing.assert_allclose(np.asarray(a), ref, rtol=1e-5, atol=1e-6)
 
 
-def test_client_folded_all_blocked_is_exact_zero():
+@pytest.mark.parametrize("bits_mode", BITS_MODES)
+def test_client_folded_all_blocked_is_exact_zero(bits_mode):
     """σ² → 0 with H_th > 0: |M| = 0 everywhere -> exactly 0, never
     noise/(cnt·N), never NaN."""
     fl = FLConfig(n_clusters=C, n_clients=N, h_threshold=0.5, noise_std=5.0,
@@ -125,14 +139,16 @@ def test_client_folded_all_blocked_is_exact_zero():
     p = jnp.full((C, N), 2.0)
     packer = _packer(g)
     ghat = ota.ota_aggregate_client_folded(jax.random.PRNGKey(13), g, p,
-                                           chan, N, packer)
+                                           chan, N, packer,
+                                           bits_mode=bits_mode)
     for leaf in jax.tree.leaves(ghat):
         arr = np.asarray(leaf)
         assert np.isfinite(arr).all()
         np.testing.assert_array_equal(arr, np.zeros_like(arr))
 
 
-def test_client_folded_composes_with_scenario_vmap():
+@pytest.mark.parametrize("bits_mode", BITS_MODES)
+def test_client_folded_composes_with_scenario_vmap(bits_mode):
     """Under an (S≥4,)-batched ChannelParams bank with a shared key/grads
     (the ScenarioBank contract), every row equals its unbanked run."""
     base = FLConfig(n_clusters=C, n_clients=N)
@@ -149,11 +165,12 @@ def test_client_folded_composes_with_scenario_vmap():
                            0.5, 1.5)
     packer = _packer(g)
     banked = jax.vmap(
-        lambda ch: ota.ota_aggregate_client_folded(key, g, p, ch, N, packer)
-    )(bank)
+        lambda ch: ota.ota_aggregate_client_folded(
+            key, g, p, ch, N, packer, bits_mode=bits_mode))(bank)
     for s in range(4):
         one = ota.ota_aggregate_client_folded(
-            key, g, p, jax.tree.map(lambda x: x[s], bank), N, packer)
+            key, g, p, jax.tree.map(lambda x: x[s], bank), N, packer,
+            bits_mode=bits_mode)
         for a, b in zip(jax.tree.leaves(one),
                         jax.tree.leaves(jax.tree.map(lambda x: x[s], banked))):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -182,36 +199,53 @@ def test_client_fold_kernel_matches_jnp_property(n, seed, noise):
                                rtol=1e-5, atol=1e-6)
 
 
-def test_client_folded_rejects_mismatched_tree():
+def _missing_leaf(g):
+    return {"final": g["final"], "trunk": {"fc0": g["trunk"]["fc0"]}}
+
+
+def _wrong_leaf_shape(g):
+    return {**g, "final": {"w": g["final"]["w"][:, :, :10, :],
+                           "b": g["final"]["b"]}}
+
+
+@pytest.mark.parametrize("mangle,names", [
+    (_missing_leaf, ("fc1", "missing")),
+    (_wrong_leaf_shape, ("final", "(10, 8)")),
+], ids=["missing_leaf", "wrong_leaf_shape"])
+def test_client_folded_rejects_mismatched_tree(mangle, names):
     """A grads tree that does not match the packer template (beyond its
-    (C, N) batch axes) raises the readable leaf-path error."""
+    (C, N) batch axes) raises the readable error: it names the engine,
+    the offending leaf path or shape and the section it belongs in, not
+    an opaque downstream shape error."""
     g = _grad_tree(jax.random.PRNGKey(0), C, N)
     packer = _packer(g)
     fl = FLConfig(n_clusters=C, n_clients=N, sigma2=(1.0,))
     chan = channel_params(fl)
-    bad = dict(g)
-    bad["final"] = {"w": g["final"]["w"][:, :, :10, :],  # wrong leaf shape
-                    "b": g["final"]["b"]}
-    with pytest.raises(ValueError, match="client-folded"):
-        ota.ota_aggregate_client_folded(jax.random.PRNGKey(1), bad,
+    with pytest.raises(ValueError, match="client-folded") as e:
+        ota.ota_aggregate_client_folded(jax.random.PRNGKey(1), mangle(g),
                                         jnp.ones((C, N)), chan, N, packer)
+    msg = str(e.value)
+    assert "section" in msg and any(s in msg for s in names), msg
 
 
-def test_packed_supplied_equals_fused_on_multisection_layout():
-    """ota_aggregate_packed's supplied-bits mode (the ScenarioBank hoist)
-    must reproduce the fused in-kernel draw on a ``sections="toplevel"``
-    packer — the generalized per-section schedule, not the old head/tail
-    pair, on BOTH sides."""
+def test_client_folded_supplied_equals_fused_on_multisection_layout():
+    """The supplied-words mode (the ScenarioBank hoist: whole chunks
+    drawn outside, sliced per leaf) must reproduce the fused mode (each
+    leaf's words from their positions, the drawing kernel on a leaf's
+    ROW_QUANTUM body) on a ``sections="toplevel"`` packer — the
+    per-section schedule on BOTH sides. The kernels run in interpret
+    mode."""
     fl = FLConfig(n_clusters=C, n_clients=N, sigma2=(0.5, 1.0, 2.0),
                   noise_std=0.8)
     chan = channel_params(fl)
     key = jax.random.PRNGKey(31)
     g = _grad_tree(jax.random.fold_in(key, 1), C, N)
-    wg = jax.tree.map(lambda l: jnp.sum(l, axis=1), g)
+    p = jax.random.uniform(jax.random.fold_in(key, 2), (C, N), jnp.float32,
+                           0.5, 1.5)
     packer = _packer(g)
-    a = ota.ota_aggregate_packed(key, wg, chan, N, packer)
-    b = ota.ota_aggregate_packed(key, wg, chan, N, packer,
-                                 bits_mode="supplied")
+    a, b = (ota.ota_aggregate_client_folded(key, g, p, chan, N, packer,
+                                            bits_mode=m, impl="pallas")
+            for m in BITS_MODES)
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    rtol=1e-6, atol=1e-7)

@@ -233,23 +233,6 @@ def test_check_tree_matches_packer_names_leaf_and_section():
     assert "missing" in str(e.value) or "fc1" in str(e.value)
 
 
-def test_packed_final_gather_mismatch_error_is_readable():
-    """The distributed packed-ω̃ gather raises with leaf path + expected
-    section on a wrong pytree, not an opaque shape error."""
-    from repro.core.hota import OTACtx, make_packed_final_gather
-    template = {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32),
-                "b": jax.ShapeDtypeStruct((4,), jnp.float32)}
-    gather = make_packed_final_gather(
-        ("client", "cluster"), ("cluster",), 2, 4, jnp.float32,
-        [("embed", "mlp"), ("mlp",)], template=template)
-    ctx = OTACtx(*(jnp.zeros(()) for _ in range(6)))
-    wrong = {"w": jnp.zeros((8, 4)), "extra": jnp.zeros((3,))}
-    with pytest.raises(ValueError) as e:
-        jax.eval_shape(gather, wrong, ctx)
-    msg = str(e.value)
-    assert "packed final gather" in msg and ("extra" in msg or "b" in msg)
-
-
 def test_slab_helpers_roundtrip():
     x = jnp.arange(1000.0).reshape(10, 100)
     slab, n = pad_to_lanes(x)

@@ -9,36 +9,79 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels import (
     flash_attention, flash_attention_reference,
     masked_gradnorm, masked_gradnorm_reference,
-    ota_channel, ota_channel_reference,
 )
+from repro.kernels.ota_channel.ops import (
+    ota_mask_count_apply, ota_mask_weight_apply,
+)
+from repro.kernels.ota_channel.ref import bits_to_mask
 
 
-# ---------------------------------------------------------------- ota_channel
-@pytest.mark.parametrize("shape", [(100,), (8, 128), (2048,), (3, 17, 11)])
+# ------------------------------------------------- ota_mask_weight / count
+# impl="pallas" forces the kernels (interpret mode on CPU). A leaf's
+# ROW_QUANTUM main body runs the kernel and the ragged remainder the jnp
+# reference, so the grid holds kernel-only leaves ((8, 128), (2048,)) and
+# remainder-only ones ((100,), (3, 17, 11)); the oracle is ref.bits_to_mask
+# on the same words.
+SHAPES = [(100,), (8, 128), (2048,), (3, 17, 11)]
+
+
+def _mask_weight_ref(x, bits, sigma2, h_th, w):
+    m = bits_to_mask(bits, sigma2, h_th).reshape(x.shape)
+    return jnp.where(m, w * x.astype(jnp.float32), 0.0), m
+
+
+@pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ota_channel_matches_ref(shape, dtype):
+def test_ota_mask_weight_matches_ref(shape, dtype):
     x = jax.random.normal(jax.random.PRNGKey(0), shape).astype(dtype)
-    key = jax.random.PRNGKey(7)
-    o1, m1 = ota_channel(x, key, 1.0, 0.032)
-    o2, m2 = ota_channel_reference(x, key, 1.0, 0.032)
-    np.testing.assert_allclose(np.asarray(o1, np.float32),
-                               np.asarray(o2, np.float32), rtol=1e-5)
-    np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
+    n = int(np.prod(shape))
+    bits = jax.random.bits(jax.random.PRNGKey(7), (n,), jnp.uint32)
+    o1, m1 = ota_mask_weight_apply(x, bits, 1.0, 0.032, 1.0, 0.7,
+                                   interpret=True, impl="pallas")
+    o2, m2 = _mask_weight_ref(x, bits, 1.0, 0.032, 0.7)
+    assert o1.shape == m1.shape == shape and o1.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2, np.float32))
 
 
 @settings(max_examples=15, deadline=None)
 @given(n=st.integers(1, 5000), sigma2=st.floats(0.25, 2.0),
        seed=st.integers(0, 99))
-def test_ota_channel_property(n, sigma2, seed):
+def test_ota_mask_weight_property(n, sigma2, seed):
     x = jax.random.normal(jax.random.PRNGKey(seed), (n,))
-    key = jax.random.PRNGKey(seed + 1)
-    o1, m1 = ota_channel(x, key, sigma2, 0.032)
-    o2, m2 = ota_channel_reference(x, key, sigma2, 0.032)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-5)
-    # masked entries are exactly zeroed; unmasked pass through unchanged
+    bits = jax.random.bits(jax.random.PRNGKey(seed + 1), (n,), jnp.uint32)
+    o1, m1 = ota_mask_weight_apply(x, bits, sigma2, 0.032, 1.0, 0.7,
+                                   interpret=True, impl="pallas")
+    o2, m2 = _mask_weight_ref(x, bits, sigma2, 0.032, 0.7)
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2, np.float32))
+    # masked entries are exactly zeroed; unmasked carry w·x
     np.testing.assert_array_equal(np.asarray(o1[m1 < 0.5]), 0.0)
     np.testing.assert_allclose(np.asarray(o1[m1 > 0.5]),
-                               np.asarray(x[m1 > 0.5]), rtol=1e-6)
+                               0.7 * np.asarray(x[m1 > 0.5]), rtol=1e-6)
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_ota_mask_count_matches_ref(shape):
+    """M_me ∘ (w·x) and Σ_l M_l over three clusters' streams, one of them
+    blacked out (live=0): it drops out of the count."""
+    c, me = 3, 1
+    x = jax.random.normal(jax.random.PRNGKey(1), shape)
+    n = int(np.prod(shape))
+    bits = jax.random.bits(jax.random.PRNGKey(2), (c, n), jnp.uint32)
+    sig = jnp.asarray([0.25, 1.0, 2.0])
+    live = jnp.asarray([1.0, 1.0, 0.0])
+    out, cnt = ota_mask_count_apply(x, bits, me, sig, 0.032, 1.0, 0.7,
+                                    live_all=live, interpret=True,
+                                    impl="pallas")
+    masks = bits_to_mask(bits, sig.reshape(c, 1), 0.032) & (
+        live.reshape(c, 1) > 0.5)
+    ref_cnt = masks.sum(axis=0).astype(jnp.float32).reshape(shape)
+    ref_out = jnp.where(masks[me].reshape(shape), 0.7 * x, 0.0)
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(ref_cnt))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                               rtol=1e-6)
+    assert float(jnp.max(cnt)) <= 2.0
 
 
 # ------------------------------------------------------------ masked_gradnorm

@@ -296,11 +296,13 @@ def test_on_tpu_resolves_at_trace_time(monkeypatch):
 def test_interpret_default_matches_explicit_on_cpu():
     """interpret=None resolves from the live backend inside the op: on
     this CPU host it must take exactly the interpret=True path."""
-    from repro.kernels.ota_channel.ops import ota_channel
+    from repro.kernels.ota_channel.ops import ota_mask_weight_apply
 
-    x = jax.random.normal(jax.random.PRNGKey(0), (640,))
-    key = jax.random.PRNGKey(1)
-    a_out, a_mask = ota_channel(x, key, 0.5, 0.1)
-    b_out, b_mask = ota_channel(x, key, 0.5, 0.1, interpret=True)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2048,))
+    bits = jax.random.bits(jax.random.PRNGKey(1), (2048,), jnp.uint32)
+    a_out, a_mask = ota_mask_weight_apply(x, bits, 0.5, 0.1, 1.0, 0.7,
+                                          impl="pallas")
+    b_out, b_mask = ota_mask_weight_apply(x, bits, 0.5, 0.1, 1.0, 0.7,
+                                          interpret=True, impl="pallas")
     np.testing.assert_array_equal(np.asarray(a_out), np.asarray(b_out))
     np.testing.assert_array_equal(np.asarray(a_mask), np.asarray(b_mask))

@@ -1,18 +1,17 @@
-"""Flat-packed OTA aggregation vs the per-leaf oracle (shared bit stream),
-plus the paper's edge cases routed through the fused kernel and the PRNG
-stream-disjointness pins (noise vs cluster fold-in domains)."""
+"""The packed layout's streams: eq.-5 masks as the tail slice of the
+round's draw, the slab engine vs the per-leaf path with the channel off,
+and the PRNG stream-disjointness pins (noise vs cluster fold-in
+domains). The client-folded engine's equivalence to the per-leaf oracle
+on shared streams lives in tests/test_client_folded.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-from hypothesis import given, settings, strategies as st
 
 from repro.common.config import FLConfig
 from repro.common.flatpack import packer_for
 from repro.core import ota
-from repro.core.channel import channel_params, stack_channel_params
-from repro.kernels import ota_aggregate, ota_aggregate_reference
-from repro.kernels.ota_channel.ref import bits_to_gaussian, bits_to_mask
+from repro.core.channel import channel_params
+from repro.kernels.ota_channel.ref import bits_to_mask
 
 
 def _wg_tree(key, C, scale=1.0):
@@ -29,57 +28,6 @@ def _wg_tree(key, C, scale=1.0):
 def _template(tree):
     return jax.tree.map(lambda l: jax.ShapeDtypeStruct(l.shape[1:], l.dtype),
                         tree)
-
-
-# ---------------------------------------------------------------- packed vs
-# per-leaf oracle on a SHARED bit stream: the kernel's estimate must equal
-# running eqs. 8-10 leaf-by-leaf with the masks/noise decoded from the same
-# bits (ota_aggregate_leaf is the seed implementation, kept as oracle).
-@pytest.mark.parametrize("C,sigmas", [(2, (1.0, 0.25)), (4, (0.5,)),
-                                      (10, (0.25, 0.5, 1.0, 2.0))])
-def test_packed_matches_per_leaf_oracle(C, sigmas):
-    fl = FLConfig(n_clusters=C, n_clients=3, sigma2=sigmas, noise_std=0.7)
-    chan = channel_params(fl)
-    key = jax.random.PRNGKey(42)
-    wg = _wg_tree(jax.random.fold_in(key, 1), C)
-    packer = packer_for(_template(wg), tail="final")
-
-    ghat = ota.ota_aggregate_packed(key, wg, chan, fl.n_clients, packer)
-
-    # oracle: same bits -> per-leaf masks/noise -> seed ota_aggregate_leaf
-    bits = ota.packed_gain_bits(key, packer, C)              # (C, P)
-    nbits = ota.packed_noise_bits(key, packer)
-    sig = chan.sigma2.reshape(C, 1)
-    masks_slab = bits_to_mask(bits, sig, chan.h_threshold, chan.ota_on)
-    noise_slab = (bits_to_gaussian(nbits, 1.0) * chan.noise_std
-                  * chan.ota_on)
-    mask_tree = packer.unpack(masks_slab.astype(jnp.float32))
-    noise_tree = packer.unpack(noise_slab)
-    oracle = jax.tree.map(
-        lambda w, m, z: ota.ota_aggregate_leaf(w, m > 0.5, z, fl.n_clients),
-        wg, mask_tree, noise_tree)
-
-    for a, b in zip(jax.tree.leaves(ghat), jax.tree.leaves(oracle)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
-
-
-@settings(max_examples=10, deadline=None)
-@given(C=st.integers(1, 6), n=st.integers(3, 400), seed=st.integers(0, 99),
-       noise=st.floats(0.0, 3.0))
-def test_packed_slab_kernel_matches_ref_property(C, n, seed, noise):
-    """ota_aggregate (Pallas) == ota_aggregate_reference (jnp) on random
-    lane-aligned slabs — the kernel-level contract."""
-    key = jax.random.PRNGKey(seed)
-    p = 1024 * (-(-n // 1024))
-    wg = jax.random.normal(key, (C, p))
-    bits = jax.random.bits(jax.random.fold_in(key, 1), (C, p), jnp.uint32)
-    nbits = jax.random.bits(jax.random.fold_in(key, 2), (p,), jnp.uint32)
-    sigma2 = jnp.linspace(0.25, 2.0, C)
-    a = ota_aggregate(wg, bits, nbits, sigma2, 0.032, noise, 1.0, 3)
-    b = ota_aggregate_reference(wg, bits, nbits, sigma2, 0.032, noise, 1.0, 3)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=1e-5, atol=1e-6)
 
 
 def test_final_masks_are_tail_slice_of_round_draw():
@@ -106,83 +54,6 @@ def test_final_masks_are_tail_slice_of_round_draw():
         [m.reshape(-1).astype(jnp.float32)
          for m in jax.tree.leaves(fmasks)])))
     assert 0.5 < rate < 1.0
-
-
-def test_packed_all_blocked_is_exact_zero():
-    """σ² → 0 with H_th > 0: |M_k| = 0 everywhere, so ĝ must be exactly 0
-    on every leaf — never noise/(cnt·N), never NaN — through the kernel."""
-    C = 3
-    fl = FLConfig(n_clusters=C, n_clients=2, h_threshold=0.5, noise_std=5.0,
-                  sigma2=(1e-14,))
-    chan = channel_params(fl)
-    wg = jax.tree.map(lambda l: jnp.full_like(l, 1e6),
-                      _wg_tree(jax.random.PRNGKey(0), C))
-    packer = packer_for(_template(wg), tail="final")
-    ghat = ota.ota_aggregate_packed(jax.random.PRNGKey(11), wg, chan,
-                                    fl.n_clients, packer)
-    for leaf in jax.tree.leaves(ghat):
-        arr = np.asarray(leaf)
-        assert np.isfinite(arr).all()
-        np.testing.assert_array_equal(arr, np.zeros_like(arr))
-
-
-def test_packed_ota_off_is_plain_weighted_mean():
-    """ota=False through the kernel: traced gate forces all-pass masks and
-    zero AWGN -> ĝ = Σ_l wg_l / (C·N) exactly (error-free baseline)."""
-    C, N = 4, 3
-    fl = FLConfig(n_clusters=C, n_clients=N, noise_std=7.0, ota=False)
-    chan = channel_params(fl)
-    wg = _wg_tree(jax.random.PRNGKey(5), C)
-    packer = packer_for(_template(wg), tail="final")
-    ghat = ota.ota_aggregate_packed(jax.random.PRNGKey(2), wg, chan, N,
-                                    packer)
-    for g, w in zip(jax.tree.leaves(ghat), jax.tree.leaves(wg)):
-        ref = np.asarray(w).sum(axis=0) / (C * N)
-        np.testing.assert_allclose(np.asarray(g), ref, rtol=1e-6, atol=1e-7)
-
-
-def test_supplied_bits_mode_identical_to_fused():
-    """bits_mode="supplied" (ScenarioBank's vmap-hoisted draw) must
-    reproduce the fused in-kernel stream value-for-value."""
-    C = 3
-    fl = FLConfig(n_clusters=C, n_clients=2, sigma2=(0.5, 1.0, 2.0),
-                  noise_std=0.8)
-    chan = channel_params(fl)
-    key = jax.random.PRNGKey(21)
-    wg = _wg_tree(jax.random.fold_in(key, 1), C)
-    packer = packer_for(_template(wg), tail="final")
-    a = ota.ota_aggregate_packed(key, wg, chan, 2, packer)
-    b = ota.ota_aggregate_packed(key, wg, chan, 2, packer,
-                                 bits_mode="supplied")
-    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                   rtol=1e-6, atol=1e-7)
-
-
-def test_packed_composes_with_scenario_vmap():
-    """The packed path under a (S,)-batched ChannelParams bank (the
-    ScenarioBank contract): vmap over chan, shared key/wg (CRN)."""
-    C, N = 2, 3
-    base = FLConfig(n_clusters=C, n_clients=N)
-    bank = stack_channel_params([
-        channel_params(base),
-        channel_params(FLConfig(n_clusters=C, n_clients=N,
-                                sigma2=(0.05, 1.0))),
-        channel_params(FLConfig(n_clusters=C, n_clients=N, ota=False)),
-    ])
-    key = jax.random.PRNGKey(3)
-    wg = _wg_tree(jax.random.fold_in(key, 1), C)
-    packer = packer_for(_template(wg), tail="final")
-
-    banked = jax.vmap(
-        lambda ch: ota.ota_aggregate_packed(key, wg, ch, N, packer))(bank)
-    for s in range(3):
-        one = ota.ota_aggregate_packed(
-            key, wg, jax.tree.map(lambda x: x[s], bank), N, packer)
-        for a, b in zip(jax.tree.leaves(one),
-                        jax.tree.leaves(jax.tree.map(lambda x: x[s], banked))):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-6, atol=1e-7)
 
 
 def test_sim_packed_equals_per_leaf_when_ota_off():

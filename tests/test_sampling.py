@@ -30,6 +30,7 @@ from repro.core.channel import channel_params, fault_params
 from repro.core.sampling import (ClientBank, SampledHotaSim,
                                  gather_clients, init_client_bank,
                                  scatter_clients)
+from repro.kernels.ota_channel.ref import ota_aggregate_slab_ref
 
 C, N = 2, 2
 
@@ -88,8 +89,11 @@ def _jitted(c=C, n=N):
         "fold": wrap(ota.ota_aggregate_client_folded, False),
         "stream_f": wrap(ota.ota_aggregate_streaming, True),
         "fold_f": wrap(ota.ota_aggregate_client_folded, True),
-        "packed": jax.jit(lambda k, wg: ota.ota_aggregate_packed(
-            k, wg, chan, n, packer, bits_mode="supplied")),
+        "slab_ref": jax.jit(lambda k, wg: packer.unpack(
+            ota_aggregate_slab_ref(
+                packer.pack(wg), ota.packed_gain_bits(k, packer, c),
+                ota.packed_noise_bits(k, packer), chan.sigma2,
+                chan.h_threshold, chan.noise_std, chan.ota_on, n))),
     }
 
 
@@ -202,15 +206,16 @@ def test_streaming_hlo_holds_one_cluster():
 @settings(max_examples=3, deadline=None)
 @given(c=st.integers(1, 2), n=st.integers(1, 3))
 def test_streaming_triple_equivalence(c, n):
-    """sim (client-folded) ≡ streaming ≡ dist (einsum + packed kernel,
-    supplied bits) on shared streams, across random (C, N) topologies —
-    three formulations of eqs. 3 + 8-10 drawing the same §4 streams."""
+    """client-folded ≡ streaming ≡ slab oracle (einsum, then
+    ``ota_aggregate_slab_ref`` on the whole layout's words) on shared
+    streams, across random (C, N) topologies — three formulations of
+    eqs. 3 + 8-10 drawing the same §4 streams."""
     j = _jitted(c, n)
     key, g, p = j["args"]
     s = j["stream"](key, g, p)
     f = j["fold"](key, g, p)
     wg = jax.tree.map(lambda l: jnp.einsum("cn,cn...->c...", p, l), g)
-    d = j["packed"](key, wg)
+    d = j["slab_ref"](key, wg)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6), s, f)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(

@@ -6,14 +6,13 @@ Covers: bitwise equivalence of ``ota_aggregate_sectioned`` to the
 client-folded engine (streaming=False) and to the cluster-scan streaming
 engine (streaming=True) under every composed feature (faults via
 live/n_eff, split layouts via max_section_rows); the peak-memory HLO
-pins with positive controls (the packed engine's (C, P) slab, the
-supplied-mode client-folded engine's (C, CHUNK) stream draw); the no-silent-inertness
+pins with positive controls (the whole layout's (C, P) gain-word draw,
+the supplied-mode client-folded engine's (C, CHUNK) stream draw); the no-silent-inertness
 refusals (HotaSim build guards, the distributed step's ota_streaming
 rejection, ``apply_layout``'s named LayoutUnavailableError, the stale
 disk-cache re-measure path, LayoutBudgetError); the C-axis-blocked
-client kernel vs its unblocked form; the hardware-PRNG seed schedule
-(``tpu_hw_seed`` collision-freedom and blocking invariance); and the
-forced-4-device distributed program (slow marker).
+client kernel vs its unblocked form; and the forced-4-device
+distributed program (slow marker).
 """
 import functools
 import json
@@ -165,8 +164,9 @@ def _lower(agg, setup, **kw):
 def test_sectioned_hlo_no_full_slab():
     """The §3.16 pin: the compiled sectioned round holds no (P,)-sized
     or (C, P)-sized f32/u32 buffer — peak live streams are one section.
-    Positive control: the PACKED engine materializes the f32[C, P] slab
-    (so this pin cannot rot into vacuity)."""
+    Positive control: the whole layout's gain-word draw
+    (``packed_gain_bits``) holds the u32[C, P] buffer the pin must
+    detect (so this pin cannot rot into vacuity)."""
     setup = _setup()
     fl, chan, key, g, p, packer = setup
     P = packer.size
@@ -175,14 +175,16 @@ def test_sectioned_hlo_no_full_slab():
         hlo_audit.assert_hlo_pins(
             hlo, hlo_audit.no_slab_pins(C, P),
             context=f"sectioned round {kw} — per-section peak (§3.16)")
-    wg = jax.tree.map(lambda l: jnp.einsum("cn,cn...->c...", p, l), g)
-    hlo_packed = jax.jit(lambda k, w: ota.ota_aggregate_packed(
-        k, w, chan, N, packer)).lower(key, wg).compile().as_text()
+    hlo_bits = jax.jit(lambda k: ota.packed_gain_bits(
+        k, packer, C)).lower(key).compile().as_text()
     hlo_audit.assert_hlo_pins(
-        hlo_packed,
-        [hlo_audit.require_buffer((C, P), dtypes=("f32",),
-                                  note="the packed engine's (C, P) slab")],
-        context="packed-engine positive control")
+        hlo_bits,
+        [hlo_audit.require_buffer((C, P), dtypes=("u32",),
+                                  note="the whole layout's (C, P) words")],
+        context="whole-layout draw positive control")
+    with pytest.raises(AssertionError):
+        hlo_audit.assert_hlo_pins(hlo_bits, hlo_audit.no_slab_pins(C, P),
+                                  context="the pin sees the control")
 
 
 def test_sectioned_streaming_hlo_holds_one_cluster_one_section():
@@ -405,55 +407,6 @@ def test_client_params_blocked_layout():
     np.testing.assert_array_equal(got_live[:5], live)
     np.testing.assert_array_equal(got_live[5:], 0.0)    # padded dead
     np.testing.assert_array_equal(np.asarray(rows[:, -1]), 2.0)
-
-
-# ================================================ hardware-PRNG schedule
-
-def test_tpu_hw_seed_schedule_collision_free():
-    """The compiled TPU branch's per-(cluster, chunk) seeds are distinct
-    across the whole grid — and keyed on GLOBAL cluster indices, so
-    C-axis blocking enumerates the identical seed set in any block
-    shape (the blocking-invariance half of the §3.16 kernel rule)."""
-    key2 = jnp.asarray([0xDEADBEEF, 0x12345678], jnp.uint32)
-    CC, II = 64, 256
-    ls, iis = np.meshgrid(np.arange(CC), np.arange(II), indexing="ij")
-    seeds = np.asarray(jax.vmap(
-        lambda l, i: K.tpu_hw_seed(key2, l, i))(
-            jnp.asarray(ls.ravel(), jnp.uint32),
-            jnp.asarray(iis.ravel(), jnp.uint32)))
-    assert len(np.unique(seeds)) == CC * II
-    # AWGN stream (l=None) is the l-free base schedule — same arithmetic
-    # as l=0; disjointness from the gain streams comes from its own key
-    awgn = np.asarray(jax.vmap(
-        lambda i: K.tpu_hw_seed(key2, None, i))(
-            jnp.arange(II, dtype=jnp.uint32)))
-    np.testing.assert_array_equal(awgn, seeds.reshape(CC, II)[0])
-    # blocked enumeration (any cb) covers the same global seed set
-    cb = 5
-    blocked = []
-    for j in range((CC + cb - 1) // cb):
-        for l_loc in range(cb):
-            l = j * cb + l_loc
-            if l < CC:
-                blocked.append(int(K.tpu_hw_seed(
-                    key2, jnp.uint32(l), jnp.uint32(0))))
-    np.testing.assert_array_equal(np.sort(np.asarray(blocked)),
-                                  np.sort(seeds.reshape(CC, II)[:, 0]))
-
-
-def test_tpu_fused_kernel_traces():
-    """The compiled-TPU fused kernel (hardware PRNG, C-blocked grid) is
-    structurally valid: abstract evaluation on any backend succeeds and
-    yields the section-slab output shape. (Execution needs a TPU; this
-    pins that the branch cannot rot into a trace error.)"""
-    c, rows = 3, 2 * K.CHUNK_ROWS
-    wg = jax.ShapeDtypeStruct((c, rows, K.LANE), jnp.float32)
-    keys = jax.ShapeDtypeStruct((2, 2), jnp.uint32)
-    params = jax.ShapeDtypeStruct((1, c + 2), jnp.float32)
-    out = jax.eval_shape(
-        lambda w, k, pr: K.ota_aggregate_fused_pallas(
-            w, k, pr, n_clients=2, interpret=False), wg, keys, params)
-    assert out.shape == (rows, K.LANE) and out.dtype == jnp.float32
 
 
 # =============================================== distributed (slow path)

@@ -26,12 +26,10 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import ota
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.masked_gradnorm.ops import masked_gradnorm
-from repro.kernels.ota_channel.kernel import (
-    ota_aggregate_client_pallas, ota_aggregate_fused_pallas,
-)
+from repro.kernels.ota_channel.kernel import ota_aggregate_client_pallas
 from repro.kernels.ota_channel.ops import (
-    _channel_params_block, ota_aggregate, ota_client_fold_apply,
-    ota_client_fold_drawn_apply, ota_mask_count_apply, ota_mask_weight_apply,
+    ota_client_fold_apply, ota_client_fold_drawn_apply, ota_mask_count_apply,
+    ota_mask_weight_apply,
 )
 from repro.kernels.slab import LANE
 
@@ -118,12 +116,17 @@ def test_client_fold_drawn_compiles(one_chip):
 
 
 @pytest.mark.parametrize("shape,word0", [((1024, 2048), 2048),
-                                         ((512, 256), 1024)],
-                         ids=["fc2_w", "final_w"])
+                                         ((512, 256), 1024),
+                                         ((256, 512), 0),
+                                         ((512, 1024), 1024),
+                                         ((2048, 512), 3072)],
+                         ids=["fc2_w", "final_w", "fc0_w", "fc1_w", "fc3_w"])
 def test_client_fold_drawn2d_compiles(one_chip, shape, word0):
-    """The drawing kernel on a leaf in its own (a, b) layout, at fc2.w
-    (column blocks of 1024, draw tiles of 8 rows) and final.w (whole
-    256-wide rows, tiles of 32)."""
+    """The drawing kernel on each of the paper round's 2-D leaves in its
+    own (a, b) layout: fc2.w (column blocks of 1024, draw tiles of 8
+    rows), final.w (whole 256-wide rows, tiles of 32), fc0.w and fc3.w
+    (512-wide rows, tiles of 16) and fc1.w (1024-wide rows, tiles of
+    8)."""
     size = shape[0] * shape[1]
     n_chunks = (word0 + size - 1) // ota.CHUNK + 1
 
@@ -245,24 +248,6 @@ def test_client_fold_cluster_blocked_compiles(one_chip):
     _compile(fn, one_chip, "ota_client_fold_cblk",
              ((C, N, ROWS, LANE), F32), ((C, ROWS, LANE), U32),
              ((ROWS, LANE), U32), ((1, C * (N + 2) + 3), F32))
-
-
-def test_fused_hw_prng_compiles(one_chip):
-    """The in-kernel hardware-PRNG aggregate (C-blocked grid)."""
-    def fn(wg, keys, sig):
-        params = _channel_params_block(sig, 3.2e-2, 1.0, 1.0, C)
-        return ota_aggregate_fused_pallas(wg, keys, params, n_clients=N,
-                                          interpret=False)
-    _compile(fn, one_chip, "ota_aggregate_fused", ((C, ROWS, LANE), F32),
-             ((2, 2), U32), ((C,), F32))
-
-
-def test_supplied_bits_aggregate_compiles(one_chip):
-    def fn(wg, bits, nbits, sig):
-        return ota_aggregate(wg, bits, nbits, sig, 3.2e-2, 1.0, 1.0,
-                             n_clients=N, interpret=False)
-    _compile(fn, one_chip, "ota_aggregate", ((C, P), F32), ((C, P), U32),
-             ((P,), U32), ((C,), F32))
 
 
 def test_mask_weight_compiles(one_chip):
